@@ -4,14 +4,8 @@ with its brpc parameter server (SURVEY §2.2), redesigned TPU-first
 (distributed/ps.py docstring).
 
 Usage: PYTHONPATH=. python examples/ctr_sparse_embedding.py
+Runs on whatever platform JAX selects (JAX_PLATFORMS=cpu for a dry run).
 """
-import os
-
-import jax
-
-if not os.environ.get("PADDLE_TPU_EXAMPLE_TPU"):
-    jax.config.update("jax_platforms", "cpu")
-
 import numpy as np
 
 import paddle_tpu as paddle

@@ -1,15 +1,8 @@
 """Train → export → serve: the deployment path.
 
 Usage: PYTHONPATH=. python examples/deploy_inference.py
+Runs on whatever platform JAX selects (JAX_PLATFORMS=cpu for a dry run).
 """
-import os
-import jax
-
-# examples default to CPU so they run anywhere; set PADDLE_TPU_EXAMPLE_TPU=1
-# on a TPU host to use the chips
-if not os.environ.get("PADDLE_TPU_EXAMPLE_TPU"):
-    jax.config.update("jax_platforms", "cpu")
-
 import tempfile
 
 import numpy as np

@@ -19,16 +19,11 @@ analog of the reference's fused_multi_transformer CacheKV serving):
      overloaded_total; HTTP 503 once begin_drain() flips the replica
      out of rotation), `/statusz`, and `/tracez` tail-sampled traces
 
-Usage: PYTHONPATH=. python examples/serve_gpt.py
-       PADDLE_TPU_EXAMPLE_TPU=1 ... [gpt3-1.3b] for real-chip sizes.
+Usage: PYTHONPATH=. python examples/serve_gpt.py [gpt3-1.3b]
+Runs on whatever platform JAX selects (JAX_PLATFORMS=cpu for a dry run);
+on a TPU the model is bf16, and a preset name serves at real size.
 """
-import os
 import sys
-
-import jax
-
-if not os.environ.get("PADDLE_TPU_EXAMPLE_TPU"):
-    jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 import paddle_tpu as paddle
@@ -36,6 +31,7 @@ import paddle_tpu as paddle
 
 def main():
     from paddle_tpu.models import GPTForCausalLM, gpt_config, GPTConfig
+    paddle.device.enable_compile_cache()
     paddle.seed(0)
     if len(sys.argv) > 1:
         cfg = gpt_config(sys.argv[1])
@@ -46,7 +42,7 @@ def main():
                         intermediate_size=128)
         B, cap, new = 2, 12, 8
     model = GPTForCausalLM(cfg)
-    if os.environ.get("PADDLE_TPU_EXAMPLE_TPU"):
+    if paddle.device.on_tpu():
         model.to(dtype="bfloat16")
     model.eval()
     rng = np.random.RandomState(0)

@@ -1,15 +1,8 @@
-"""Eager + fused-step training example (runs on CPU in seconds).
+"""Eager + fused-step training example (seconds, on any platform).
 
 Usage: PYTHONPATH=. python examples/train_eager.py
+Runs on whatever platform JAX selects (JAX_PLATFORMS=cpu for a dry run).
 """
-import os
-import jax
-
-# examples default to CPU so they run anywhere; set PADDLE_TPU_EXAMPLE_TPU=1
-# on a TPU host to use the chips
-if not os.environ.get("PADDLE_TPU_EXAMPLE_TPU"):
-    jax.config.update("jax_platforms", "cpu")
-
 import numpy as np
 import paddle_tpu as paddle
 import paddle_tpu.nn as nn

@@ -6,15 +6,9 @@ in under a minute with the tiny default config; pass a preset name for the
 real sizes on a TPU host.
 
 Usage: PYTHONPATH=. python examples/train_ernie.py [ernie-3.0-medium]
-       PADDLE_TPU_EXAMPLE_TPU=1 ... to use the chips.
+Runs on whatever platform JAX selects (JAX_PLATFORMS=cpu for a dry run).
 """
-import os
 import sys
-
-import jax
-
-if not os.environ.get("PADDLE_TPU_EXAMPLE_TPU"):
-    jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 import paddle_tpu as paddle

@@ -1,20 +1,16 @@
 """Hybrid-parallel GPT training on a device mesh.
 
-On CPU this uses 8 virtual devices (set before jax import); on a TPU slice
-the same code uses the real chips. Usage:
-    PYTHONPATH=. python examples/train_gpt_sharded.py
+Needs eight devices. With JAX_PLATFORMS=cpu these are eight virtual CPU
+devices (the flag below only shapes the CPU backend, and has to be set
+before jax is imported); on a TPU slice the same code uses the real chips.
+Usage:
+    JAX_PLATFORMS=cpu PYTHONPATH=. python examples/train_gpt_sharded.py
 """
 import os
 
 if "--xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                + " --xla_force_host_platform_device_count=8")
-import jax
-
-# examples default to CPU so they run anywhere; set PADDLE_TPU_EXAMPLE_TPU=1
-# on a TPU host to use the chips
-if not os.environ.get("PADDLE_TPU_EXAMPLE_TPU"):
-    jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 import paddle_tpu as paddle

@@ -53,19 +53,15 @@ def _source_summary(eqn, max_frames: int = 4) -> str:
     is what lets an allowlist entry match on the MEANINGFUL function
     (layer_norm, attention_reference, decode_static) instead of a lambda
     or closure body three frames down."""
-    try:
-        from jax._src import source_info_util
-        frames = []
-        for fr in source_info_util.user_frames(eqn.source_info):
-            frames.append(f"{fr.file_name.rsplit('/', 1)[-1]}:"
-                          f"{fr.start_line} ({fr.function_name})")
-            if len(frames) >= max_frames:
-                break
-        if frames:
-            return " < ".join(frames)
-        return source_info_util.summarize(eqn.source_info)
-    except Exception:
-        return ""
+    from jax._src import source_info_util
+    frames = []
+    # jax 0.9: user_frames walks the eqn's Traceback, not its SourceInfo
+    for fr in source_info_util.user_frames(eqn.source_info.traceback):
+        frames.append(f"{fr.file_name.rsplit('/', 1)[-1]}:"
+                      f"{fr.start_line} ({fr.function_name})")
+        if len(frames) >= max_frames:
+            break
+    return " < ".join(frames)
 
 
 def iter_eqns(jaxpr) -> Iterable:

@@ -81,7 +81,11 @@ _INSTR_RE = re.compile(
 _METADATA_RE = re.compile(
     r'metadata=\{[^}]*?op_name="([^"]*)"'
     r'(?:[^}]*?source_file="([^"]*)")?'
-    r'(?:[^}]*?source_line=(\d+))?')
+    r'(?:[^}]*?source_line=(\d+))?'
+    r'(?:[^}]*?stack_frame_id=(\d+))?')
+# the module header's source tables (jax 0.9 / XLA prints an op's site as
+# metadata stack_frame_id=N into these, no longer as source_file/line)
+_TABLE_ROW_RE = re.compile(r'^(\d+)\s+(?:"([^"]*)"|\{([^}]*)\})\s*$')
 _REPLICA_GROUPS_RE = re.compile(
     r"replica_groups=(\{\{[^}]*(?:\},\{[^}]*)*\}\}|\[[0-9,]+\]<=\[[^\]]*\]"
     r"(?:T\([0-9,]+\))?)")
@@ -120,6 +124,16 @@ def _where_of(meta: Optional[dict]) -> str:
     if op:
         parts.append(f"({op})")
     return " ".join(parts)
+
+
+def _frame_site(tables, frame_id: int):
+    """(file, line) of a stack frame in the module header's tables."""
+    frame = tables.get("StackFrames", {}).get(frame_id) or {}
+    loc = tables.get("FileLocations", {}).get(
+        frame.get("file_location_id")) or {}
+    file_name = tables.get("FileNames", {}).get(loc.get("file_name_id"))
+    line = loc.get("line")
+    return file_name, (str(line) if line is not None else None)
 
 
 def _parse_groups(attrs: str) -> Tuple[str, Optional[int], Optional[int]]:
@@ -213,9 +227,25 @@ def parse_hlo(text: str) -> Tuple[List[HloCollective],
     collectives: List[HloCollective] = []
     defs: Dict[str, Tuple[str, List[str]]] = {}
     entries: Dict[int, HloEntryParam] = {}
+    types: Dict[str, str] = {}       # instr name -> its result type string
+    tables: Dict[str, Dict[int, object]] = {}
+    table = None
     in_entry = False
     depth_entry = 0
     for line in text.splitlines():
+        if line in ("FileNames", "FunctionNames", "FileLocations",
+                    "StackFrames"):
+            table = tables.setdefault(line, {})
+            continue
+        if table is not None:
+            row = _TABLE_ROW_RE.match(line)
+            if row:
+                idx, name, fields = row.groups()
+                table[int(idx)] = name if name is not None else {
+                    k: int(v) for k, v in re.findall(r"(\w+)=(\d+)", fields)}
+                continue
+            if line.strip():
+                table = None
         if line.startswith("ENTRY"):
             in_entry = True
             depth_entry = 0
@@ -231,6 +261,7 @@ def parse_hlo(text: str) -> Tuple[List[HloCollective],
         operand_str = rest.split(")")[0] if ")" in rest else rest
         operand_names = re.findall(r"%([\w.\-]+)", operand_str)
         defs[name] = (opcode, operand_names)
+        types[name] = type_str
         pm = _PARAM_RE.match(line)
         if pm and in_entry:
             hlo_name, type_s, idx, shard = pm.groups()
@@ -256,12 +287,19 @@ def parse_hlo(text: str) -> Tuple[List[HloCollective],
             meta = {"op_name": meta_m.group(1),
                     "source_file": meta_m.group(2),
                     "source_line": meta_m.group(3)}
+            if meta_m.group(4) and not meta["source_file"]:
+                meta["source_file"], meta["source_line"] = _frame_site(
+                    tables, int(meta_m.group(4)))
         raw, ng, gs = _parse_groups(rest)
         ch = re.search(r"channel_id=(\d+)", rest)
+        # operands print with their types inline, or (jax 0.9) as bare
+        # names whose types are on the lines that define them
+        operands = _shape_dtype(operand_str) or [
+            v for n in operand_names for v in _shape_dtype(types.get(n, ""))]
         collectives.append(HloCollective(
             name=name, kind=base,
             out=_shape_dtype(type_str),
-            operands=_shape_dtype(operand_str),
+            operands=operands,
             operand_names=operand_names,
             replica_groups=raw, num_groups=ng, group_size=gs,
             channel_id=int(ch.group(1)) if ch else None,

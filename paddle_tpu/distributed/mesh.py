@@ -121,3 +121,50 @@ def shard_constraint(arr, *spec):
         return jax.lax.with_sharding_constraint(arr, NamedSharding(m, filter_spec(*spec)))
     except (ValueError, TypeError):
         return arr
+
+
+def _auto_axes(m):
+    """Axes of mesh `m` that no enclosing shard_map has made manual."""
+    manual = jax.sharding.get_abstract_mesh().manual_axes
+    return {a for a in m.axis_names if a not in manual}
+
+
+def kernel_axes(args, in_specs):
+    """The mesh axes `shard_kernel` will really split `args` over: those
+    `in_specs` name that the active mesh has with degree > 1, that are
+    not already manual, and whose degree divides every dim they name."""
+    m = get_mesh()
+    if m is None:
+        return frozenset()
+    axes = {a for a in _auto_axes(m) if m.shape[a] > 1}
+    for arg, spec in zip(args, in_specs):
+        for dim, name in zip(arg.shape, spec):
+            if name in axes and dim % m.shape[name]:
+                axes.discard(name)
+    return frozenset(n for spec in in_specs for n in spec if n in axes)
+
+
+def shard_kernel(fn, args, in_specs, out_spec):
+    """Call a Pallas kernel on arrays that live on the active mesh.
+
+    A Mosaic kernel is opaque to the SPMD partitioner ("Mosaic kernels
+    cannot be automatically partitioned. Please wrap the call in a
+    shard_map"), so under a mesh `fn(*args)` runs per shard inside a
+    shard_map over every mesh axis that is not manual already. Each spec
+    is a tuple of axis names (or None) per dim and names only the axes
+    the kernel is independent over — batch over dp, heads over mp; a name
+    `kernel_axes` drops leaves that dim whole on every shard, which is
+    redundant work, never a wrong answer. fn returns one array, laid out
+    as `out_spec` says. Off-mesh this is fn(*args)."""
+    m = get_mesh()
+    auto = _auto_axes(m) if m is not None else None
+    if not auto:
+        return fn(*args)
+    split = kernel_axes(args, in_specs)
+
+    def to_p(spec):
+        return P(*[n if n in split else None for n in spec])
+
+    return jax.shard_map(fn, mesh=m, axis_names=auto,
+                         in_specs=tuple(map(to_p, in_specs)),
+                         out_specs=to_p(out_spec), check_vma=False)(*args)

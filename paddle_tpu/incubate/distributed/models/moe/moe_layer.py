@@ -131,21 +131,9 @@ def _moe_forward(x, gw, w1, b1, w2, b2, *, top_k, capacity_factor, gate_type,
     cap = _capacity(b * s, e, top_k, capacity_factor)
     import os
     n = tokens.shape[0]
-    env = os.environ.get("PADDLE_TPU_MOE_GATHER")
-    if env is not None:
-        gather_mode = env == "1"
-    else:
-        # jax<0.5 SPMD-partitioner bug (r8, bisected with the numerics
-        # stats): a gather whose operand feeds/consumes an ep-sharded
-        # constraint partitions WRONG — routing indices stay exact but
-        # tokens_ext[src] (dispatch) and out_ext[safe_pos] (combine) read
-        # other shards' rows (~100% of outputs off; replicating the gather
-        # operands fixes it, proving the partitioning is at fault). The
-        # one-hot einsum dispatch is exact under the same mesh, so on old
-        # runtimes with a real ep axis we take it; the index-gather fast
-        # path stays the default everywhere else.
-        old_jax = jax.__version_info__ < (0, 5, 0)
-        gather_mode = not (old_jax and _mesh.mesh_axis_size("ep") > 1)
+    # the index-gather dispatch is the default; =0 takes the one-hot
+    # einsum pair (the exact reference the parity tests compare against)
+    gather_mode = os.environ.get("PADDLE_TPU_MOE_GATHER", "1") == "1"
 
     if gather_mode:
         # INDEX dispatch (r4): the one-hot einsum pair costs

@@ -60,7 +60,8 @@ buffers are DONATED through every call, so XLA updates KV in place.
 Pallas paged kernel while the static path stores bf16 scores, so parity
 there is approximate near argmax ties — exact whenever both sides share
 a numerics class: f32 models anywhere, or the CPU reference path; see
-ops/pallas/paged_attention.py and tools/validate_paged_tpu.py.)
+ops/pallas/paged_attention.py, and chip_smoke.py's serve phase for the
+agreement required of a bf16 model on the chip.)
 
 `ServingConfig(paged=True, prefix_cache=True)` (ISSUE 10) adds the
 radix-trie PREFIX CACHE (inference/prefix_cache.py): admission matches

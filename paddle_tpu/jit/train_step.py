@@ -1060,21 +1060,24 @@ class TrainStep:
         plan["axes"] = dict(axes)
         return plan
 
-    def aot_memory_analysis(self, *batch):
-        """Compile the full step ahead-of-time with ABSTRACT inputs (params,
+    def aot_lower(self, *batch):
+        """Lower the full step ahead-of-time from ABSTRACT inputs (params,
         optimizer state, and batch as ShapeDtypeStructs — nothing is
-        materialized or executed) and return XLA's buffer-assignment memory
-        analysis: the compiler-accounted per-device argument/output/temp
-        bytes, i.e. the true activation+workspace footprint of the chosen
-        remat/pipeline schedule. `batch` leaves may be jax.ShapeDtypeStruct
-        or arrays."""
+        materialized or executed). `batch` leaves may be
+        jax.ShapeDtypeStruct or arrays. Returns the jax Lowered: its
+        as_text() is the program handed to the compiler, compile() the
+        executable the step would run."""
         abstract_state = self._abstract_opt_state()
         saved = self._opt_state
         self._opt_state = abstract_state
         try:
+            # array leaves go in by shape and dtype only: where they sit
+            # must not pin the lowering (a caller's own ShapeDtypeStruct,
+            # sharding included, is kept as it is)
             flat, treedef = jax.tree.flatten(tuple(
                 b if isinstance(b, jax.ShapeDtypeStruct)
-                else (b._data if isinstance(b, Tensor) else jnp.asarray(b))
+                else self._sds(b._data if isinstance(b, Tensor)
+                               else jnp.asarray(b))
                 for b in batch))
             built = self._build(treedef, [len(a.shape) for a in flat])
             p_sds = tuple(jax.ShapeDtypeStruct(p._data.shape, p._data.dtype)
@@ -1085,12 +1088,24 @@ class TrainStep:
             if self._scaler is not None:
                 sstate = tuple(jax.ShapeDtypeStruct((), d)
                                for d in (jnp.float32, jnp.int32, jnp.int32))
-            lowered = built.lower(
+            return built.lower(
                 p_sds, s_sds, sstate, jax.ShapeDtypeStruct((), jnp.int32),
                 jax.ShapeDtypeStruct((), jnp.float32), key, *flat)
-            return lowered.compile().memory_analysis()
         finally:
             self._opt_state = saved
+
+    def aot_compile(self, *batch):
+        """`aot_lower(*batch).compile()`: the compiled step, for its
+        as_text() (which kernels and collectives are in it) and its
+        memory_analysis()."""
+        return self.aot_lower(*batch).compile()
+
+    def aot_memory_analysis(self, *batch):
+        """XLA's buffer-assignment memory analysis of the ahead-of-time
+        compiled step: the compiler-accounted per-device argument/output/
+        temp bytes, i.e. the true activation+workspace footprint of the
+        chosen remat/pipeline schedule."""
+        return self.aot_compile(*batch).memory_analysis()
 
     def _register_memz(self):
         """Register params/opt-state as HBM-ledger owners (ISSUE 18) —

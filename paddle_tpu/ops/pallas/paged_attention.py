@@ -29,15 +29,17 @@ attention is KV-bandwidth-bound, so the fetch pattern IS the optimization.
 Numerics: f32 scores/softmax/accumulation whatever the pool dtype (like
 the other Pallas kernels here — the XLA static-cache path instead stores
 scores in the model dtype, so bf16 models' kernel-vs-reference parity is
-approximate; see tools/validate_paged_tpu.py).
+approximate; chip_smoke.py's serve phase states what agreement it
+requires on the chip instead).
 
 Rows with lens == 0 (dummy batch slots) output zeros (the reference path
 outputs masked-uniform garbage instead — both are dropped by callers, and
 the parity tests compare live rows).
 
-CPU validation runs this kernel in interpret mode (tests); on-chip
-compiled parity is tools/validate_paged_tpu.py, same split as the other
-Pallas kernels here.
+CPU validation runs this kernel in interpret mode (tests);
+tests/test_chip_compile.py compiles every variant for the described chip
+at GPT-1.3B widths, and chip_smoke.py's kernel phase compares them with
+the references on the chip.
 """
 from __future__ import annotations
 
@@ -50,7 +52,20 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .flash_attention import _i0  # i32 index-map literal (Mosaic x64 rule)
+
 _NEG = -1e30
+
+
+def _row_map(ndim):
+    """Index map of a q / out tile: batch row `bi`, the rest whole."""
+    return lambda bi, j, tables, aux: (bi,) + (_i0(),) * (ndim - 1)
+
+
+def _page_map(ndim):
+    """Index map of a pool tile: the page the scalar-prefetched block
+    table names for (row, slot)."""
+    return lambda bi, j, tables, aux: (tables[bi, j],) + (_i0(),) * (ndim - 1)
 
 
 def _kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
@@ -165,18 +180,16 @@ def paged_attention_q8_kernel(q, kc_pool, ks_pool, vc_pool, vs_pool,
     if scale is None:
         scale = 1.0 / math.sqrt(hd)
 
-    pool_spec = pl.BlockSpec((1, bs, nh, hd),
-                             lambda bi, j, T, L: (T[bi, j], 0, 0, 0))
-    scale_spec = pl.BlockSpec((1, bs, nh),
-                              lambda bi, j, T, L: (T[bi, j], 0, 0))
+    pool_spec = pl.BlockSpec((1, bs, nh, hd), _page_map(4))
+    scale_spec = pl.BlockSpec((1, bs, nh), _page_map(3))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, mb),
         in_specs=[
-            pl.BlockSpec((1, nh, hd), lambda bi, j, T, L: (bi, 0, 0)),
+            pl.BlockSpec((1, nh, hd), _row_map(3)),
             pool_spec, scale_spec, pool_spec, scale_spec,
         ],
-        out_specs=pl.BlockSpec((1, nh, hd), lambda bi, j, T, L: (bi, 0, 0)),
+        out_specs=pl.BlockSpec((1, nh, hd), _row_map(3)),
         scratch_shapes=[pltpu.VMEM((nh, 1), jnp.float32),
                         pltpu.VMEM((nh, 1), jnp.float32),
                         pltpu.VMEM((nh, hd), jnp.float32)],
@@ -317,17 +330,15 @@ def paged_prefix_attention_kernel(q, k_pool, v_pool, tables, start, *,
     if scale is None:
         scale = 1.0 / math.sqrt(hd)
 
-    pool_spec = pl.BlockSpec((1, bs, nh, hd),
-                             lambda bi, j, T, S_: (T[bi, j], 0, 0, 0))
+    pool_spec = pl.BlockSpec((1, bs, nh, hd), _page_map(4))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, mb),
         in_specs=[
-            pl.BlockSpec((1, s, nh, hd), lambda bi, j, T, S_: (bi, 0, 0, 0)),
+            pl.BlockSpec((1, s, nh, hd), _row_map(4)),
             pool_spec, pool_spec,
         ],
-        out_specs=pl.BlockSpec((1, s, nh, hd),
-                               lambda bi, j, T, S_: (bi, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, s, nh, hd), _row_map(4)),
         scratch_shapes=[pltpu.VMEM((nh, s, 1), jnp.float32),
                         pltpu.VMEM((nh, s, 1), jnp.float32),
                         pltpu.VMEM((nh, s, hd), jnp.float32)],
@@ -353,19 +364,16 @@ def paged_prefix_attention_q8_kernel(q, kc_pool, ks_pool, vc_pool, vs_pool,
     if scale is None:
         scale = 1.0 / math.sqrt(hd)
 
-    pool_spec = pl.BlockSpec((1, bs, nh, hd),
-                             lambda bi, j, T, S_: (T[bi, j], 0, 0, 0))
-    scale_spec = pl.BlockSpec((1, bs, nh),
-                              lambda bi, j, T, S_: (T[bi, j], 0, 0))
+    pool_spec = pl.BlockSpec((1, bs, nh, hd), _page_map(4))
+    scale_spec = pl.BlockSpec((1, bs, nh), _page_map(3))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, mb),
         in_specs=[
-            pl.BlockSpec((1, s, nh, hd), lambda bi, j, T, S_: (bi, 0, 0, 0)),
+            pl.BlockSpec((1, s, nh, hd), _row_map(4)),
             pool_spec, scale_spec, pool_spec, scale_spec,
         ],
-        out_specs=pl.BlockSpec((1, s, nh, hd),
-                               lambda bi, j, T, S_: (bi, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, s, nh, hd), _row_map(4)),
         scratch_shapes=[pltpu.VMEM((nh, s, 1), jnp.float32),
                         pltpu.VMEM((nh, s, 1), jnp.float32),
                         pltpu.VMEM((nh, s, hd), jnp.float32)],
@@ -403,13 +411,11 @@ def paged_attention_kernel(q, k_pool, v_pool, tables, lens, *, scale=None,
         num_scalar_prefetch=2,
         grid=(b, mb),
         in_specs=[
-            pl.BlockSpec((1, nh, hd), lambda bi, j, T, L: (bi, 0, 0)),
-            pl.BlockSpec((1, bs, nh, hd),
-                         lambda bi, j, T, L: (T[bi, j], 0, 0, 0)),
-            pl.BlockSpec((1, bs, nh, hd),
-                         lambda bi, j, T, L: (T[bi, j], 0, 0, 0)),
+            pl.BlockSpec((1, nh, hd), _row_map(3)),
+            pl.BlockSpec((1, bs, nh, hd), _page_map(4)),
+            pl.BlockSpec((1, bs, nh, hd), _page_map(4)),
         ],
-        out_specs=pl.BlockSpec((1, nh, hd), lambda bi, j, T, L: (bi, 0, 0)),
+        out_specs=pl.BlockSpec((1, nh, hd), _row_map(3)),
         scratch_shapes=[pltpu.VMEM((nh, 1), jnp.float32),
                         pltpu.VMEM((nh, 1), jnp.float32),
                         pltpu.VMEM((nh, hd), jnp.float32)],

@@ -85,7 +85,8 @@ def test_donation_honored_plus_candidate_advice():
 
 
 def test_donation_alias_parse_survives_sharding_attrs():
-    """mhlo.sharding attr values contain nested braces and sort BEFORE
+    """Sharding attr values (`sdy.sharding = #sdy.sharding<@mesh, [{},
+    {}]>` under jax 0.9's Shardy) contain nested braces and sort BEFORE
     tf.aliasing_output in the lowered signature — the alias parse must
     not truncate there (else every sharded donation reads as a silent
     copy)."""
@@ -100,7 +101,7 @@ def test_donation_alias_parse_survives_sharding_attrs():
     jf = jax.jit(f, donate_argnums=(0,), in_shardings=(sh, sh))
     txt = jf.lower(SDS((8, 8), jnp.float32),
                    SDS((8, 8), jnp.float32)).as_text()
-    assert "mhlo.sharding" in txt      # the hazard is actually present
+    assert "sdy.sharding" in txt       # the hazard is actually present
     n, aliases = parse_io_aliases(txt)
     assert n == 2 and aliases == {0: 0}
     fs = GraphLint(donate_bytes=1).check(

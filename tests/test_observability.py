@@ -257,5 +257,39 @@ class TestDeviceMemoryStats:
         class _D:
             device_kind = "TPU v5e"
         assert device.chip_peak_flops(_D()) == 197e12
+        # a kind the table does not know is an error, not a v4: an
+        # assumed peak makes every MFU figure wrong without a trace
         _D.device_kind = "weird accelerator"
-        assert device.chip_peak_flops(_D()) == 275e12
+        with pytest.raises(ValueError, match="weird accelerator"):
+            device.chip_peak_flops(_D())
+
+    def test_no_tpu_is_said_not_hidden(self):
+        """The tests run on the CPU: on_tpu() says so, and asking for the
+        TPU by name raises instead of quietly handing back a CPU device."""
+        assert not device.on_tpu()
+        assert not paddle.is_compiled_with_tpu()
+        with pytest.raises(RuntimeError, match="no 'tpu' device"):
+            device.set_device("tpu")
+        assert device.set_device("cpu:1").platform == "cpu"
+        assert device.get_device() == "cpu:1"
+        device._current[0] = None
+
+    def test_compile_cache_follows_the_environment(self, monkeypatch):
+        """JAX_COMPILATION_CACHE_DIR set: JAX already uses it, nothing is
+        set in code. Unset: one fixed, git-ignored path in the checkout."""
+        import jax
+        was = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+        assert device.enable_compile_cache() == "/some/dir"
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        try:
+            path = device.enable_compile_cache()
+            assert path == os.path.join(repo, ".jax_compile_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+            assert device.enable_compile_cache() == path      # fixed
+            with open(os.path.join(repo, ".gitignore")) as f:
+                assert ".jax_compile_cache/" in f.read().split()
+        finally:
+            jax.config.update("jax_compilation_cache_dir", was)

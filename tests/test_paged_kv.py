@@ -145,9 +145,10 @@ def test_paged_reference_matches_masked_attention(lens):
 
 
 def test_paged_kernel_interpret_matches_reference():
-    """The Pallas kernel (interpret mode on CPU; compiled mode is
-    tools/validate_paged_tpu.py) against the gather reference — live rows
-    only (the kernel zeros dummy lens=0 rows by design)."""
+    """The Pallas kernel (interpret mode on CPU; compiled for the chip by
+    tests/test_chip_compile.py, compared on the chip by chip_smoke.py's
+    kernel phase) against the gather reference — live rows only (the
+    kernel zeros dummy lens=0 rows by design)."""
     lens = (5, 8, 1)
     bs, nh, hd, mb = 4, 4, 8, 4
     kp, vp, tables, _, _ = _build_pool(lens, bs, nh, hd, mb, seed=2)

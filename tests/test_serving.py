@@ -374,16 +374,22 @@ def test_warmup_depth_extension_is_not_a_recompile(served_model):
     chunk executables ever compiled; their eventual first compile is NOT
     shape churn and must not trip the steady-state recompile guard."""
     m, cfg = served_model
-    lens = [CAP, 5]
+    lens = [CAP, 5, 3, 7, 2, 6, 1, 4]
     ids = _prompts(cfg, lens)
     ref = m.generate_static_ragged(paddle.to_tensor(ids), lens,
-                                   max_new_tokens=NEW).numpy()
-    eos = int(ref[0, CAP])             # row 0 greedily emits EOS first
-    eng = _engine(m, eos_token_id=eos)
-    eng.submit(ids[0, :CAP])
+                                   max_new_tokens=NEW).numpy()[:, CAP:]
+    # a row whose FIRST greedy token serves as EOS, and a row that does
+    # not meet that token early — picked from what the seed's weights make
+    # of these prompts (under jax 0.9's draws the old fixed pair emitted
+    # the same first token, so the second row never went deeper)
+    first, deep = next((a, b) for a in range(len(lens))
+                       for b in range(len(lens))
+                       if ref[a, 0] not in ref[b, :4])
+    eng = _engine(m, eos_token_id=int(ref[first, 0]))
+    eng.submit(ids[first, :lens[first]])
     eng.drain()                        # warmup stops after chunk 1
     assert eng._max_depth == 2         # prefill + first-token chunk only
-    eng.submit(ids[1, :5])             # decodes deeper than warmup did
+    eng.submit(ids[deep, :lens[deep]])  # decodes deeper than warmup did
     eng.drain()
     assert eng._max_depth > 2
     assert eng.monitor.recompiles == 0

@@ -203,9 +203,12 @@ def test_dp_train_step_lint_clean_and_plan_holds():
 
 
 def test_tp_train_step_wte_gather_is_allowlisted():
-    """Shipped hybrid tp config: the vocab-parallel table gather is a
-    REAL param-gather finding — reported, but allowlisted with its
-    documented reason (scoped to wte); nothing else fires."""
+    """Shipped hybrid tp config: nothing fires. A gather of the
+    vocab-parallel table, where the partitioner inserts one, is a REAL
+    param-gather finding — reported, but allowlisted with its documented
+    reason (scoped to wte). jax 0.9's partitioner no longer inserts it for
+    this step (its all-gathers are the packed-qkv activation reshapes), so
+    the finding may be absent; any that is there must be that one."""
     mesh = _mesh({"dp": 2, "mp": 4})
     dist.set_mesh(mesh)
     try:
@@ -218,8 +221,7 @@ def test_tp_train_step_wte_gather_is_allowlisted():
         assert not active, [str(f) for f in active]
         gathers = [f for f in audit.findings
                    if f.code == "param_gather"]
-        assert gathers and all(f.allowed for f in gathers)
-        assert all("wte" in f.where for f in gathers)
+        assert all(f.allowed and "wte" in f.where for f in gathers)
         assert {"all-reduce", "all-gather"} <= set(audit.by_kind())
     finally:
         dist.set_mesh(None)

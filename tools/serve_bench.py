@@ -48,8 +48,9 @@ table, and exits NONZERO on any breach — the same exit-code convention
 as the steady-state-recompile gate, so BENCH rows carry SLO attainment.
 Under an A/B mode the gate judges the LAST leg (the feature-on engine).
 
-Without --preset a 2-layer toy GPT runs on CPU (CI-sized); with a preset
-set PADDLE_TPU_EXAMPLE_TPU=1 to run real-chip sizes.
+Runs on whatever platform JAX selects (tests pass JAX_PLATFORMS=cpu from
+outside). Without --preset a 2-layer toy GPT (CI-sized); --preset serves a
+real size, in bf16 on a TPU.
 """
 from __future__ import annotations
 
@@ -60,9 +61,6 @@ import sys
 import time
 
 import jax
-
-if not os.environ.get("PADDLE_TPU_EXAMPLE_TPU"):
-    jax.config.update("jax_platforms", "cpu")
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -78,7 +76,7 @@ def build_model(preset):
                         num_heads=4, max_position_embeddings=128,
                         intermediate_size=128)
     model = GPTForCausalLM(cfg)
-    if os.environ.get("PADDLE_TPU_EXAMPLE_TPU"):
+    if paddle.device.on_tpu():
         model.to(dtype="bfloat16")
     model.eval()
     return model, cfg
@@ -428,11 +426,12 @@ def main(argv=None) -> int:
     if args.prefix_len is None:
         args.prefix_len = max(1, args.prompt_cap // 2)
 
-    # --shards needs a multi-device backend. XLA reads XLA_FLAGS at first
-    # BACKEND INIT (not at jax import), so setting it here still works —
-    # only an already-initialized smaller backend is unrecoverable.
-    if args.shards and args.shards > 1 \
-            and not os.environ.get("PADDLE_TPU_EXAMPLE_TPU"):
+    # --shards needs a multi-device backend. The flag shapes the CPU
+    # backend only (real chips are counted as they are). XLA reads
+    # XLA_FLAGS at first BACKEND INIT (not at jax import), so setting it
+    # here still works — only an already-initialized smaller backend is
+    # unrecoverable.
+    if args.shards and args.shards > 1:
         if "--xla_force_host_platform_device_count" not in \
                 os.environ.get("XLA_FLAGS", ""):
             os.environ["XLA_FLAGS"] = (
@@ -446,6 +445,8 @@ def main(argv=None) -> int:
                   f"XLA_FLAGS=--xla_force_host_platform_device_count=N "
                   f"before the first jax backend use)", file=sys.stderr)
             return 2
+    from paddle_tpu.device import enable_compile_cache
+    enable_compile_cache()
 
     try:
         reports, engine = run_bench(args)
