@@ -24,10 +24,21 @@ Gemma-on-TPU serving studies, PAPERS.md). This module provides:
 
   RequestTrace    per-request span timestamps (enqueue → admit → prefill →
                   first token → finish); each engine phase also runs under
-                  a `jax.profiler.TraceAnnotation` ("serving/prefill",
-                  "serving/decode") so device traces attribute kernel time
-                  to serving phases exactly like annotate_layers does for
-                  modules.
+                  a `jax.profiler.TraceAnnotation` so a device trace gives
+                  kernel time, and every gap between kernels, to what the
+                  host was doing. One engine step is "serving/step". Both
+                  engines open "serving/prefill" and "serving/decode"
+                  around a model call and the read that waits for it. The
+                  paged engine splits the two into "serving/prefill_launch"
+                  / "serving/prefill_read" and "serving/decode_launch" /
+                  "serving/decode_read", and names its own work between
+                  two calls: "serving/admit" (one queued request: trie
+                  match, block mapping, copy-on-write, slot set-up),
+                  "serving/decode_prep" (KV snapshot, state shipped),
+                  "serving/deliver" (tokens handed to requests, finished
+                  rows freed and recorded), "serving/bookkeep" (batch
+                  gauges, compile accounting, the monitor's step). PERF.md
+                  section 3 names the metric that reads each.
 
   ServingMetrics  log-bucketed latency histograms (TTFT, per-output-token
                   time, end-to-end, queue wait — p50/p90/p99 derived from
@@ -115,6 +126,11 @@ from ..profiler._metrics import (LogHistogram, counter_lines, gauge_lines,
                                  histogram_lines)
 
 _logger = logging.getLogger("paddle_tpu.inference.serving")
+
+# Host spans on the device trace's clock (the module docstring lists
+# them). A no-op while no profiler session is open; a span takes no
+# keyword argument, formats no string and reads no clock of its own.
+_span = jax.profiler.TraceAnnotation
 
 
 # --------------------------------------------------------------- requests
@@ -223,6 +239,12 @@ class Request:
     @property
     def prompt_len(self) -> int:
         return int(self.prompt.shape[0])
+
+    @property
+    def n_produced(self) -> int:
+        """Tokens the paged engine has delivered to this request so far
+        (0 until its first lands); `n_out` is final, this one moves."""
+        return getattr(self, "_produced", 0)
 
     def record(self) -> dict:
         """The JSONL payload ServingMetrics streams per finished request."""
@@ -1180,23 +1202,24 @@ class ServingEngine:
         return out
 
     def _step_dispatch(self) -> List[Request]:
-        if self.config.paged:
-            return self._step_paged()
-        reqs, expired = self._admit()
-        if not reqs:
-            return expired
-        try:
-            return expired + self._run_batch(reqs)
-        except BaseException:
-            now = self.clock()
-            for r in reqs:
-                if r.status == "active":
-                    r.status, r.reason = "error", "engine_exception"
-                    r.trace.t_finish = now
-                    self.metrics.record_request(r)
-            self.metrics.gauges["inflight"] = 0
-            self.monitor.end_step(items=0)   # no-op if begin never ran
-            raise
+        with _span("serving/step"):
+            if self.config.paged:
+                return self._step_paged()
+            reqs, expired = self._admit()
+            if not reqs:
+                return expired
+            try:
+                return expired + self._run_batch(reqs)
+            except BaseException:
+                now = self.clock()
+                for r in reqs:
+                    if r.status == "active":
+                        r.status, r.reason = "error", "engine_exception"
+                        r.trace.t_finish = now
+                        self.metrics.record_request(r)
+                self.metrics.gauges["inflight"] = 0
+                self.monitor.end_step(items=0)   # no-op if begin never ran
+                raise
 
     def _run_batch(self, reqs: List[Request]) -> List[Request]:
         cfg = self.config
@@ -1218,7 +1241,7 @@ class ServingEngine:
         need = max(r.max_new_tokens for r in reqs)
         self.monitor.begin_step()
         t_pf0 = self.clock()
-        with jax.profiler.TraceAnnotation("serving/prefill"):
+        with _span("serving/prefill"):
             st = self.model.prefill_static(
                 ids, max_len=cfg.max_len, prompt_lens=lens,
                 weight_dtype=cfg.weight_dtype, cache_dtype=cfg.cache_dtype)
@@ -1232,7 +1255,7 @@ class ServingEngine:
         schedule = cfg.chunk_schedule
         for ci, chunk in enumerate(schedule):
             t_c0 = self.clock()
-            with jax.profiler.TraceAnnotation("serving/decode"):
+            with _span("serving/decode"):
                 # per-(batch, chunk) seed: every decode_static call builds
                 # a fresh PRNG stream from its seed, so reusing one seed
                 # across chunks would replay the same draws
@@ -1402,38 +1425,40 @@ class ServingEngine:
             self.metrics.gauges["inflight"] = 0
             self.monitor.end_step(items=0)
             raise
-        self.metrics.gauges["inflight"] = len(self._live())
-        if ran:
-            # gauges describe the step's micro-batch: fill = rows live at
-            # decode-chunk entry (instant admission-finishes recycle one
-            # slot sequentially, so cap admission-only steps at capacity);
-            # occupancy is snapshotted at chunk entry too — the state the
-            # step actually served, not the post-free emptiness
-            n_real = len(live_entry) if live_entry else \
-                min(len(finished), len(self._slots))
-            kv_tokens, kv_slots, kv_shared = self._kv_snapshot
-            self.metrics.record_batch(
-                n_real=n_real, capacity=len(self._slots),
-                kv_tokens=kv_tokens, kv_slots=kv_slots,
-                kv_capacity=self._pool.capacity_tokens,
-                queue_depth=len(self._queue),
-                kv_shared_tokens=kv_shared)
-        if self._spill is not None:
-            if self._spill.spilled_total > spill0[0]:
-                ran.add("spill")
-            if self._spill.rehydrated_total > spill0[1]:
-                ran.add("rehydrate")
-        # compile accounting, same convention as the static engine: a miss
-        # while every executable this step ran was already seen is shape
-        # churn — log it through the r7 recompile detector
-        dm = _jit_cache_misses() - miss0
-        if dm:
-            self.monitor.record_compile(
-                "serving_batch", (("jit_cache_misses", dm),),
-                prev_sig=(("jit_cache_misses", 0),)
-                if ran and ran <= self._paged_seen else None)
-        self._paged_seen |= ran
-        self.monitor.end_step(items=out_tokens)
+        with _span("serving/bookkeep"):
+            self.metrics.gauges["inflight"] = len(self._live())
+            if ran:
+                # gauges describe the step's micro-batch: fill = rows live
+                # at decode-chunk entry (instant admission-finishes recycle
+                # one slot sequentially, so cap admission-only steps at
+                # capacity); occupancy is snapshotted at chunk entry too —
+                # the state the step actually served, not the post-free
+                # emptiness
+                n_real = len(live_entry) if live_entry else \
+                    min(len(finished), len(self._slots))
+                kv_tokens, kv_slots, kv_shared = self._kv_snapshot
+                self.metrics.record_batch(
+                    n_real=n_real, capacity=len(self._slots),
+                    kv_tokens=kv_tokens, kv_slots=kv_slots,
+                    kv_capacity=self._pool.capacity_tokens,
+                    queue_depth=len(self._queue),
+                    kv_shared_tokens=kv_shared)
+            if self._spill is not None:
+                if self._spill.spilled_total > spill0[0]:
+                    ran.add("spill")
+                if self._spill.rehydrated_total > spill0[1]:
+                    ran.add("rehydrate")
+            # compile accounting, same convention as the static engine: a
+            # miss while every executable this step ran was already seen is
+            # shape churn — log it through the r7 recompile detector
+            dm = _jit_cache_misses() - miss0
+            if dm:
+                self.monitor.record_compile(
+                    "serving_batch", (("jit_cache_misses", dm),),
+                    prev_sig=(("jit_cache_misses", 0),)
+                    if ran and ran <= self._paged_seen else None)
+            self._paged_seen |= ran
+            self.monitor.end_step(items=out_tokens)
         return expired + finished
 
     def _clear_slot(self, slot: int):
@@ -1623,136 +1648,140 @@ class ServingEngine:
         ran = set()
         free = [i for i, r in enumerate(self._slots) if r is None]
         while self._queue and free:
-            now = self.clock()
-            req = self._queue[0]
-            if req.deadline_s is not None and \
-                    now - req.trace.t_enqueue > req.deadline_s:
+            with _span("serving/admit"):
+                now = self.clock()
+                req = self._queue[0]
+                if req.deadline_s is not None and \
+                        now - req.trace.t_enqueue > req.deadline_s:
+                    self._queue.popleft()
+                    req.status, req.reason = "timeout", "queue_deadline"
+                    req.trace.t_finish = now
+                    self.metrics.record_request(req)
+                    expired.append(req)
+                    continue
+                plen = req.prompt_len
+                need_rows = plen + req.max_new_tokens - 1
+                matched, t = ([], 0) if self._prefix is None \
+                    else self._prefix.match(req.prompt)
+                # COW: an aligned full hit (t == plen) shares all matched
+                # blocks EXCEPT the last, which is replaced by a private copy
+                # (the re-decode write lands in it); otherwise the shared run
+                # is the matched run and fresh blocks carry the suffix
+                cow = t == plen and t > 0
+                shared = matched[:-1] if cow else matched
+                blocks = self._pool.alloc(req.id, need_rows, shared=shared)
+                if blocks is None and self._prefix is not None:
+                    # cached-but-idle prefixes are SOFT capacity: evict LRU
+                    # refcount-free entries before deciding to wait —
+                    # protecting the whole matched run (`shared` plus the
+                    # COW source) from being reclaimed out from under this
+                    # very admission
+                    n_fresh = self._pool.blocks_needed(need_rows) - len(shared)
+                    if self._prefix.reclaim(n_fresh, protect=matched):
+                        blocks = self._pool.alloc(req.id, need_rows,
+                                                  shared=shared)
+                    if blocks is None and not self._live():
+                        # nothing in flight will ever free blocks, so waiting
+                        # cannot help: a request that fits the pool alone
+                        # (preflight's fits_ever) must not starve on its own
+                        # protected cached prefix — drop the hit, reclaim
+                        # freely, full-prefill
+                        matched, t, cow, shared = [], 0, False, []
+                        if self._prefix.reclaim(
+                                self._pool.blocks_needed(need_rows)):
+                            blocks = self._pool.alloc(req.id, need_rows)
+                if blocks is None:
+                    # oversubscription wait: queued head outsizes the free
+                    # list. One structured row per EPISODE (ISSUE 18) — the
+                    # enter transition carries the flight-recorder trigger
+                    # key; steady-state waiting stays silent
+                    self._mem_pressure_enter(req, need_rows)
+                    break            # wait for live rows to free their blocks
+                self._mem_pressure_exit()
                 self._queue.popleft()
-                req.status, req.reason = "timeout", "queue_deadline"
-                req.trace.t_finish = now
-                self.metrics.record_request(req)
-                expired.append(req)
-                continue
-            plen = req.prompt_len
-            need_rows = plen + req.max_new_tokens - 1
-            matched, t = ([], 0) if self._prefix is None \
-                else self._prefix.match(req.prompt)
-            # COW: an aligned full hit (t == plen) shares all matched
-            # blocks EXCEPT the last, which is replaced by a private copy
-            # (the re-decode write lands in it); otherwise the shared run
-            # is the matched run and fresh blocks carry the suffix
-            cow = t == plen and t > 0
-            shared = matched[:-1] if cow else matched
-            blocks = self._pool.alloc(req.id, need_rows, shared=shared)
-            if blocks is None and self._prefix is not None:
-                # cached-but-idle prefixes are SOFT capacity: evict LRU
-                # refcount-free entries before deciding to wait —
-                # protecting the whole matched run (`shared` plus the
-                # COW source) from being reclaimed out from under this
-                # very admission
-                n_fresh = self._pool.blocks_needed(need_rows) - len(shared)
-                if self._prefix.reclaim(n_fresh, protect=matched):
-                    blocks = self._pool.alloc(req.id, need_rows,
-                                              shared=shared)
-                if blocks is None and not self._live():
-                    # nothing in flight will ever free blocks, so waiting
-                    # cannot help: a request that fits the pool alone
-                    # (preflight's fits_ever) must not starve on its own
-                    # protected cached prefix — drop the hit, reclaim
-                    # freely, full-prefill
-                    matched, t, cow, shared = [], 0, False, []
-                    if self._prefix.reclaim(
-                            self._pool.blocks_needed(need_rows)):
-                        blocks = self._pool.alloc(req.id, need_rows)
-            if blocks is None:
-                # oversubscription wait: queued head outsizes the free
-                # list. One structured row per EPISODE (ISSUE 18) — the
-                # enter transition carries the flight-recorder trigger
-                # key; steady-state waiting stays silent
-                self._mem_pressure_enter(req, need_rows)
-                break            # wait for live rows to free their blocks
-            self._mem_pressure_exit()
-            self._queue.popleft()
-            slot = free.pop(0)
-            req.status = "active"
-            req.trace.t_admit = now
-            req.trace.batch_id = self._batch_id
-            # install into the slot BEFORE the device call: if prefill
-            # dies mid-flight, _step_paged's handler finds the request
-            # here and records it as status="error" — the engine's
-            # in-flight accounting contract
-            self._slots[slot] = req
-            table_row = self._pool.table_row(req.id, self._tables.shape[1])
-            self._tables[slot] = table_row
-            self._shared_tok[slot] = len(shared) * bs
-            # probe admissions (ISSUE 19) stay out of the cache-efficiency
-            # counters: a prober's hit/miss variants are DESIGNED to
-            # always hit / always miss, so counting them would turn the
-            # fleet hit-rate and prefill-savings signals into artifacts
-            # of the probe cadence
-            if self._prefix is not None and not req.probe:
-                self.metrics.counters[
-                    "prefix_hit" if t else "prefix_miss"] += 1
-            if t >= plen - 1 and t > 0:
-                # zero-prefill admission: the whole prompt (minus the
-                # re-decoded last token) is served from cached blocks
-                if cow:
-                    self._cow_copy(matched[-1], int(blocks[len(shared)]))
-                    ran.add("cow")
-                self._lens[slot] = plen - 1
-                self._pending[slot] = int(req.prompt[plen - 1])
-                self._done[slot] = False
-                req._chunks = []
-                req._produced = 0
-                req.trace.t_prefill_done = now   # nothing to prefill
-                if not req.probe:
-                    self.metrics.counters["prefill_tokens_saved"] += \
-                        plen - 1
-                # re-stamp the matched chain; only positions < t hold
-                # written KV here (the pending re-decode hasn't run), so
-                # the insert must not cache any fresh block yet
-                self._insert_prefix(req, blocks, t)
-            elif cfg.prefill_chunk is not None:
-                # chunked prefill (ISSUE 11 satellite): admission only
-                # installs the slot — _advance_prefill runs one
-                # [1, prefill_chunk] window per engine step from position
-                # t, so a cap-length prompt costs cap/chunk STEPS of
-                # bounded work instead of one monopolizing call, and the
-                # decode batch keeps stepping between windows. The slot's
-                # decode state stays neutral (lens 0 / done) until the
-                # final window samples the first token.
-                self._prefill_pos[slot] = t
-                req._chunks = []
-                req._produced = 0
-                if t and not req.probe:
-                    self.metrics.counters["prefill_tokens_saved"] += t
-            else:
-                suffix = plen - t
-                ids = np.full((1, cfg.prompt_cap), cfg.pad_token_id,
-                              dtype=np.int64)
-                ids[0, :suffix] = req.prompt[t:]
-                start = None if t == 0 else np.asarray([t], np.int32)  # lint: allow(tracer-asarray)
-                t_pf0 = self.clock()
-                with jax.profiler.TraceAnnotation("serving/prefill"):
-                    self._pools, first = self.model.prefill_paged(
-                        ids, np.asarray([suffix], np.int32),  # lint: allow(tracer-asarray)
-                        self._pools, table_row[None],
-                        temperature=cfg.temperature, top_k=cfg.top_k,
-                        top_p=cfg.top_p, seed=cfg.seed + self._calls,
-                        weight_dtype=cfg.weight_dtype,
-                        cache_dtype=cfg.cache_dtype, start=start)
-                    tok = int(np.asarray(first.numpy())[0])  # lint: allow(tracer-asarray)
-                self._calls += 1
-                ran.add("prefill" if t == 0 else "prefix_prefill")
-                req.trace.events.append(
-                    ("prefill" if t == 0 else "suffix_prefill",
-                     t_pf0, self.clock()))
-                if t and not req.probe:
-                    self.metrics.counters["prefill_tokens_saved"] += t
-                if self._complete_prefill(slot, req, tok, self.clock()):
-                    finished.append(req)
-                    free.insert(0, slot)
-            self._batch_id += 1
+                slot = free.pop(0)
+                req.status = "active"
+                req.trace.t_admit = now
+                req.trace.batch_id = self._batch_id
+                # install into the slot BEFORE the device call: if prefill
+                # dies mid-flight, _step_paged's handler finds the request
+                # here and records it as status="error" — the engine's
+                # in-flight accounting contract
+                self._slots[slot] = req
+                table_row = self._pool.table_row(req.id, self._tables.shape[1])
+                self._tables[slot] = table_row
+                self._shared_tok[slot] = len(shared) * bs
+                # probe admissions (ISSUE 19) stay out of the cache-efficiency
+                # counters: a prober's hit/miss variants are DESIGNED to
+                # always hit / always miss, so counting them would turn the
+                # fleet hit-rate and prefill-savings signals into artifacts
+                # of the probe cadence
+                if self._prefix is not None and not req.probe:
+                    self.metrics.counters[
+                        "prefix_hit" if t else "prefix_miss"] += 1
+                if t >= plen - 1 and t > 0:
+                    # zero-prefill admission: the whole prompt (minus the
+                    # re-decoded last token) is served from cached blocks
+                    if cow:
+                        self._cow_copy(matched[-1], int(blocks[len(shared)]))
+                        ran.add("cow")
+                    self._lens[slot] = plen - 1
+                    self._pending[slot] = int(req.prompt[plen - 1])
+                    self._done[slot] = False
+                    req._chunks = []
+                    req._produced = 0
+                    req.trace.t_prefill_done = now   # nothing to prefill
+                    if not req.probe:
+                        self.metrics.counters["prefill_tokens_saved"] += \
+                            plen - 1
+                    # re-stamp the matched chain; only positions < t hold
+                    # written KV here (the pending re-decode hasn't run), so
+                    # the insert must not cache any fresh block yet
+                    self._insert_prefix(req, blocks, t)
+                elif cfg.prefill_chunk is not None:
+                    # chunked prefill (ISSUE 11 satellite): admission only
+                    # installs the slot — _advance_prefill runs one
+                    # [1, prefill_chunk] window per engine step from position
+                    # t, so a cap-length prompt costs cap/chunk STEPS of
+                    # bounded work instead of one monopolizing call, and the
+                    # decode batch keeps stepping between windows. The slot's
+                    # decode state stays neutral (lens 0 / done) until the
+                    # final window samples the first token.
+                    self._prefill_pos[slot] = t
+                    req._chunks = []
+                    req._produced = 0
+                    if t and not req.probe:
+                        self.metrics.counters["prefill_tokens_saved"] += t
+                else:
+                    suffix = plen - t
+                    ids = np.full((1, cfg.prompt_cap), cfg.pad_token_id,
+                                  dtype=np.int64)
+                    ids[0, :suffix] = req.prompt[t:]
+                    start = None if t == 0 else np.asarray([t], np.int32)  # lint: allow(tracer-asarray)
+                    t_pf0 = self.clock()
+                    with _span("serving/prefill"):
+                        with _span("serving/prefill_launch"):
+                            self._pools, first = self.model.prefill_paged(
+                                ids, np.asarray([suffix], np.int32),  # lint: allow(tracer-asarray)
+                                self._pools, table_row[None],
+                                temperature=cfg.temperature,
+                                top_k=cfg.top_k, top_p=cfg.top_p,
+                                seed=cfg.seed + self._calls,
+                                weight_dtype=cfg.weight_dtype,
+                                cache_dtype=cfg.cache_dtype, start=start)
+                        with _span("serving/prefill_read"):
+                            tok = int(np.asarray(first.numpy())[0])  # lint: allow(tracer-asarray)
+                    self._calls += 1
+                    ran.add("prefill" if t == 0 else "prefix_prefill")
+                    req.trace.events.append(
+                        ("prefill" if t == 0 else "suffix_prefill",
+                         t_pf0, self.clock()))
+                    if t and not req.probe:
+                        self.metrics.counters["prefill_tokens_saved"] += t
+                    if self._complete_prefill(slot, req, tok, self.clock()):
+                        finished.append(req)
+                        free.insert(0, slot)
+                self._batch_id += 1
         if not self._queue:
             # waiting head left some other way (deadline expiry, error
             # recovery draining the queue): close the episode truthfully
@@ -1771,52 +1800,56 @@ class ServingEngine:
         row that hit EOS or its budget. Returns (finished, real tokens)."""
         cfg = self.config
         c = cfg.decode_chunk
-        self._snapshot_kv()
-        tables, lens, pending, done = self._ship_decode_state()
+        with _span("serving/decode_prep"):
+            self._snapshot_kv()
+            tables, lens, pending, done = self._ship_decode_state()
         t_c0 = self.clock()
-        with jax.profiler.TraceAnnotation("serving/decode"):
-            toks, self._pools, _, done_d = self.model.decode_paged(
-                self._pools, tables, lens, pending,
-                done, c, temperature=cfg.temperature,
-                top_k=cfg.top_k, top_p=cfg.top_p,
-                seed=cfg.seed + self._calls,
-                eos_token_id=cfg.eos_token_id,
-                weight_dtype=cfg.weight_dtype,
-                cache_dtype=cfg.cache_dtype)
-            arr = np.asarray(toks.numpy())          # host sync per chunk  # lint: allow(tracer-asarray)
+        with _span("serving/decode"):
+            with _span("serving/decode_launch"):
+                toks, self._pools, _, done_d = self.model.decode_paged(
+                    self._pools, tables, lens, pending,
+                    done, c, temperature=cfg.temperature,
+                    top_k=cfg.top_k, top_p=cfg.top_p,
+                    seed=cfg.seed + self._calls,
+                    eos_token_id=cfg.eos_token_id,
+                    weight_dtype=cfg.weight_dtype,
+                    cache_dtype=cfg.cache_dtype)
+            with _span("serving/decode_read"):
+                arr = np.asarray(toks.numpy())      # host sync per chunk  # lint: allow(tracer-asarray)
         self._calls += 1
         t = self.clock()
-        pend_new = arr[:, -1].astype(np.int32)
-        done_new = np.array(done_d)        # copy: slot edits need a
-        #                                    writable host array
-        pf = self._prefill_pos >= 0        # mid-prefill rows rode as
-        pend_new[pf] = self._pending[pf]   # neutralized dummies — their
-        done_new[pf] = self._done[pf]      # real state must survive
-        self._pending = pend_new
-        self._done = done_new
-        finished: List[Request] = []
-        out_tokens = 0
-        for slot in live:
-            req = self._slots[slot]
-            req.trace.events.append(("decode", t_c0, t))
-            take = min(c, req.max_new_tokens - req._produced)
-            req._chunks.append(arr[slot, :take])
-            req._produced += take
-            out_tokens += take
-            if req.trace.t_first_token is None:
-                # zero-prefill admission (prefix cache): this chunk's
-                # first token IS the request's first token — TTFT was
-                # one decode step, measured not estimated
-                req.trace.t_first_token = t
-            self._lens[slot] += c     # device wrote c rows regardless
-            # EOS scan covers only the FRESH slice: earlier chunks were
-            # checked when they landed (an EOS there already finished the
-            # row), so the per-generation host cost stays O(n)
-            row_done = req._produced >= req.max_new_tokens or \
-                _hit_eos(arr[slot, :take], cfg.eos_token_id)
-            if row_done:
-                self._finish_paged_row(slot, t)
-                finished.append(req)
+        with _span("serving/deliver"):
+            pend_new = arr[:, -1].astype(np.int32)
+            done_new = np.array(done_d)        # copy: slot edits need a
+            #                                    writable host array
+            pf = self._prefill_pos >= 0        # mid-prefill rows rode as
+            pend_new[pf] = self._pending[pf]   # neutralized dummies — their
+            done_new[pf] = self._done[pf]      # real state must survive
+            self._pending = pend_new
+            self._done = done_new
+            finished: List[Request] = []
+            out_tokens = 0
+            for slot in live:
+                req = self._slots[slot]
+                req.trace.events.append(("decode", t_c0, t))
+                take = min(c, req.max_new_tokens - req._produced)
+                req._chunks.append(arr[slot, :take])
+                req._produced += take
+                out_tokens += take
+                if req.trace.t_first_token is None:
+                    # zero-prefill admission (prefix cache): this chunk's
+                    # first token IS the request's first token — TTFT was
+                    # one decode step, measured not estimated
+                    req.trace.t_first_token = t
+                self._lens[slot] += c     # device wrote c rows regardless
+                # EOS scan covers only the FRESH slice: earlier chunks were
+                # checked when they landed (an EOS there already finished the
+                # row), so the per-generation host cost stays O(n)
+                row_done = req._produced >= req.max_new_tokens or \
+                    _hit_eos(arr[slot, :take], cfg.eos_token_id)
+                if row_done:
+                    self._finish_paged_row(slot, t)
+                    finished.append(req)
         return finished, out_tokens
 
     def _advance_prefill(self):
@@ -1846,21 +1879,25 @@ class ServingEngine:
             ids = np.full((1, pc), cfg.pad_token_id, dtype=np.int64)
             ids[0, :clen] = req.prompt[off:off + clen]
             t_pf0 = self.clock()
-            with jax.profiler.TraceAnnotation("serving/prefill"):
-                self._pools, first = self.model.prefill_paged(
-                    ids, np.asarray([clen], np.int32),  # lint: allow(tracer-asarray)
-                    self._pools, self._tables[slot][None],
-                    temperature=cfg.temperature, top_k=cfg.top_k,
-                    top_p=cfg.top_p, seed=cfg.seed + self._calls,
-                    weight_dtype=cfg.weight_dtype,
-                    cache_dtype=cfg.cache_dtype,
-                    start=np.asarray([off], np.int32))  # lint: allow(tracer-asarray)
+            with _span("serving/prefill"):
+                with _span("serving/prefill_launch"):
+                    self._pools, first = self.model.prefill_paged(
+                        ids, np.asarray([clen], np.int32),  # lint: allow(tracer-asarray)
+                        self._pools, self._tables[slot][None],
+                        temperature=cfg.temperature, top_k=cfg.top_k,
+                        top_p=cfg.top_p, seed=cfg.seed + self._calls,
+                        weight_dtype=cfg.weight_dtype,
+                        cache_dtype=cfg.cache_dtype,
+                        start=np.asarray([off], np.int32))  # lint: allow(tracer-asarray)
                 # only the FINAL window's sampled token is meaningful —
                 # syncing the intermediate ones would serialize every
                 # window on a host round-trip for a value that gets
                 # discarded (exactly the long-prompt stall chunked
                 # prefill exists to remove)
-                tok = int(np.asarray(first.numpy())[0]) if final else 0  # lint: allow(tracer-asarray)
+                tok = 0
+                if final:
+                    with _span("serving/prefill_read"):
+                        tok = int(np.asarray(first.numpy())[0])  # lint: allow(tracer-asarray)
             self._calls += 1
             ran.add("prefill_chunk")
             req.trace.events.append(("prefill_chunk", t_pf0,
@@ -1957,65 +1994,69 @@ class ServingEngine:
         if not src:
             finished, out_tokens = self._decode_chunk_paged(live)
             return finished, out_tokens, {"decode"}
-        self._snapshot_kv()
-        tables, lens, pending, done = self._ship_decode_state()
+        with _span("serving/decode_prep"):
+            self._snapshot_kv()
+            tables, lens, pending, done = self._ship_decode_state()
         t_c0 = self.clock()
-        with jax.profiler.TraceAnnotation("serving/decode"):
-            toks, n_acc, self._pools, done_d = self.model.verify_paged(
-                self._pools, tables, lens, pending, drafts, done,
-                eos_token_id=cfg.eos_token_id,
-                weight_dtype=cfg.weight_dtype,
-                cache_dtype=cfg.cache_dtype)
-            arr = np.asarray(toks.numpy())          # host sync per window  # lint: allow(tracer-asarray)
-            acc = np.asarray(n_acc)  # lint: allow(tracer-asarray)
+        with _span("serving/decode"):
+            with _span("serving/decode_launch"):
+                toks, n_acc, self._pools, done_d = self.model.verify_paged(
+                    self._pools, tables, lens, pending, drafts, done,
+                    eos_token_id=cfg.eos_token_id,
+                    weight_dtype=cfg.weight_dtype,
+                    cache_dtype=cfg.cache_dtype)
+            with _span("serving/decode_read"):
+                arr = np.asarray(toks.numpy())      # host sync per window  # lint: allow(tracer-asarray)
+                acc = np.asarray(n_acc)  # lint: allow(tracer-asarray)
         self._calls += 1
         t = self.clock()
-        done_new = np.array(done_d)
-        finished: List[Request] = []
-        out_tokens = 0
-        mt = self.metrics
-        for slot in live:
-            req = self._slots[slot]
-            req.trace.events.append(("spec_verify", t_c0, t))
-            n_emit = int(acc[slot]) + 1
-            take = min(n_emit, req.max_new_tokens - req._produced)
-            fresh = arr[slot, :take]
-            req._chunks.append(fresh)
-            req._produced += take
-            out_tokens += take
-            if req.trace.t_first_token is None:
-                # zero-prefill admission: this window's first token IS
-                # the request's first token
-                req.trace.t_first_token = t
-            self._lens[slot] += n_emit   # the accepted frontier
-            self._pending[slot] = np.int32(arr[slot, n_emit - 1])
-            self._done[slot] = bool(done_new[slot])  # lint: allow(tracer-bool)
-            if slot in src:
-                # acceptance accounting covers DRAFTED rows only and
-                # REAL draft tokens only: a short trie draft's pad
-                # filler counts neither as proposed nor (if a pad
-                # accidentally matches) as accepted. A budget-truncated
-                # final window credits only the accepted drafts it
-                # actually EMITTED, so sum over windows ties out against
-                # speculative tokens out and the rate stays honest on
-                # short-budget / block-granular-draft traffic.
-                tag, dlen = src[slot]
-                used = min(int(acc[slot]), take, dlen)
-                req.spec_proposed += dlen
-                req.spec_accepted += used
-                if not req.probe:   # probe windows would skew the
-                    #                 acceptance-rate signal (ISSUE 19)
-                    mt.counters["spec_windows"] += 1
-                    mt.counters["spec_proposed"] += dlen
-                    mt.counters["spec_accepted"] += used
-                    mt.counters["spec_drafts_trie" if tag == "trie"
-                                else "spec_drafts_model"] += 1
-                    mt.hists["spec_accept_len"].observe(take)
-            row_done = req._produced >= req.max_new_tokens or \
-                _hit_eos(fresh, cfg.eos_token_id)
-            if row_done:
-                self._finish_paged_row(slot, t)
-                finished.append(req)
+        with _span("serving/deliver"):
+            done_new = np.array(done_d)
+            finished: List[Request] = []
+            out_tokens = 0
+            mt = self.metrics
+            for slot in live:
+                req = self._slots[slot]
+                req.trace.events.append(("spec_verify", t_c0, t))
+                n_emit = int(acc[slot]) + 1
+                take = min(n_emit, req.max_new_tokens - req._produced)
+                fresh = arr[slot, :take]
+                req._chunks.append(fresh)
+                req._produced += take
+                out_tokens += take
+                if req.trace.t_first_token is None:
+                    # zero-prefill admission: this window's first token IS
+                    # the request's first token
+                    req.trace.t_first_token = t
+                self._lens[slot] += n_emit   # the accepted frontier
+                self._pending[slot] = np.int32(arr[slot, n_emit - 1])
+                self._done[slot] = bool(done_new[slot])  # lint: allow(tracer-bool)
+                if slot in src:
+                    # acceptance accounting covers DRAFTED rows only and
+                    # REAL draft tokens only: a short trie draft's pad
+                    # filler counts neither as proposed nor (if a pad
+                    # accidentally matches) as accepted. A budget-truncated
+                    # final window credits only the accepted drafts it
+                    # actually EMITTED, so sum over windows ties out against
+                    # speculative tokens out and the rate stays honest on
+                    # short-budget / block-granular-draft traffic.
+                    tag, dlen = src[slot]
+                    used = min(int(acc[slot]), take, dlen)
+                    req.spec_proposed += dlen
+                    req.spec_accepted += used
+                    if not req.probe:   # probe windows would skew the
+                        #                 acceptance-rate signal (ISSUE 19)
+                        mt.counters["spec_windows"] += 1
+                        mt.counters["spec_proposed"] += dlen
+                        mt.counters["spec_accepted"] += used
+                        mt.counters["spec_drafts_trie" if tag == "trie"
+                                    else "spec_drafts_model"] += 1
+                        mt.hists["spec_accept_len"].observe(take)
+                row_done = req._produced >= req.max_new_tokens or \
+                    _hit_eos(fresh, cfg.eos_token_id)
+                if row_done:
+                    self._finish_paged_row(slot, t)
+                    finished.append(req)
         return finished, out_tokens, {"spec_verify"}
 
     def _finish_paged_row(self, slot: int, t: float):

@@ -102,6 +102,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc,
                                       (8, bq))
 
 
+FWD_NAME = "pallas_flash_fwd"
+
+
 def _flash_fwd(q, k, v, *, scale, causal, bq, bk, interpret, kv_len=None):
     b, s_q, h, d = q.shape
     s_k = k.shape[1]
@@ -127,6 +130,7 @@ def _flash_fwd(q, k, v, *, scale, causal, bq, bk, interpret, kv_len=None):
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name=FWD_NAME,
     )(qt, kt, vt)
     return out, lse, (qt, kt, vt)
 
@@ -207,6 +211,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
 
 
+DQ_NAME = "pallas_flash_dq"
+DKV_NAME = "pallas_flash_dkv"
+
+
 def _flash_bwd(res, g, *, scale, causal, bq, bk, interpret, kv_len=None):
     qt, kt, vt, out, lse = res
     bh, s_q, d = qt.shape
@@ -233,6 +241,7 @@ def _flash_bwd(res, g, *, scale, causal, bq, bk, interpret, kv_len=None):
         out_specs=pl.BlockSpec((1, bq, d), lambda b, qi, ki: (b, qi, _i0())),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name=DQ_NAME,
     )(qt, kt, vt, dot, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -254,6 +263,7 @@ def _flash_bwd(res, g, *, scale, causal, bq, bk, interpret, kv_len=None):
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
         interpret=interpret,
+        name=DKV_NAME,
     )(qt, kt, vt, dot, lse, delta)
     return dq, dk, dv
 
@@ -462,6 +472,9 @@ def _packed_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc,
             lse.T[:, None, :], (nh, 8, bq)).reshape(nh * 8, bq)
 
 
+PACKED_FWD_NAME = "pallas_packed_flash_fwd"
+
+
 def _packed_flash_fwd(q, k, v, *, scale, causal, bq, bk, interpret, nh,
                       kv_len=None):
     b, s_q, H = q.shape
@@ -488,6 +501,7 @@ def _packed_flash_fwd(q, k, v, *, scale, causal, bq, bk, interpret, nh,
                         pltpu.VMEM((bq, nh), jnp.float32),
                         pltpu.VMEM((bq, H), jnp.float32)],
         interpret=interpret,
+        name=PACKED_FWD_NAME,
     )(q, k, v)
     return out, lse
 
@@ -583,6 +597,10 @@ def _packed_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
 
 
+PACKED_DQ_NAME = "pallas_packed_flash_dq"
+PACKED_DKV_NAME = "pallas_packed_flash_dkv"
+
+
 def _packed_flash_bwd(q, k, v, out, lse, g, *, scale, causal, bq, bk,
                       interpret, nh, kv_len=None):
     b, s_q, H = q.shape
@@ -623,6 +641,7 @@ def _packed_flash_bwd(q, k, v, out, lse, g, *, scale, causal, bq, bk,
         out_specs=pl.BlockSpec((1, bq, H), lambda bi, qi, ki: (bi, qi, _i0())),
         scratch_shapes=[pltpu.VMEM((bq, H), jnp.float32)],
         interpret=interpret,
+        name=PACKED_DQ_NAME,
     )(q, k, v, g, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -646,6 +665,7 @@ def _packed_flash_bwd(q, k, v, out, lse, g, *, scale, causal, bq, bk,
         scratch_shapes=[pltpu.VMEM((bk, H), jnp.float32),
                         pltpu.VMEM((bk, H), jnp.float32)],
         interpret=interpret,
+        name=PACKED_DKV_NAME,
     )(q, k, v, g, lse, delta)
     return dq, dk, dv
 

@@ -239,6 +239,9 @@ def _lens_spec(b):
                         memory_space=pltpu.SMEM)
 
 
+FWD_NAME = "pallas_fused_mha_fwd"
+
+
 def _mha_fwd(qkv, seed, lensf, nh, scale, kv_len, causal, drop_p, G,
              interpret, use_lens):
     b, s, F3 = qkv.shape
@@ -260,6 +263,7 @@ def _mha_fwd(qkv, seed, lensf, nh, scale, kv_len, causal, drop_p, G,
         ],
         out_specs=pl.BlockSpec((1, s, G * hd), lambda bi, g: (bi, _i0(), g)),
         interpret=interpret,
+        name=FWD_NAME,
     )(jax.lax.bitcast_convert_type(seed, jnp.int32),
       *extra_args, qkv, qkv, qkv)
     return out
@@ -270,6 +274,9 @@ def _mha_vjp_fwd(qkv, seed, lensf, nh, scale, kv_len, causal, drop_p, G,
     out = _mha_fwd(qkv, seed, lensf, nh, scale, kv_len, causal, drop_p, G,
                    interpret, use_lens)
     return out, (qkv, seed, lensf)
+
+
+BWD_NAME = "pallas_fused_mha_bwd"
 
 
 def _mha_vjp_bwd(nh, scale, kv_len, causal, drop_p, G, interpret, use_lens,
@@ -307,6 +314,7 @@ def _mha_vjp_bwd(nh, scale, kv_len, causal, drop_p, G, interpret, use_lens,
         out_specs=pl.BlockSpec((1, s, F3),
                                lambda bi, gg: (bi, _i0(), _i0())),
         interpret=interpret,
+        name=BWD_NAME,
     )(jax.lax.bitcast_convert_type(seed, jnp.int32),
       *extra_args, qkv, qkv, qkv, g_out)
     return dqkv, jnp.zeros_like(seed), jnp.zeros_like(lensf)

@@ -98,6 +98,9 @@ def _mha_b(q, k, v, bias, nh, scale, G, interpret):
     return _fwd(q, k, v, bias, nh, scale, G, interpret)
 
 
+FWD_NAME = "pallas_fused_mha_bias_fwd"
+
+
 def _fwd(q, k, v, bias, nh, scale, G, interpret):
     b, s, F = q.shape
     hd = F // nh
@@ -117,12 +120,16 @@ def _fwd(q, k, v, bias, nh, scale, G, interpret):
         ],
         out_specs=spec,
         interpret=interpret,
+        name=FWD_NAME,
     )(bias, q, k, v)
     return out
 
 
 def _vjp_fwd(q, k, v, bias, nh, scale, G, interpret):
     return _fwd(q, k, v, bias, nh, scale, G, interpret), (q, k, v, bias)
+
+
+BWD_NAME = "pallas_fused_mha_bias_bwd"
 
 
 def _vjp_bwd(nh, scale, G, interpret, res, g_out):
@@ -152,6 +159,7 @@ def _vjp_bwd(nh, scale, G, interpret, res, g_out):
                                                          _i0())),
         ),
         interpret=interpret,
+        name=BWD_NAME,
     )(bias, q, k, v, g_out)
     return dq, dk, dv, dbias.astype(bias.dtype)
 

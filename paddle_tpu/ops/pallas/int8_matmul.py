@@ -61,6 +61,9 @@ def _pick_tiles(m, k, n, itemsize, block_n):
     return 8, 128
 
 
+KERNEL_NAME = "pallas_int8_matmul"
+
+
 def int8_matmul(x, q, s, *, w_layout="kn", block_n=512, interpret=False):
     """y = x @ dequant(q, s). x: [M, K]; see module doc for layouts.
     Returns [M, N] in x.dtype. Falls back to an XLA dequant-matmul when the
@@ -94,6 +97,7 @@ def int8_matmul(x, q, s, *, w_layout="kn", block_n=512, interpret=False):
         ],
         out_specs=pl.BlockSpec((mt, bn), lambda mi, ni: (mi, ni)),
         interpret=interpret,
+        name=KERNEL_NAME,
     )(x, q, s.reshape(1, n).astype(jnp.float32))
     return out
 

@@ -95,6 +95,9 @@ def _pick_block(r):
     return bq
 
 
+FWD_NAME = "pallas_layer_norm_fwd"
+
+
 def _ln_fwd(x2, gamma, beta, eps, interpret):
     r, h = x2.shape
     bq = _pick_block(r)
@@ -134,8 +137,12 @@ def _ln_fwd(x2, gamma, beta, eps, interpret):
                    pl.BlockSpec((8, bq), lambda i: (_i0(), i)),
                    pl.BlockSpec((8, bq), lambda i: (_i0(), i))),
         interpret=interpret,
+        name=FWD_NAME,
     )(*args)
     return out, mu, rs
+
+
+BWD_NAME = "pallas_layer_norm_bwd"
 
 
 def _ln_bwd(dy2, x2, mu, rs, gamma, interpret):
@@ -179,6 +186,7 @@ def _ln_bwd(dy2, x2, mu, rs, gamma, interpret):
         scratch_shapes=[pltpu.VMEM((1, h), jnp.float32),
                         pltpu.VMEM((1, h), jnp.float32)],
         interpret=interpret,
+        name=BWD_NAME,
     )(*args)
     return dx, dg[0], db[0]
 

@@ -127,6 +127,9 @@ def _pick_block_v(bt, h, itemsize):
     return 128
 
 
+FWD_NAME = "pallas_linear_ce_fwd"
+
+
 def _fwd(x, w, labels, *, block_t, block_v, w_layout, interpret):
     t, h = x.shape
     vocab = w.shape[0] if w_layout == "vh" else w.shape[1]
@@ -153,6 +156,7 @@ def _fwd(x, w, labels, *, block_t, block_v, w_layout, interpret):
                         pltpu.VMEM((block_t, 1), jnp.float32),
                         pltpu.VMEM((block_t, 1), jnp.float32)],
         interpret=interpret,
+        name=FWD_NAME,
     )(labels.reshape(t, 1).astype(jnp.int32), x, w)
     return lse[:, 0], gold[:, 0]
 

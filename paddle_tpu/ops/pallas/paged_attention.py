@@ -160,6 +160,9 @@ def _kernel_q8(tables_ref, lens_ref, q_ref, kc_ref, ks_ref, vc_ref,
         o_ref[0] = (acc_sc[...] / l).astype(o_ref.dtype)
 
 
+DECODE_Q8_NAME = "pallas_paged_q8_decode"
+
+
 def paged_attention_q8_kernel(q, kc_pool, ks_pool, vc_pool, vs_pool,
                               tables, lens, *, scale=None,
                               interpret=False):
@@ -200,6 +203,7 @@ def paged_attention_q8_kernel(q, kc_pool, ks_pool, vc_pool, vs_pool,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nh, hd), q.dtype),
         interpret=interpret,
+        name=DECODE_Q8_NAME,
     )(tables.astype(jnp.int32), lens.astype(jnp.int32), q3,
       kc_pool, ks_pool, vc_pool, vs_pool)
     return out[:, None] if squeezed else out
@@ -316,6 +320,9 @@ def _kernel_multi_q8(tables_ref, start_ref, q_ref, kc_ref, ks_ref, vc_ref,
             o_ref[0, :, h, :] = (acc_sc[h] / l).astype(o_ref.dtype)
 
 
+PREFIX_NAME = "pallas_paged_prefix"
+
+
 def paged_prefix_attention_kernel(q, k_pool, v_pool, tables, start, *,
                                   scale=None, interpret=False):
     """Ragged multi-token paged attention: q [B, S, H, D] query tokens at
@@ -349,7 +356,11 @@ def paged_prefix_attention_kernel(q, k_pool, v_pool, tables, start, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, s, nh, hd), q.dtype),
         interpret=interpret,
+        name=PREFIX_NAME,
     )(tables.astype(jnp.int32), start.astype(jnp.int32), q, k_pool, v_pool)
+
+
+PREFIX_Q8_NAME = "pallas_paged_q8_prefix"
 
 
 def paged_prefix_attention_q8_kernel(q, kc_pool, ks_pool, vc_pool, vs_pool,
@@ -384,8 +395,12 @@ def paged_prefix_attention_q8_kernel(q, kc_pool, ks_pool, vc_pool, vs_pool,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, s, nh, hd), q.dtype),
         interpret=interpret,
+        name=PREFIX_Q8_NAME,
     )(tables.astype(jnp.int32), start.astype(jnp.int32), q,
       kc_pool, ks_pool, vc_pool, vs_pool)
+
+
+DECODE_NAME = "pallas_paged_decode"
 
 
 def paged_attention_kernel(q, k_pool, v_pool, tables, lens, *, scale=None,
@@ -425,5 +440,6 @@ def paged_attention_kernel(q, k_pool, v_pool, tables, lens, *, scale=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nh, hd), q.dtype),
         interpret=interpret,
+        name=DECODE_NAME,
     )(tables.astype(jnp.int32), lens.astype(jnp.int32), q3, k_pool, v_pool)
     return out[:, None] if squeezed else out

@@ -19,6 +19,7 @@ import: only one process may hold libtpu, and every xdist worker imports
 every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -92,9 +93,20 @@ def _compile(fn, sharding, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _assert_kernel(text, n=1):
-    assert text.count("tpu_custom_call") >= n, \
-        "compiled without the Pallas kernel"
+def _assert_kernel(text, *names):
+    """Each name stands in the left-hand side of a `tpu_custom_call`
+    instruction, wrapped by the transforms the kernel ran under
+    (`%transpose_jvp_pallas_flash_dq__.1`): that left-hand side is what a
+    device event in a trace is called, and what a metric looks for."""
+    assert names
+    lhs = [line.split(" = ", 1)[0] for line in text.splitlines()
+           if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(lhs) >= len(names), "compiled without the Pallas kernel"
+    for name in names:
+        # the name whole: after a `%` or `_`, before underscores and `.<n>`
+        # (benchmarks/readers/named_kernel_roofline.py looks for it so)
+        whole = re.compile(r"[%_]" + name + r"_*\.")
+        assert any(whole.search(l) for l in lhs), (name, lhs)
 
 
 # ------------------------------------------------------------ flash attention
@@ -104,7 +116,7 @@ _QKV_TRAIN = ((TRAIN_B, TRAIN_S, NH, HD), jnp.bfloat16)
 def test_flash_attention_forward(one_chip):
     text = _compile(lambda q, k, v: fa.flash_attention(q, k, v, causal=True),
                     one_chip, _QKV_TRAIN, _QKV_TRAIN, _QKV_TRAIN)
-    _assert_kernel(text)
+    _assert_kernel(text, fa.FWD_NAME)
 
 
 def test_flash_attention_backward(one_chip):
@@ -114,7 +126,7 @@ def test_flash_attention_backward(one_chip):
 
     text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
                     _QKV_TRAIN, _QKV_TRAIN, _QKV_TRAIN)
-    _assert_kernel(text, 3)      # forward, dq, dkv
+    _assert_kernel(text, fa.FWD_NAME, fa.DQ_NAME, fa.DKV_NAME)
 
 
 # ------------------------------------------------------------------ linear CE
@@ -129,7 +141,7 @@ def _ce_shapes(t):
 def test_linear_ce_forward(one_chip, t):
     text = _compile(lambda x, w, l: lce.linear_cross_entropy(x, w, l),
                     one_chip, *_ce_shapes(t))
-    _assert_kernel(text)
+    _assert_kernel(text, lce.FWD_NAME)
 
 
 def test_linear_ce_backward(one_chip):
@@ -138,7 +150,7 @@ def test_linear_ce_backward(one_chip):
 
     text = _compile(jax.grad(loss, argnums=(0, 1)), one_chip,
                     *_ce_shapes(TRAIN_B * TRAIN_S))
-    _assert_kernel(text)
+    _assert_kernel(text, lce.FWD_NAME)     # the backward is XLA's
 
 
 @pytest.mark.parametrize("h,itemsize", [(768, 2), (1024, 2), (2048, 2),
@@ -167,13 +179,13 @@ def _q(s):
 def test_paged_decode(one_chip):
     text = _compile(pa.paged_attention_kernel, one_chip,
                     _q(1), _POOL, _POOL, _TABLES, _ROWS)
-    _assert_kernel(text)
+    _assert_kernel(text, pa.DECODE_NAME)
 
 
 def test_paged_decode_int8(one_chip):
     text = _compile(pa.paged_attention_q8_kernel, one_chip,
                     _q(1), _CODES, _SCALES, _CODES, _SCALES, _TABLES, _ROWS)
-    _assert_kernel(text)
+    _assert_kernel(text, pa.DECODE_Q8_NAME)
 
 
 # S=128: suffix prefill at prompt_cap; S=4: a speculative verify window
@@ -181,14 +193,14 @@ def test_paged_decode_int8(one_chip):
 def test_paged_prefix(one_chip, s):
     text = _compile(pa.paged_prefix_attention_kernel, one_chip,
                     _q(s), _POOL, _POOL, _TABLES, _ROWS)
-    _assert_kernel(text)
+    _assert_kernel(text, pa.PREFIX_NAME)
 
 
 @pytest.mark.parametrize("s", [128, 4])
 def test_paged_prefix_int8(one_chip, s):
     text = _compile(pa.paged_prefix_attention_q8_kernel, one_chip,
                     _q(s), _CODES, _SCALES, _CODES, _SCALES, _TABLES, _ROWS)
-    _assert_kernel(text)
+    _assert_kernel(text, pa.PREFIX_Q8_NAME)
 
 
 # ------------------------------------------------- kernels under the mesh
@@ -204,7 +216,7 @@ def test_flash_under_dp_mp_mesh(mesh):
         return out.astype(jnp.float32).sum()
 
     text = _compile(jax.grad(loss, argnums=(0, 1, 2)), None, qkv, qkv, qkv)
-    _assert_kernel(text, 3)
+    _assert_kernel(text, fa.FWD_NAME, fa.DQ_NAME, fa.DKV_NAME)
     assert "all-gather" not in text and "all-reduce" not in text, \
         "batch rows and heads are independent: no collective belongs here"
 
@@ -223,7 +235,7 @@ def test_linear_ce_under_dp_mp_mesh(mesh):
         return lce.linear_cross_entropy(x, w, l).sum()
 
     text = _compile(jax.grad(loss, argnums=(0, 1)), None, *shapes)
-    _assert_kernel(text)
+    _assert_kernel(text, lce.FWD_NAME)
     assert f"bf16[{VOCAB},{HIDDEN}]" not in text, "W was gathered whole"
     assert "all-gather" not in text
 
@@ -241,7 +253,7 @@ def test_paged_kernels_under_mp_mesh(mesh, s):
                                                     rows),
         None, _q(s) + (heads,), _POOL + (heads,), _POOL + (heads,),
         _TABLES + (rep,), _ROWS + (rep,))
-    _assert_kernel(text)
+    _assert_kernel(text, pa.DECODE_NAME if s == 1 else pa.PREFIX_NAME)
     assert "all-gather" not in text
 
 
@@ -255,19 +267,21 @@ def test_fused_mha_with_dropout_backward(one_chip):
         out = fm.fused_mha(qkv, 12, dropout_p=0.1, dropout_seed=seed)
         return out.astype(jnp.float32).sum()
 
-    text = _compile(jax.grad(loss), one_chip,
+    # the backward kernel works the forward out again from qkv, so the
+    # forward kernel survives only where the value is asked for too
+    text = _compile(jax.value_and_grad(loss), one_chip,
                     ((32, 512, 3 * 768), jnp.bfloat16), ((), jnp.int32))
-    _assert_kernel(text)
+    _assert_kernel(text, fm.FWD_NAME, fm.BWD_NAME)
 
 
 def test_fused_mha_bias_backward(one_chip):
     def loss(qkv, bias):
         return fmb.fused_mha_bias(qkv, 3, bias).astype(jnp.float32).sum()
 
-    text = _compile(jax.grad(loss, argnums=(0, 1)), one_chip,
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1)), one_chip,
                     ((4096, 49, 3 * 96), jnp.bfloat16),
                     ((64, 3, 49, 49), jnp.float32))
-    _assert_kernel(text)
+    _assert_kernel(text, fmb.FWD_NAME, fmb.BWD_NAME)
 
 
 @pytest.mark.parametrize("w_layout,wshape", [("kn", (HIDDEN, 4 * HIDDEN)),
@@ -281,7 +295,7 @@ def test_int8_matmul(one_chip, monkeypatch, w_layout, wshape):
         lambda x, q, s: i8.int8_matmul(x, q, s, w_layout=w_layout),
         one_chip, ((SERVE_B, HIDDEN), jnp.bfloat16), (wshape, jnp.int8),
         ((n,), jnp.float32))
-    _assert_kernel(text)
+    _assert_kernel(text, i8.KERNEL_NAME)
 
 
 def test_layer_norm_backward(one_chip):
@@ -291,4 +305,41 @@ def test_layer_norm_backward(one_chip):
     text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
                     ((TRAIN_B * TRAIN_S, HIDDEN), jnp.bfloat16),
                     ((HIDDEN,), jnp.bfloat16), ((HIDDEN,), jnp.bfloat16))
-    _assert_kernel(text, 2)
+    _assert_kernel(text, ln.FWD_NAME, ln.BWD_NAME)
+
+
+def test_flash_attention_packed_backward(one_chip):
+    """The [B, S, nh*hd] layout: its three kernels carry
+    names of their own, so a trace tells them from the unpacked ones."""
+    qkv = ((TRAIN_B, TRAIN_S, NH * HD), jnp.bfloat16)
+
+    def loss(q, k, v):
+        out = fa.flash_attention_packed(q, k, v, NH, causal=True)
+        return out.astype(jnp.float32).sum()
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                    qkv, qkv, qkv)
+    _assert_kernel(text, fa.PACKED_FWD_NAME, fa.PACKED_DQ_NAME,
+                   fa.PACKED_DKV_NAME)
+
+
+# ------------------------------------------------------- the kernels' names
+def test_every_kernel_has_a_name_and_none_holds_another():
+    """A metric finds a kernel's events by looking for its name inside the
+    event's instruction name, so every `pl.pallas_call` of ops/pallas/
+    passes a constant `name=`, all start with `pallas_`, and no name is
+    part of another (`..._decode` against `..._decode_q8` would count the
+    int8 kernel's time under the bf16 one's)."""
+    import inspect
+    names, calls = [], 0
+    for mod in (fa, fm, fmb, i8, ln, lce, pa):
+        src = inspect.getsource(mod)
+        consts = re.findall(r"^([A-Z0-9_]*NAME) = ", src, re.M)
+        used = re.findall(r"^\s+name=(\w+),$", src, re.M)
+        calls += src.count("pl.pallas_call(")
+        assert sorted(consts) == sorted(used), (mod.__name__, consts, used)
+        names += [getattr(mod, c) for c in consts]
+    assert len(names) == calls == len(set(names))
+    assert all(re.fullmatch(r"pallas_[a-z0-9_]+", n) for n in names), names
+    for a in names:
+        assert not [b for b in names if a != b and a in b], a
