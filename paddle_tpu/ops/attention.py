@@ -497,7 +497,8 @@ def _use_paged_kernel():
     """Kernel-vs-reference routing, mirroring `_use_pallas`:
     PADDLE_TPU_PAGED=0 forces the jnp reference, =1 forces the Pallas
     kernel (opting a capable host in), unforced requires a TPU-class
-    platform. No shape constraints — the kernel is VPU-only."""
+    platform. No shape constraints: heads whose pages a DMA cannot slice
+    keep the slot-grid kernel (`pallas/paged_attention.py`)."""
     import os
     force = os.environ.get("PADDLE_TPU_PAGED")
     if force == "0":

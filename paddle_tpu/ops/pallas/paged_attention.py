@@ -7,39 +7,76 @@ scattered through one [num_blocks, block, H, D] pool, named by an int32
 block table. The XLA-visible alternative — gather the blocks into a
 contiguous [B, L, H, D] buffer, then attend — materializes the whole
 working set in HBM twice per step (`paged_attention_reference`, the
-CPU/tier-1 path). This kernel instead walks the block table directly:
+CPU/tier-1 path). The kernels here walk the block table instead.
+
+The decode kernel (`paged_attention_kernel`, one query token a row) walks
+each row's own pages, several a step (`_kernel_walk`; ISSUE 27):
+
+  grid (B,)      one program per batch row, in order. The pools stay in
+                 HBM (`pl.ANY`); the block table and `lens` are scalar-
+                 prefetched.
+  blocks         a row of `lens` tokens costs cdiv(lens, pages_per_step
+                 * bs) trips of a `fori_loop`, whatever the table's
+                 width; a dummy row (lens 1) costs one, a row of lens 0
+                 none. `_pages_per_step` takes the step from the shapes:
+                 what `_WALK_VMEM_BUDGET` holds of (K, V) x two slots (8
+                 pages = 128 tokens = 1 MiB a step for bf16 pages of
+                 16 x 16 x 128).
+  fetch          one `make_async_copy` per LIVE page of the block into a
+                 double-buffered VMEM slot; the row's next block, or the
+                 next row's first, is in flight while this one is
+                 computed. Table slots past a row's last page are never
+                 read: nothing past `lens` reaches the output (padding
+                 slots may point anywhere, page 0 may hold NaN). What a
+                 slot holds past the block's live pages is zeros or an
+                 earlier row's own page, and meets a zero probability.
+  compute        the block as a [T*nh, hd] matrix (row = (token, head)),
+                 straight from the slot in the pools' dtype: scores are
+                 q [nh, hd] x block^T -> [nh, T*nh] on the MXU, of which
+                 row h keeps its own head's lanes (and columns < lens);
+                 the online-softmax state is [nh, 1] for all heads at
+                 once; the value product is p [nh, T*nh] x block on the
+                 MXU. The MXU does nh times the needed multiply-adds and
+                 is idle otherwise; K and V never pass through the VPU.
+
+A DMA can slice a page out of a pool only where the page fills whole
+(8, 128) tiles of the pool's layout (`_pages_dma_sliceable`: heads % 8,
+head_dim % 128; 1.3B, 6.7B, 13B, and their halves under mp). Other heads
+(2.7B's 80, 125M's 12 x 64) keep the grid over (row, table slot), one
+page a program, VPU-only (`_kernel_slots`): its time follows the table's
+width, not the live KV. The int8 decode kernel (`_kernel_q8`) and the
+ragged multi-token kernels are that grid too:
 
   grid (B, MB)   one program per (batch row, table slot), MB innermost so
                  the online-softmax state lives in VMEM scratch across a
                  row's blocks (same accumulator pattern as
                  flash_attention.py);
   block fetch    the K/V BlockSpec index maps read the SCALAR-PREFETCHED
-                 block table — Pallas DMAs exactly the pool page the row
-                 needs next, so HBM traffic is the true KV bytes, not the
-                 padded envelope. Table padding entries are 0 (the trash
-                 block), and consecutive same-index fetches collapse in
-                 the pipeline, so invalid tail slots cost ~nothing;
+                 block table. Table padding entries are 0 (the trash
+                 block); a slot past `lens` skips its arithmetic
+                 (`pl.when`), not its grid step (~0.1 us each on v5e,
+                 PERF.md section 6, PR 27);
   masking        global column j*bs + i is attendable iff < lens[row];
-                 blocks entirely past lens skip their accumulate
-                 (`pl.when`), partial blocks mask per column.
+                 partial blocks mask per column.
 
-Compute is deliberately VPU-only (broadcast-multiply-reduce per head, the
-q vector is 1 token — there is no MXU shape here worth a relayout); decode
-attention is KV-bandwidth-bound, so the fetch pattern IS the optimization.
-Numerics: f32 scores/softmax/accumulation whatever the pool dtype (like
-the other Pallas kernels here — the XLA static-cache path instead stores
-scores in the model dtype, so bf16 models' kernel-vs-reference parity is
+Numerics: f32 scores/softmax/accumulation whatever the pool dtype, one
+rounding to the output's dtype (the walk hands the MXU a bf16 pool's
+probabilities as (hi, lo) bf16 halves, and asks float32 pools for the
+float32 contraction). The XLA static-cache path instead stores scores in
+the model dtype, so bf16 models' kernel-vs-reference parity is
 approximate; chip_smoke.py's serve phase states what agreement it
-requires on the chip instead).
+requires on the chip instead.
 
 Rows with lens == 0 (dummy batch slots) output zeros (the reference path
 outputs masked-uniform garbage instead — both are dropped by callers, and
 the parity tests compare live rows).
 
-CPU validation runs this kernel in interpret mode (tests);
+CPU validation runs these kernels in interpret mode (tests; the walk with
+`_pages_dma_sliceable` patched, since toy heads fill no tile);
 tests/test_chip_compile.py compiles every variant for the described chip
-at GPT-1.3B widths, and chip_smoke.py's kernel phase compares them with
-the references on the chip.
+at GPT-1.3B widths and at the serving cells' geometry, and chip_smoke.py's
+kernel phase and tools/validate_paged_tpu.py compare them with the
+references on the chip.
 """
 from __future__ import annotations
 
@@ -68,7 +105,7 @@ def _page_map(ndim):
     return lambda bi, j, tables, aux: (tables[bi, j],) + (_i0(),) * (ndim - 1)
 
 
-def _kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
+def _kernel_slots(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
             m_sc, l_sc, acc_sc, *, scale, nh, bs, n_slots):
     b, j = pl.program_id(0), pl.program_id(1)
 
@@ -402,6 +439,155 @@ def paged_prefix_attention_q8_kernel(q, kc_pool, ks_pool, vc_pool, vs_pool,
 
 DECODE_NAME = "pallas_paged_decode"
 
+# K and V blocks, two slots each: what the walk keeps in VMEM. Well under
+# the 16 MiB scoped limit, beside the [nh, T*nh] f32 score tiles.
+_WALK_VMEM_BUDGET = 2 * 1024 * 1024
+_MAX_PAGES_PER_STEP = 16
+
+
+def _pages_per_step(page_bytes, table_width):
+    """Pages one step of the walk fetches and computes: as many as the
+    VMEM budget holds of (K, V) x two slots, at most the table."""
+    fit = _WALK_VMEM_BUDGET // (4 * page_bytes)
+    return max(1, min(_MAX_PAGES_PER_STEP, table_width, fit))
+
+
+def _mxu_rows(x, dtype):
+    """x [r, n] as the left operand of a matmul against a block of the
+    pools, in the pools' dtype and without losing x's bits: a float32 x
+    meets a narrower pool as its (hi, lo) halves stacked into [2r, n] (the
+    MXU streams rows; the block, its weights, is the cost). `_sum_rows`
+    adds the halves' products."""
+    if x.dtype == dtype or jnp.dtype(dtype).itemsize >= 4:
+        return x.astype(dtype)
+    x = x.astype(jnp.float32)
+    hi = x.astype(dtype)
+    lo = (x - hi.astype(jnp.float32)).astype(dtype)
+    return jnp.concatenate([hi, lo], axis=0)
+
+
+def _block_dot(x, block, contract):
+    """x [r, .] against a [w, hd] block of a pool, float32 out. float32
+    pools ask for the float32 contraction (the default rounds both sides
+    to bf16); Mosaic refuses that precision on bf16 tiles, which are
+    exact in one pass anyway."""
+    precision = (lax.Precision.HIGHEST if block.dtype == jnp.float32
+                 else None)
+    return lax.dot_general(x, block, (contract, ((), ())),
+                           precision=precision,
+                           preferred_element_type=jnp.float32)
+
+
+def _sum_rows(y, r):
+    """Undo `_mxu_rows` on a product: [k*r, n] -> [r, n]."""
+    return sum(y[i:i + r] for i in range(0, y.shape[0], r))
+
+
+def _kernel_walk(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
+                 k_buf, v_buf, sems, slot_ref, lane_ref, *, scale, bs, pps):
+    b, n_rows = pl.program_id(0), pl.num_programs(0)
+    mb = tables_ref.shape[1]
+    nh, hd = q_ref.shape[1], q_ref.shape[2]
+    t = pps * bs                                    # tokens a block holds
+    w = t * nh                                      # its (token, head) rows
+
+    def cdiv(a, d):                   # i32 throughout (Mosaic x64 rule)
+        return lax.div(a + (d - 1), jnp.int32(d))
+
+    def pages_of(row):
+        return jnp.minimum(cdiv(lens_ref[row], bs), mb)
+
+    def block_dmas(op, row, blk, slot, n_pages):
+        """"start" or "wait" the copies of block `blk` of `row`: its live
+        pages only, K and V on one semaphore each per slot."""
+        slot = jnp.asarray(slot, jnp.int32)         # (Mosaic x64 rule)
+        for i in range(pps):
+            @pl.when(blk * pps + i < n_pages)
+            def _():
+                page = tables_ref[row, blk * pps + i]
+                for j, (pool, buf) in enumerate(((k_hbm, k_buf),
+                                                 (v_hbm, v_buf))):
+                    getattr(pltpu.make_async_copy(
+                        pool.at[page], buf.at[slot, jnp.int32(i)],
+                        sems.at[jnp.int32(j), slot]), op)()
+
+    def fetch_first_of_next_row(slot):
+        nxt = jnp.minimum(b + 1, n_rows - 1)
+        @pl.when(b + 1 < n_rows)
+        def _():
+            block_dmas("start", nxt, 0, slot, pages_of(nxt))
+
+    n_pages = pages_of(b)
+    n_blocks = cdiv(n_pages, pps)
+    ln = jnp.minimum(lens_ref[b], mb * bs)
+
+    @pl.when(b == 0)
+    def _():
+        # row h of a score tile keeps the lanes of its own head: lane j is
+        # (token j // nh, head j % nh) of the block. Kept for every row
+        # as the lane's number where the head is the row's, else past
+        # every length: one compare a block then masks head and length
+        lane = lax.broadcasted_iota(jnp.int32, (nh, w), 1)
+        head = lax.broadcasted_iota(jnp.int32, (nh, w), 0)
+        lane_ref[...] = jnp.where(lax.rem(lane, jnp.int32(nh)) == head,
+                                  lane, jnp.int32(jnp.iinfo(jnp.int32).max))
+        # a block's pages past its row's last are not fetched, and what a
+        # slot holds there meets a probability of exactly 0: so it has to
+        # be finite. Zero once; after that it is some row's own page
+        v_buf[...] = jnp.zeros_like(v_buf)
+        slot_ref[0] = 0
+        block_dmas("start", 0, 0, 0, n_pages)   # nobody fetched ahead
+
+    slot0 = slot_ref[0]
+    q = _mxu_rows(q_ref[b], k_buf.dtype)            # [nh or 2 nh, hd]
+
+    def body(blk, carry):
+        m_prev, l_prev, acc = carry
+        slot = lax.rem(slot0 + blk, jnp.int32(2))
+
+        @pl.when(blk + 1 < n_blocks)
+        def _():
+            block_dmas("start", b, blk + 1, 1 - slot, n_pages)
+
+        @pl.when(blk + 1 == n_blocks)
+        def _():
+            fetch_first_of_next_row(1 - slot)
+
+        block_dmas("wait", b, blk, slot, n_pages)
+
+        k = k_buf[slot].reshape(w, hd)
+        v = v_buf[slot].reshape(w, hd)
+        s = _sum_rows(_block_dot(q, k, ((1,), (1,))), nh) * scale  # [nh, w]
+        keep = lane_ref[...] < (ln - blk * t) * nh
+        s = jnp.where(keep, s, jnp.asarray(_NEG, s.dtype))
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)                  # exactly 0 off `keep`
+        corr = jnp.exp(m_prev - m_new)
+        l_new = corr * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        pv = _block_dot(_mxu_rows(p, v.dtype), v, ((1,), (0,)))
+        return m_new, l_new, corr * acc + _sum_rows(pv, nh)
+
+    _, l, acc = lax.fori_loop(
+        jnp.int32(0), n_blocks, body,
+        (jnp.full((nh, 1), _NEG, jnp.float32),
+         jnp.zeros((nh, 1), jnp.float32),
+         jnp.zeros((nh, hd), jnp.float32)))
+    o_ref[b] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+    @pl.when(n_blocks == 0)
+    def _():                          # an empty row fetches ahead too
+        fetch_first_of_next_row(slot0)
+
+    slot_ref[0] = lax.rem(slot0 + n_blocks, jnp.int32(2))
+
+
+def _pages_dma_sliceable(nh, hd):
+    """Whether the chip's compiler lets a DMA slice one page out of a
+    [NB, bs, nh, hd] pool: the pool is tiled over (nh, hd) in HBM, and a
+    slice has to cover whole tiles (2.7B's heads of 80, 125M's 12 heads
+    of 64 do not)."""
+    return nh % 8 == 0 and hd % 128 == 0
+
 
 def paged_attention_kernel(q, k_pool, v_pool, tables, lens, *, scale=None,
                            interpret=False):
@@ -417,28 +603,54 @@ def paged_attention_kernel(q, k_pool, v_pool, tables, lens, *, scale=None,
     else:
         q3 = q
     b, nh, hd = q3.shape
-    nb, bs = k_pool.shape[0], k_pool.shape[1]
+    bs = k_pool.shape[1]
     mb = tables.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(hd)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, mb),
-        in_specs=[
-            pl.BlockSpec((1, nh, hd), _row_map(3)),
-            pl.BlockSpec((1, bs, nh, hd), _page_map(4)),
-            pl.BlockSpec((1, bs, nh, hd), _page_map(4)),
-        ],
-        out_specs=pl.BlockSpec((1, nh, hd), _row_map(3)),
-        scratch_shapes=[pltpu.VMEM((nh, 1), jnp.float32),
-                        pltpu.VMEM((nh, 1), jnp.float32),
-                        pltpu.VMEM((nh, hd), jnp.float32)],
-    )
+    if _pages_dma_sliceable(nh, hd):
+        pps = _pages_per_step(bs * nh * hd * k_pool.dtype.itemsize, mb)
+        # q and the output whole, once: 4 KiB a row is not worth a DMA
+        # and a wait in every program
+        rows = pl.BlockSpec((b, nh, hd),
+                            lambda bi, tables, lens: (_i0(), _i0(), _i0()))
+        pool = pl.BlockSpec(memory_space=pl.ANY)
+        kernel = functools.partial(_kernel_walk, scale=scale, bs=bs, pps=pps)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b,),
+            in_specs=[rows, pool, pool],
+            out_specs=rows,
+            scratch_shapes=[pltpu.VMEM((2, pps, bs, nh, hd), k_pool.dtype),
+                            pltpu.VMEM((2, pps, bs, nh, hd), v_pool.dtype),
+                            pltpu.SemaphoreType.DMA((2, 2)),
+                            pltpu.SMEM((1,), jnp.int32),
+                            pltpu.VMEM((nh, pps * bs * nh), jnp.int32)],
+        )
+        # rows in order: each fetches the next one's first block
+        semantics = ("arbitrary",)
+    else:
+        kernel = functools.partial(_kernel_slots, scale=scale, nh=nh, bs=bs,
+                                   n_slots=mb)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, mb),
+            in_specs=[
+                pl.BlockSpec((1, nh, hd), _row_map(3)),
+                pl.BlockSpec((1, bs, nh, hd), _page_map(4)),
+                pl.BlockSpec((1, bs, nh, hd), _page_map(4)),
+            ],
+            out_specs=pl.BlockSpec((1, nh, hd), _row_map(3)),
+            scratch_shapes=[pltpu.VMEM((nh, 1), jnp.float32),
+                            pltpu.VMEM((nh, 1), jnp.float32),
+                            pltpu.VMEM((nh, hd), jnp.float32)],
+        )
+        semantics = None
     out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, nh=nh, bs=bs, n_slots=mb),
+        kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nh, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
         name=DECODE_NAME,
     )(tables.astype(jnp.int32), lens.astype(jnp.int32), q3, k_pool, v_pool)

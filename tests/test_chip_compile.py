@@ -182,6 +182,46 @@ def test_paged_decode(one_chip):
     _assert_kernel(text, pa.DECODE_NAME)
 
 
+def test_paged_decode_at_the_serving_cells_geometry(one_chip):
+    """B 32, a table of 128, 3,072 pages of 16 tokens, 16 heads of 128
+    (benchmarks/workloads/serve-gpt3-1.3b-chat*.json). The by-shape
+    metric (benchmarks/metrics/paged_attention_roofline.*.json) knows the
+    kernel by what the compiled call shows: one result [B, heads, D], the
+    tables first, the two pools last; the by-name one by DECODE_NAME."""
+    b, mb, nb = 32, 128, 3072
+    pool = ((nb, KV_BLOCK, NH, HD), jnp.bfloat16)
+    text = _compile(pa.paged_attention_kernel, one_chip,
+                    ((b, 1, NH, HD), jnp.bfloat16), pool, pool,
+                    ((b, mb), jnp.int32), ((b,), jnp.int32))
+    _assert_kernel(text, pa.DECODE_NAME)
+    call, = [l for l in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l]
+    result, operands = re.match(
+        r"\s*(?:ROOT )?%\S+ = (\S+) custom-call\((.*?)\), custom_call_target",
+        call).groups()
+    assert result.startswith(f"bf16[{b},{NH},{HD}]"), result
+    shapes = dict(re.findall(r"(%\S+) = (\w+\[[\d,]*\])", text))
+    operands = [shapes[o.strip()] if o.strip() in shapes else o.strip()
+                for o in operands.split(", ")]
+    assert operands[0].startswith(f"s32[{b},{mb}]"), operands
+    pool_shape = f"bf16[{nb},{KV_BLOCK},{NH},{HD}]"
+    assert [o[:len(pool_shape)] for o in operands[-2:]] == [pool_shape] * 2, \
+        operands
+
+
+@pytest.mark.parametrize("nh,hd", [(32, 80), (12, 64)])
+def test_paged_decode_heads_no_dma_can_slice(one_chip, nh, hd):
+    """2.7B's heads of 80 and 125M's 12 heads of 64 do not fill the
+    (8, 128) tiles the pools are laid out in, and the compiler lets a DMA
+    slice whole tiles only: those keep the grid over the table's slots."""
+    assert not pa._pages_dma_sliceable(nh, hd)
+    pool = ((POOL_BLOCKS, KV_BLOCK, nh, hd), jnp.bfloat16)
+    text = _compile(pa.paged_attention_kernel, one_chip,
+                    ((SERVE_B, 1, nh, hd), jnp.bfloat16), pool, pool,
+                    _TABLES, _ROWS)
+    _assert_kernel(text, pa.DECODE_NAME)
+
+
 def test_paged_decode_int8(one_chip):
     text = _compile(pa.paged_attention_q8_kernel, one_chip,
                     _q(1), _CODES, _SCALES, _CODES, _SCALES, _TABLES, _ROWS)

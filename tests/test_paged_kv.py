@@ -10,8 +10,11 @@ slot-level continuous batching: a short request finishes early, frees its
 blocks immediately, and a queued request is spliced into the vacated slot
 mid-flight with ZERO recompiles.
 """
+import functools
+
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 import paddle_tpu as paddle
@@ -23,7 +26,9 @@ from paddle_tpu.ops.attention import (attention_reference,
                                       paged_attention_reference,
                                       paged_cache_write,
                                       paged_prefill_write)
+from paddle_tpu.ops.pallas import paged_attention as pa
 from paddle_tpu.ops.pallas.paged_attention import paged_attention_kernel
+from tools.validate_paged_tpu import ragged_case, ragged_cases
 
 
 # ------------------------------------------------------ block allocator
@@ -159,6 +164,70 @@ def test_paged_kernel_interpret_matches_reference():
     want = paged_attention_reference(q, kp, vp, tables, la)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+# The walk (the kernel of heads a DMA can slice: 8k heads of 128k lanes) in
+# interpret mode, where no tiling rule applies: the test says so for the
+# toy heads. Two tables: the serving cells' 128 slots of 16 tokens, and
+# 21 slots, which the 16 pages of a step do not divide.
+_walk_interpreted = jax.jit(functools.partial(paged_attention_kernel,
+                                              interpret=True))
+_WALK_TABLES = {"cell": dict(bs=16, mb=128), "odd": dict(bs=4, mb=21)}
+_WALK_CASES = [(t, name) for t, g in _WALK_TABLES.items()
+               for name in ragged_cases(g["bs"], 16, g["mb"])]
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("table,name", _WALK_CASES)
+def test_paged_walk_interpret_matches_reference(monkeypatch, table, name,
+                                                dtype):
+    """Every edge of the walk against the gather reference: a dummy row,
+    exactly one page, exactly one block, one token more, the whole table,
+    and a batch mixing them. Pages no row owns, page 0 among them (where
+    the table's padding points), hold NaN: nothing past `lens` may reach
+    the output."""
+    monkeypatch.setattr(pa, "_pages_dma_sliceable", lambda nh, hd: True)
+    g = _WALK_TABLES[table]
+    nh, hd = 2, 8
+    pps = pa._pages_per_step(g["bs"] * nh * hd * jnp.dtype(dtype).itemsize,
+                             g["mb"])
+    assert pps == 16 and (g["mb"] % pps == 0) == (table == "cell")
+    lens = ragged_cases(g["bs"], pps, g["mb"])[name]
+    lens = (lens * 8)[:8]               # one shape a table: one compile
+    q, kp, vp, tables, la = ragged_case(lens, nh=nh, hd=hd, dtype=dtype,
+                                        nb=1 + 4 * (g["mb"] + 1) + 3, **g)
+    got = _walk_interpreted(q, kp, vp, tables, la)
+    assert got.dtype == q.dtype
+    f32 = jnp.float32
+    want = paged_attention_reference(
+        q.astype(f32), jnp.nan_to_num(kp.astype(f32)),
+        jnp.nan_to_num(vp.astype(f32)), tables, la)
+    tol = 2e-5 if dtype == "float32" else 1e-2      # one bf16 rounding
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want), rtol=tol, atol=tol / 4)
+
+
+def test_paged_walk_only_where_a_dma_can_slice_a_page():
+    """The chip's compiler slices whole tiles: 1.3B's and 13B's heads
+    walk, 2.7B's heads of 80 and 125M's 12 of 64 keep the grid over the
+    table's slots (tests/test_chip_compile.py compiles both)."""
+    assert pa._pages_dma_sliceable(16, 128)
+    assert pa._pages_dma_sliceable(40, 128)
+    assert pa._pages_dma_sliceable(8, 128)          # 16 heads over mp=2
+    assert not pa._pages_dma_sliceable(32, 80)
+    assert not pa._pages_dma_sliceable(12, 64)
+
+
+@pytest.mark.parametrize("page_bytes,mb,want", [
+    (64 * 1024, 128, 8),        # the cells: bf16 16 x 16 x 128, 2 MiB
+    (128 * 1024, 128, 4),       # the same page in float32
+    (32 * 1024, 128, 16),       # 8 heads a shard: the cap
+    (64 * 1024, 6, 6),          # never wider than the table
+    (4 * 1024 * 1024, 64, 1),   # a page over the budget still walks
+])
+def test_pages_per_step_comes_from_the_shapes(page_bytes, mb, want):
+    assert pa._pages_per_step(page_bytes, mb) == want
+    assert 4 * want * page_bytes <= max(pa._WALK_VMEM_BUDGET, 4 * page_bytes)
 
 
 def test_prefill_write_matches_per_token_writes():

@@ -4,7 +4,10 @@ CPU CI exercises the Pallas kernels in interpret mode only (tests/
 test_paged_kv.py, tests/test_spec_decode.py); Mosaic compilation and the
 scalar-prefetched block-table fetch path are checked here on the chip:
   1. compiled kernel parity vs `paged_attention_reference` across ragged
-     lengths (incl. a row at an exact block boundary and a dummy row)
+     lengths (incl. a row at an exact block boundary and a dummy row),
+     then the decode walk's own edges at the benchmark cells' geometry
+     (`ragged_cases`: the cases tests/test_paged_kv.py runs in interpret
+     mode, here compiled: B 32, table 128, 3,072 pages of 16, 16 x 128)
   2. MULTI-TOKEN kernel parity (ISSUE 11) vs the gather reference across
      (k, block, start) shapes — k=1 degenerate, windows starting at and
      crossing block boundaries, serving-scale geometry
@@ -29,10 +32,15 @@ import jax
 import jax.numpy as jnp
 
 
+FAILED = []
+
+
 def check(name, ok, detail=""):
-    print(f"[{'PASS' if ok else 'FAIL'}] {name} {detail}")
+    """Every check runs; `main` exits 1 at the end if one failed (a chip
+    call is too dear to stop at the first)."""
+    print(f"[{'PASS' if ok else 'FAIL'}] {name} {detail}", flush=True)
     if not ok:
-        sys.exit(1)
+        FAILED.append(name)
 
 
 def kernel_parity(dtype, nh, hd, bs, tol):
@@ -59,6 +67,64 @@ def kernel_parity(dtype, nh, hd, bs, tol):
     err = np.abs(got[live] - want[live]).max()
     check(f"kernel parity {dtype} nh={nh} hd={hd} bs={bs}", err < tol,
           f"max err {err:.2e}")
+
+
+def ragged_cases(bs, pps, mb):
+    """name -> lens: the edges of a walk that takes `pps` pages of `bs`
+    tokens a step through a table of `mb` slots, and a batch mixing them
+    (dummy rows as the engine ships them: lens 1)."""
+    blk = pps * bs
+    edges = {"dummy": 1, "one_page": bs, "one_block": blk,
+             "block_plus_one": blk + 1, "whole_table": mb * bs}
+    cases = {name: (ln, 1) for name, ln in edges.items()}
+    cases["ragged"] = tuple(edges.values()) + (bs + 1, 1, blk - 1)
+    return cases
+
+
+def ragged_case(lens, *, bs, nh, hd, mb, dtype, nb=None, seed=0):
+    """(q, k_pool, v_pool, tables, lens) for rows of `lens` tokens whose
+    pages lie scattered through the pools. Table slots past a row's last
+    page point at page 0; page 0 and every page no row owns hold NaN, so
+    anything read past `lens` shows in the output."""
+    rng = np.random.RandomState(seed)
+    n_pages = [-(-ln // bs) for ln in lens]
+    nb = nb or 1 + sum(n_pages) + 3
+    owned = rng.permutation(np.arange(1, nb))[:sum(n_pages)]
+    pools = []
+    for _ in range(2):
+        pool = np.full((nb, bs, nh, hd), np.nan, np.float32)
+        pool[owned] = rng.randn(len(owned), bs, nh, hd) * 0.3
+        pools.append(jnp.asarray(pool, dtype))
+    tables = np.zeros((len(lens), mb), np.int32)
+    at = 0
+    for b, n in enumerate(n_pages):
+        tables[b, :n] = owned[at:at + n]
+        at += n
+    q = jnp.asarray(rng.randn(len(lens), 1, nh, hd) * 0.3, dtype)
+    return (q, pools[0], pools[1], jnp.asarray(tables),
+            jnp.asarray(lens, jnp.int32))
+
+
+def kernel_ragged_parity(dtype, tol, *, b=32, mb=128, nb=3072, bs=16, nh=16,
+                         hd=128):
+    """The decode kernel against the reference at the serving cells' own
+    geometry, on every edge of its walk."""
+    from paddle_tpu.ops.attention import paged_attention_reference
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    pps = pa._pages_per_step(bs * nh * hd * jnp.dtype(dtype).itemsize, mb)
+    for name, lens in ragged_cases(bs, pps, mb).items():
+        lens = (lens * b)[:b]                   # the cell's batch of 32
+        q, kp, vp, tables, la = ragged_case(lens, bs=bs, nh=nh, hd=hd,
+                                            mb=mb, dtype=dtype, nb=nb)
+        got = np.asarray(pa.paged_attention_kernel(q, kp, vp, tables, la),
+                         np.float32)
+        # the reference gathers the padded slots too, and 0 x NaN is NaN
+        want = np.asarray(paged_attention_reference(
+            q.astype(jnp.float32), jnp.nan_to_num(kp.astype(jnp.float32)),
+            jnp.nan_to_num(vp.astype(jnp.float32)), tables, la), np.float32)
+        err = np.abs(got - want).max()          # NaN fails the comparison
+        check(f"decode walk {name} {jnp.dtype(dtype).name} pages/step={pps}",
+              err < tol, f"max err {err:.2e}")
 
 
 def kernel_prefix_parity(dtype, nh, hd, bs, s, starts, tol):
@@ -206,6 +272,8 @@ def sharded_engine_parity(shards):
     ndev = len(jax.devices())
     check(f"--shards {shards}: enough local devices", shards <= ndev,
           f"({ndev} available)")
+    if shards > ndev:
+        return
     paddle.seed(0)
     cfg = GPTConfig(vocab_size=256, hidden_size=256, num_layers=2,
                     num_heads=max(4, shards),  # divisible head count
@@ -259,12 +327,17 @@ def main():
     kernel_parity(jnp.float32, nh=4, hd=64, bs=16, tol=2e-5)
     kernel_parity(jnp.bfloat16, nh=16, hd=128, bs=16, tol=2e-2)
     kernel_parity(jnp.bfloat16, nh=12, hd=64, bs=32, tol=2e-2)
+    kernel_ragged_parity(jnp.bfloat16, tol=2e-2)
+    kernel_ragged_parity(jnp.float32, tol=2e-4)
     # multi-token (ISSUE 11): k=1 degenerate, boundary-start, boundary-
     # crossing windows, serving-scale geometry + a wide prefill window
+    # float32 pools: the multi-token kernel's dots take the MXU's default
+    # precision (one bf16 pass: 1.8e-3 on the v5e, PR 27), unlike the
+    # decode walk's; ROADMAP S3 owns that kernel
     kernel_prefix_parity(jnp.float32, nh=4, hd=64, bs=16, s=1,
-                         starts=(40, 16, 0), tol=2e-5)
+                         starts=(40, 16, 0), tol=5e-3)
     kernel_prefix_parity(jnp.float32, nh=4, hd=64, bs=16, s=8,
-                         starts=(16, 13, 0), tol=2e-5)
+                         starts=(16, 13, 0), tol=5e-3)
     kernel_prefix_parity(jnp.bfloat16, nh=16, hd=128, bs=16, s=8,
                          starts=(32, 5, 0), tol=2e-2)
     kernel_prefix_parity(jnp.bfloat16, nh=16, hd=128, bs=16, s=64,
@@ -273,6 +346,9 @@ def main():
     spec_engine_parity()
     if args.shards:
         sharded_engine_parity(args.shards)
+    if FAILED:
+        print(f"{len(FAILED)} validation(s) FAILED: {FAILED}")
+        sys.exit(1)
     print("all paged serving validations passed")
 
 
