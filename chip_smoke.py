@@ -43,7 +43,10 @@ PROMPT_LENS = (128, 17, 3, 64, 100, 1, 33, 128)
 KERNEL_SHAPES = dict(nh=16, hd=128, hidden=2048, vocab=50304,
                      flash=(3, 2048), ce_tokens=6144,
                      pool_blocks=1024, kv_block=16, table_slots=64,
-                     serve_batch=8, prefix_s=(128, 4))
+                     serve_batch=8, prefix_s=(128, 4),
+                     # latent decode at the expert model's serving cell:
+                     # (heads, latent width, rank, page, table slots, pages)
+                     latent=(128, 576, 512, 128, 40, 1024))
 
 # --- tolerances -------------------------------------------------------------
 # Kernel vs its jnp reference on bf16 inputs, as max |got - want| over
@@ -174,7 +177,9 @@ def kernels_phase(shapes: dict, seed: int, *, interpret: bool = False,
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops import attention as A
+    from paddle_tpu.ops import latent_attention as L
     from paddle_tpu.ops.pallas import flash_attention as fa
+    from paddle_tpu.ops.pallas import latent_attention as la
     from paddle_tpu.ops.pallas import linear_ce as lce
     from paddle_tpu.ops.pallas import paged_attention as pa
 
@@ -298,6 +303,26 @@ def kernels_phase(shapes: dict, seed: int, *, interpret: bool = False,
              lambda q, *p: A.paged_prefix_attention_reference_q8(
                  q, *p, tables, start),
              [qs, *q8_pools])
+    del pools, codes, scales, q8_pools
+
+    # latent decode: every head against one latent a token, the row's own
+    # pages walked; rows empty, one token, mid-page, a full table, ragged
+    lh, lw, rank, lbs, lmb, lnb = shapes["latent"]
+    lcap = lmb * lbs
+    ltables = jnp.asarray(np.stack([
+        rng.permutation(np.arange(1, lnb))[:lmb] for _ in range(sb)]),
+        jnp.int32)
+    llens = jnp.asarray(
+        [0, 1, lbs + 1, lcap // 2, lcap, lcap // 3, 2 * lbs, lcap - 1][:sb],
+        jnp.int32)
+    case(f"latent decode q [{sb},{lh},{lw}] pool [{lnb},{lw},{lbs}]",
+         lambda q, p: la.latent_decode_kernel(
+             q, p, ltables, llens, rank=rank, scale=lw ** -0.5,
+             interpret=interpret)[1:],
+         lambda q, p: L.latent_paged_attention(
+             q[:, None], p, ltables, llens - 1, rank=rank,
+             scale=lw ** -0.5)[1:, 0],
+         [rnd(sb, lh, lw, scale=0.3), rnd(lnb, lw, lbs)])
     _require(not failures, f"kernel phase failed: {failures}")
 
 

@@ -7,8 +7,11 @@ hostage for padding and a finished row's slot stays pinned until the whole
 micro-batch drains. The TPU-idiomatic fix (Ragged Paged Attention,
 arxiv 2604.15464; PAPERS.md serving studies) is a BLOCK pool:
 
-  * device state is ONE fixed-shape tensor per layer —
-    ``[num_blocks, block_size, num_heads, head_dim]`` — plus an int32 block
+  * device state is ONE fixed-shape tensor per layer and plane —
+    ``[num_blocks, block_size, num_heads, head_dim]`` K and V planes, or
+    whatever one block of the model's cache is (`block_shapes`: a latent-
+    attention model pools one ``[num_blocks, latent_width, block_size]``
+    plane a layer) — plus an int32 block
     table ``[B, max_blocks]`` and a length vector ``[B]``. Every shape is
     pinned, so a single compiled executable serves ANY mix of request
     lengths (the whole point: zero steady-state recompiles);
@@ -54,15 +57,24 @@ class BlockPool:
     num_blocks : total blocks in the pool, INCLUDING the reserved trash
         block 0 (usable capacity is ``(num_blocks - 1) * block_size``).
     block_size : KV rows (token positions) per block.
-    num_layers / num_heads / head_dim / dtype : pool tensor geometry —
+    num_layers / block_shapes / head_axis / dtype : pool tensor geometry —
         normally taken from the model via :meth:`for_model`.
-    cache_dtype : None = pools carry the model dtype; "int8" = pools are
-        (codes int8, scale f32) pairs with per-(row, head) factored
-        scales (the static int8-KV trick ported to the paged pool).
+        `block_shapes` is the shape of ONE block of each plane a layer
+        pools: K and V, each ``(block_size, num_heads, head_dim)``, for
+        GPT; one ``(latent_width, block_size)`` for a latent-attention
+        model. `head_axis` is where a block has its heads (1 for K and V;
+        None: no head axis, so nothing to shard over mp and no per-(row,
+        head) scales). The allocator, reference counts, copy-on-write,
+        spill payloads and the prefix trie move whole blocks and never
+        look inside one.
+    cache_dtype : None = pools carry the model dtype; "int8" = every
+        plane is a (codes int8, scale f32) pair with per-(row, head)
+        factored scales (the static int8-KV trick ported to the paged
+        pool); needs a head axis.
     """
 
     def __init__(self, *, num_blocks: int, block_size: int,
-                 num_layers: int, num_heads: int, head_dim: int,
+                 num_layers: int, block_shapes, head_axis: int = None,
                  dtype="float32", cache_dtype=None):
         if num_blocks < 2:
             raise ValueError("num_blocks must be >= 2 (block 0 is the "
@@ -72,11 +84,18 @@ class BlockPool:
         if cache_dtype not in (None, "int8"):
             raise ValueError(f"cache_dtype must be None or 'int8'; "
                              f"got {cache_dtype!r}")
+        if cache_dtype is not None and head_axis is None:
+            raise ValueError("cache_dtype='int8' scales per (row, head): "
+                             "it needs planes with a head axis")
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.num_layers = int(num_layers)
-        self.num_heads = int(num_heads)
-        self.head_dim = int(head_dim)
+        self.block_shapes = tuple(tuple(int(d) for d in shp)
+                                  for shp in block_shapes)
+        self.head_axis = head_axis
+        # heads of a plane (None: the planes have no head axis)
+        self.num_heads = None if head_axis is None \
+            else self.block_shapes[0][head_axis]
         self.dtype = dtype
         self.cache_dtype = cache_dtype
         # LIFO free list: recently freed blocks are re-issued first, which
@@ -92,58 +111,56 @@ class BlockPool:
     @classmethod
     def for_model(cls, model, *, num_blocks: int, block_size: int,
                   cache_dtype=None):
-        """Geometry from a GPTForCausalLM-style model (config + dtype)."""
-        cfg = model.config
-        dtype = model.gpt.wte.weight._data.dtype
+        """Geometry from the model's `kv_pool_geometry(block_size)`:
+        num_layers, block_shapes, head_axis and dtype."""
         return cls(num_blocks=num_blocks, block_size=block_size,
-                   num_layers=cfg.num_layers, num_heads=cfg.num_heads,
-                   head_dim=cfg.head_dim, dtype=dtype,
-                   cache_dtype=cache_dtype)
+                   cache_dtype=cache_dtype,
+                   **model.kv_pool_geometry(block_size))
 
     def make_pools(self):
-        """Fresh zeroed device pools. Per layer: ``(k_pool, v_pool)``
-        each ``[num_blocks, block_size, num_heads, head_dim]`` — or, for
-        ``cache_dtype="int8"``, ``(k_codes, k_scale, v_codes, v_scale)``
-        with int8 ``[NB, bs, H, D]`` codes and f32 ``[NB, bs, H]``
-        factored scales. The caller owns them from here — jitted steps
-        donate and replace them, so the allocator deliberately does NOT
-        keep a reference.
+        """Fresh zeroed device pools. Per layer one array a plane, each
+        ``[num_blocks, *block_shape]``: ``(k_pool, v_pool)`` of
+        ``[NB, bs, H, D]`` for GPT — or, for ``cache_dtype="int8"``, a
+        (codes, scale) pair a plane, ``(k_codes, k_scale, v_codes,
+        v_scale)`` with int8 ``[NB, bs, H, D]`` codes and f32
+        ``[NB, bs, H]`` factored scales. The caller owns them from here —
+        jitted steps donate and replace them, so the allocator
+        deliberately does NOT keep a reference.
 
         Under an active mesh with an ``mp`` axis (multi-chip serving,
-        ISSUE 16) the pools come up HEAD-SHARDED: ``[NB, bs, H, D]``
-        with H split over mp (int8 scale pools ``[NB, bs, H]`` shard the
-        same axis, so codes and their scales always live on the same
-        shard). Block tables, the free list, refcounts, and every other
-        allocator structure stay host-side and replicated — sharding is
-        purely a device-placement property of the arrays."""
+        ISSUE 16) the pools come up HEAD-SHARDED: the head axis split
+        over mp (int8 scale pools shard the same axis, so codes and
+        their scales always live on the same shard). Block tables, the
+        free list, refcounts, and every other allocator structure stay
+        host-side and replicated — sharding is purely a device-placement
+        property of the arrays."""
+        import jax
         import jax.numpy as jnp
         from ..distributed import mesh as _mesh
         mp = _mesh.mesh_axis_size("mp")
-        if mp > 1 and self.num_heads % mp != 0:
+        if mp > 1 and (self.num_heads is None or self.num_heads % mp != 0):
             raise ValueError(
-                f"head-sharded pools need num_heads divisible by the mp "
-                f"axis; got num_heads={self.num_heads}, mp={mp}")
-        pool_sh = _mesh.named_sharding(None, None, "mp", None)
-        scale_sh = _mesh.named_sharding(None, None, "mp")
+                f"pools shard their head axis over mp: they need planes "
+                f"with a head axis divisible by the mp axis; got "
+                f"block_shapes={self.block_shapes}, head_axis="
+                f"{self.head_axis}, mp={mp}")
 
-        def _zeros(shape, dtype, sh):
-            z = jnp.zeros(shape, dtype)
-            if sh is not None:
-                import jax
-                z = jax.device_put(z, sh)
+        def _zeros(shape, dtype):
+            z = jnp.zeros((self.num_blocks,) + shape, dtype)
+            if self.head_axis is not None:
+                spec = [None] * len(shape)
+                spec[self.head_axis] = "mp"
+                sh = _mesh.named_sharding(None, *spec)
+                if sh is not None:
+                    z = jax.device_put(z, sh)
             return z
 
-        shape = (self.num_blocks, self.block_size,
-                 self.num_heads, self.head_dim)
-        if self.cache_dtype == "int8":
-            sshape = shape[:3]
-            return [(_zeros(shape, jnp.int8, pool_sh),
-                     _zeros(sshape, jnp.float32, scale_sh),
-                     _zeros(shape, jnp.int8, pool_sh),
-                     _zeros(sshape, jnp.float32, scale_sh))
-                    for _ in range(self.num_layers)]
-        return [(_zeros(shape, self.dtype, pool_sh),
-                 _zeros(shape, self.dtype, pool_sh))
+        def _plane(shape):
+            if self.cache_dtype == "int8":      # scale: per (row, head)
+                return (_zeros(shape, jnp.int8),
+                        _zeros(shape[:-1], jnp.float32))
+            return (_zeros(shape, self.dtype),)
+        return [sum((_plane(shp) for shp in self.block_shapes), ())
                 for _ in range(self.num_layers)]
 
     # ------------------------------------------------------------- sizing
@@ -161,15 +178,15 @@ class BlockPool:
 
     @property
     def bytes_per_block(self) -> int:
-        """HBM bytes ONE block pins across every layer's K+V pools — the
+        """HBM bytes ONE block pins across every layer's planes — the
         unit the prefix cache's byte budget is charged in."""
-        import numpy as np_
-        rows = self.block_size * self.num_heads
-        if self.cache_dtype == "int8":
-            per = rows * self.head_dim * 1 + rows * 4    # codes + f32 scale
+        if self.cache_dtype == "int8":          # codes + f32 scale
+            per = sum(math.prod(shp) + 4 * math.prod(shp[:-1])
+                      for shp in self.block_shapes)
         else:
-            per = rows * self.head_dim * np_.dtype(self.dtype).itemsize
-        return 2 * per * self.num_layers                 # K and V
+            per = sum(math.prod(shp) for shp in self.block_shapes) \
+                * np.dtype(self.dtype).itemsize
+        return per * self.num_layers
 
     @property
     def free_blocks(self) -> int:
@@ -338,7 +355,7 @@ class BlockPool:
     def _spill_sig(self) -> tuple:
         from ..distributed import mesh as _mesh
         return ("spill_scatter", self.num_blocks, self.block_size,
-                self.num_layers, self.num_heads, self.head_dim,
+                self.num_layers, self.block_shapes,
                 str(self.dtype), self.cache_dtype,
                 _mesh.mesh_axis_size("mp"))
 
@@ -402,10 +419,12 @@ class BlockPool:
                              vs.at[blk].set(scales[2 * i + 1]))
                             for i, (kc, ks, vc, vs) in enumerate(pools)]
             else:
+                n = len(self.block_shapes)
+
                 def run(pools, blk, planes):
-                    return [(k.at[blk].set(planes[2 * i]),
-                             v.at[blk].set(planes[2 * i + 1]))
-                            for i, (k, v) in enumerate(pools)]
+                    return [tuple(p.at[blk].set(planes[n * i + j])
+                                  for j, p in enumerate(layer))
+                            for i, layer in enumerate(pools)]
             fn = _SPILL_SCATTER_CACHE[sig] = jax.jit(
                 run, donate_argnums=(0,))
         return fn(pools, np.int32(block), *payload)

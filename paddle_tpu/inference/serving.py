@@ -560,7 +560,21 @@ class ServingMetrics:
                                       "from the draft-model hook",
                  "mem_pressure_episodes": "admission stalls waiting on "
                                           "KV blocks (one per episode, "
-                                          "not per step)"}
+                                          "not per step)",
+                 # expert layers (models that hold a share of a sparse
+                 # layer's experts; absent otherwise)
+                 "expert_assignments_here": "(token, expert) assignments "
+                                            "computed by experts held "
+                                            "here",
+                 "expert_assignments_made": "(token, expert) assignments "
+                                            "the routers made (top-k a "
+                                            "live token and layer)",
+                 "experts_hit": "held experts that got >= 1 token, "
+                                "summed over expert-layer calls",
+                 "expert_tokens_max": "tokens of the fullest held expert, "
+                                      "summed over expert-layer calls",
+                 "expert_layer_calls": "expert-layer calls (one a layer "
+                                       "and model step)"}
         for name, value in self.counters.items():
             lines.extend(counter_lines(prefix, f"{name}_total", value,
                                        helps[name]))
@@ -833,7 +847,15 @@ class ServingEngine:
                  clock: Callable[[], float] = time.monotonic):
         self.model = model
         self.config = config
+        # a model family that implements only part of the engine's surface
+        # (models/pangu_moe.py: the paged path alone) refuses the rest here
+        if hasattr(model, "check_serving_config"):
+            model.check_serving_config(config)
         self.metrics = metrics or ServingMetrics()
+        # counters the model's own steps report (expert routing): they
+        # arrive with each decode chunk and finished prefill
+        for name in getattr(model, "step_counter_names", ()):
+            self.metrics.counters.setdefault(name, 0)
         # fault injection (ISSUE 12 Injector): fired at serving.step so
         # the OOM post-mortem path is rehearsable without a real OOM
         self.chaos = chaos
@@ -1827,6 +1849,7 @@ class ServingEngine:
             done_new[pf] = self._done[pf]      # real state must survive
             self._pending = pend_new
             self._done = done_new
+            self._take_step_counters()
             finished: List[Request] = []
             out_tokens = 0
             for slot in live:
@@ -1912,6 +1935,15 @@ class ServingEngine:
                 finished.append(req)
         return finished, ran
 
+    def _take_step_counters(self):
+        """Add what the model's steps counted since the last read (expert
+        routing; a few floats beside tokens that were just read, so the
+        copy waits for nothing) to the metrics' counters."""
+        pop = getattr(self.model, "pop_step_counters", None)
+        if pop is not None:
+            for name, value in pop().items():
+                self.metrics.counters[name] += value
+
     def _complete_prefill(self, slot: int, req: Request, tok: int,
                           tp: float) -> bool:
         """Shared prefill-completion bookkeeping (one-shot admission AND
@@ -1922,6 +1954,7 @@ class ServingEngine:
         again)."""
         cfg = self.config
         plen = req.prompt_len
+        self._take_step_counters()
         req.trace.t_prefill_done = tp
         req.trace.t_first_token = tp  # sampled with the prefill
         self._lens[slot] = plen

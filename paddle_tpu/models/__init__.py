@@ -10,6 +10,7 @@ from .gpt import (  # noqa: F401
     GPTConfig, GPTModel, GPTForCausalLM, GPTPretrainingCriterion,
     gpt_config, PRESETS as GPT_PRESETS,
 )
+from .pangu_moe import PanguMoEConfig, PanguMoEForCausalLM  # noqa: F401
 from .gpt_stacked import (  # noqa: F401
     GPTStackedForCausalLM,
 )

@@ -1260,6 +1260,16 @@ class GPTForCausalLM(Layer):
         return Tensor(toks), new_state
 
     # ------------------------------------------------ paged-pool serving
+    def kv_pool_geometry(self, block_size: int) -> dict:
+        """What `BlockPool.for_model` builds: K and V planes a layer, a
+        block of each `[block_size, num_heads, head_dim]`, heads on axis 1
+        (sharded over mp; int8 pools scale per row and head)."""
+        cfg = self.config
+        return {"num_layers": cfg.num_layers,
+                "block_shapes": ((block_size, cfg.num_heads,
+                                  cfg.head_dim),) * 2,
+                "head_axis": 1, "dtype": self.gpt.wte.weight._data.dtype}
+
     def prefill_paged(self, input_ids, prompt_lens, pools, block_tables,
                       temperature: float = 0.0, top_k: int = 0,
                       top_p: float = 1.0, seed: int = 0,

@@ -42,6 +42,7 @@ from .layers.rnn import (  # noqa: F401
     SimpleRNN, LSTM, GRU, RNN, SimpleRNNCell, LSTMCell, GRUCell,
 )
 from .layers.decode import BeamSearchDecoder, dynamic_decode  # noqa: F401
+from .layers.experts import HeldExperts  # noqa: F401
 
 from ..core.tensor import Parameter  # noqa: F401
 

@@ -36,6 +36,7 @@ from paddle_tpu.ops.pallas import fused_mha_bias as fmb
 from paddle_tpu.ops.pallas import int8_matmul as i8
 from paddle_tpu.ops.pallas import layer_norm as ln
 from paddle_tpu.ops.pallas import linear_ce as lce
+from paddle_tpu.ops.pallas import latent_attention as la
 from paddle_tpu.ops.pallas import paged_attention as pa
 
 # GPT-1.3B geometry (models/gpt.py gpt3-1.3b) and chip_smoke.py's shapes
@@ -363,6 +364,21 @@ def test_flash_attention_packed_backward(one_chip):
                    fa.PACKED_DKV_NAME)
 
 
+def test_latent_decode_at_the_serving_cells_geometry(one_chip):
+    """B 128, 128 heads against one latent of 576 a token, a table of 40
+    pages of 128 tokens, 6,144 pages [576, 128]
+    (benchmarks/workloads/serve-pangu-ultra-moe-agent-over.json): a page
+    with its tokens along the lanes is what a DMA can slice, 576 = 4.5
+    lane tiles the other way round is not."""
+    text = _compile(
+        lambda q, p, t, l: la.latent_decode_kernel(q, p, t, l, rank=512,
+                                                   scale=192 ** -0.5),
+        one_chip, ((128, 128, 576), jnp.bfloat16),
+        ((6144, 576, 128), jnp.bfloat16), ((128, 40), jnp.int32),
+        ((128,), jnp.int32))
+    _assert_kernel(text, la.LATENT_DECODE_NAME)
+
+
 # ------------------------------------------------------- the kernels' names
 def test_every_kernel_has_a_name_and_none_holds_another():
     """A metric finds a kernel's events by looking for its name inside the
@@ -372,7 +388,7 @@ def test_every_kernel_has_a_name_and_none_holds_another():
     int8 kernel's time under the bf16 one's)."""
     import inspect
     names, calls = [], 0
-    for mod in (fa, fm, fmb, i8, ln, lce, pa):
+    for mod in (fa, fm, fmb, i8, ln, lce, pa, la):
         src = inspect.getsource(mod)
         consts = re.findall(r"^([A-Z0-9_]*NAME) = ", src, re.M)
         used = re.findall(r"^\s+name=(\w+),$", src, re.M)

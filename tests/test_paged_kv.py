@@ -36,7 +36,7 @@ from tools.validate_paged_tpu import ragged_case, ragged_cases
 class TestBlockPool:
     def _pool(self, blocks=8, bs=4):
         return BlockPool(num_blocks=blocks, block_size=bs, num_layers=1,
-                         num_heads=2, head_dim=4)
+                         block_shapes=((bs, 2, 4),) * 2, head_axis=1)
 
     def test_alloc_free_reuse(self):
         p = self._pool()
@@ -93,7 +93,7 @@ class TestBlockPool:
     def test_validation(self):
         with pytest.raises(ValueError, match="num_blocks"):
             BlockPool(num_blocks=1, block_size=4, num_layers=1,
-                      num_heads=1, head_dim=4)
+                      block_shapes=((4, 1, 4),) * 2, head_axis=1)
         p = self._pool()
         pools = p.make_pools()
         assert len(pools) == 1
@@ -112,7 +112,7 @@ def _build_pool(lens, bs=4, nh=4, hd=8, mb=4, seed=0):
     kp = jnp.zeros(pool_shape, jnp.float32)
     vp = jnp.zeros(pool_shape, jnp.float32)
     alloc = BlockPool(num_blocks=nb, block_size=bs, num_layers=1,
-                      num_heads=nh, head_dim=hd)
+                      block_shapes=((bs, nh, hd),) * 2, head_axis=1)
     tables = np.zeros((B, mb), np.int32)
     L = mb * bs
     K = rng.randn(B, L, nh, hd).astype(np.float32) * 0.3

@@ -35,7 +35,7 @@ from paddle_tpu.ops.pallas.paged_attention import paged_attention_q8_kernel
 
 def _pool(blocks=10, bs=4, **kw):
     return BlockPool(num_blocks=blocks, block_size=bs, num_layers=2,
-                     num_heads=2, head_dim=4, **kw)
+                     block_shapes=((bs, 2, 4),) * 2, head_axis=1, **kw)
 
 
 class TestRefcountedPool:

@@ -117,7 +117,7 @@ def test_multi_token_q8_kernel_interpret_parity(s, start):
 class TestLookupContinuation:
     def _pool(self):
         return BlockPool(num_blocks=32, block_size=4, num_layers=1,
-                         num_heads=1, head_dim=2)
+                         block_shapes=((4, 1, 2),) * 2, head_axis=1)
 
     def test_continuation_after_full_blocks(self):
         p = self._pool()
