@@ -26,15 +26,21 @@ Gemma-on-TPU serving studies, PAPERS.md). This module provides:
                   first token → finish); each engine phase also runs under
                   a `jax.profiler.TraceAnnotation` so a device trace gives
                   kernel time, and every gap between kernels, to what the
-                  host was doing. One engine step is "serving/step". Both
-                  engines open "serving/prefill" and "serving/decode"
-                  around a model call and the read that waits for it. The
-                  paged engine splits the two into "serving/prefill_launch"
-                  / "serving/prefill_read" and "serving/decode_launch" /
-                  "serving/decode_read", and names its own work between
-                  two calls: "serving/admit" (one queued request: trie
-                  match, block mapping, copy-on-write, slot set-up),
-                  "serving/decode_prep" (KV snapshot, state shipped),
+                  host was doing. One engine step is "serving/step". The
+                  padded engine opens "serving/prefill" and
+                  "serving/decode" around a model call and the read that
+                  waits for it. The paged engine launches before it reads
+                  (`_step_paged`): "serving/prefill" encloses one window's
+                  "serving/prefill_launch"; "serving/decode" encloses
+                  "serving/decode_launch" (this step's chunk enqueued)
+                  and then the reads of what the step BEFORE launched,
+                  "serving/prefill_read" (its final windows' first
+                  tokens) and "serving/decode_read" (its chunk's tokens:
+                  the host waits here while the chip runs this step's
+                  chunk). Its own work between two calls: "serving/admit"
+                  (one queued request: trie match, block mapping,
+                  copy-on-write, slot set-up), "serving/decode_prep" (KV
+                  snapshot, the chunk's inputs staged),
                   "serving/deliver" (tokens handed to requests, finished
                   rows freed and recorded), "serving/bookkeep" (batch
                   gauges, compile accounting, the monitor's step). PERF.md
@@ -67,6 +73,12 @@ for anything that fits the pool, and the same two guarantees hold:
 greedy output bit-identical to generate_static_ragged per row, zero jit
 cache misses after the {prefill, decode} pair compiles once. The pool
 buffers are DONATED through every call, so XLA updates KV in place.
+A paged step launches before it reads: this step's prefill windows and
+decode chunk are enqueued, with each row's pending token picked on the
+device from the last chunk's outputs, and only then are the tokens of the
+step before copied to the host, while the chip works (`_step_paged`).
+Tokens reach a request one step after their launch; a budget's end is
+known at launch, an EOS one chunk late (`eos_late_rows`).
 (Bit-identity caveat: bf16 models on TPU route through the f32-score
 Pallas paged kernel while the static path stores bf16 scores, so parity
 there is approximate near argmax ties — exact whenever both sides share
@@ -119,6 +131,7 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 import jax
+import jax.numpy as jnp
 
 from ..profiler import StepMonitor
 from ..profiler.monitor import _jit_cache_misses
@@ -133,6 +146,25 @@ _logger = logging.getLogger("paddle_tpu.inference.serving")
 _span = jax.profiler.TraceAnnotation
 
 
+# where a paged row's pending token and done flag are read from when a
+# decode chunk is launched (ServingEngine._stage_decode_inputs)
+_SRC_HOST, _SRC_CHUNK, _SRC_FIRST = 0, 1, 2
+
+
+@dataclass
+class _Flight:
+    """What one paged engine step launched and has not read: the final
+    prefill windows' first tokens and one decode chunk's tokens, still on
+    the device. The step after reads them (`ServingEngine._land`)."""
+    # (slot, request, first-token Tensor [1], event name, launch time)
+    firsts: List[tuple] = field(default_factory=list)
+    # (slot, request, tokens of the chunk that count against its budget)
+    rows: List[tuple] = field(default_factory=list)
+    toks: object = None          # the chunk's tokens, Tensor [B, chunk]
+    t0: float = 0.0              # the chunk's launch time
+    stats: object = None         # the model's step counters of these calls
+
+
 # --------------------------------------------------------------- requests
 
 @dataclass
@@ -141,10 +173,11 @@ class RequestTrace:
 
     enqueue → admit is queue wait; admit → prefill_done is the batched
     prefill; first_token lands after the 1-token decode chunk; finish is
-    stamped at the end of the decode CHUNK in which the row hit EOS or its
-    budget (every chunk ends in a host sync, so chunk granularity is free
-    — a short request co-batched with long ones is not charged for decode
-    chunks past its own completion).
+    stamped when the host has read the decode CHUNK in which the row hit
+    EOS or its budget (chunk granularity — a short request co-batched
+    with long ones is not charged for decode chunks past its own
+    completion). The paged engine stamps first_token and finish with the
+    time the tokens reached the host, one step after their launch.
 
     `trace_id` names the request across export surfaces (JSONL rows, the
     /tracez ring, logs); `events` are the engine-call WINDOWS the request
@@ -243,7 +276,8 @@ class Request:
     @property
     def n_produced(self) -> int:
         """Tokens the paged engine has delivered to this request so far
-        (0 until its first lands); `n_out` is final, this one moves."""
+        (0 until its first lands; tokens launched and not yet read by the
+        host do not count); `n_out` is final, this one moves."""
         return getattr(self, "_produced", 0)
 
     def record(self) -> dict:
@@ -351,7 +385,14 @@ class ServingMetrics:
                          "spec_drafts_model": 0,
                          # HBM ledger (ISSUE 18): oversubscription-wait
                          # episodes (admission stalled on the free list)
-                         "mem_pressure_episodes": 0}
+                         "mem_pressure_episodes": 0,
+                         # the paged step's overlap: decode chunks
+                         # launched, those launched while an earlier one
+                         # was still unread, and chunk rows spent on a
+                         # request whose EOS the host had not seen yet
+                         "decode_chunks": 0,
+                         "decode_chunks_overlapped": 0,
+                         "eos_late_rows": 0}
         self.gauges = {"queue_depth": 0, "inflight": 0,
                        "batch_fill_ratio": None, "kv_occupancy": None,
                        "kv_slots_occupancy": None,
@@ -561,6 +602,13 @@ class ServingMetrics:
                  "mem_pressure_episodes": "admission stalls waiting on "
                                           "KV blocks (one per episode, "
                                           "not per step)",
+                 "decode_chunks": "paged decode chunks launched",
+                 "decode_chunks_overlapped": "paged decode chunks launched "
+                                             "while an earlier chunk's "
+                                             "tokens were still unread",
+                 "eos_late_rows": "rows of a decode chunk launched after "
+                                  "their request's EOS and before the "
+                                  "host read it (tokens discarded)",
                  # expert layers (models that hold a share of a sparse
                  # layer's experts; absent otherwise)
                  "expert_assignments_here": "(token, expert) assignments "
@@ -871,7 +919,7 @@ class ServingEngine:
         self._fingerprint = None
         # the monitor carries batch step timing + the recompile guard; the
         # serving engine measures dispatch-to-sync walls (truthful: every
-        # chunk ends in a host sync for the token handoff)
+        # step ends in a host sync for the token handoff)
         self.monitor = monitor or StepMonitor(unit="tokens/s",
                                               track_memory=False)
         self.clock = clock
@@ -931,8 +979,9 @@ class ServingEngine:
             # batch slot runs its own request; EOS/budget frees the slot's
             # blocks immediately and _admit_paged splices a queued request
             # into the vacancy mid-flight. Device state is the donated
-            # per-layer pools; tables/lens/pending/done are tiny host
-            # vectors edited per slot and shipped with every chunk.
+            # per-layer pools; tables/lens are tiny host vectors edited
+            # per slot and shipped with every chunk; pending/done are the
+            # host's only for rows it has read (`_src`).
             from .kv_cache import BlockPool
             B, MB = config.max_batch, config.table_width
             self._pool = BlockPool.for_model(model,
@@ -973,13 +1022,31 @@ class ServingEngine:
                         reader=lambda blk: self._pool.read_block(
                             self._pools, blk),
                         writer=self._spill_write)
-            # chunked prefill (ISSUE 11): next prompt position to prefill
-            # per slot; -1 = not mid-prefill (a plain decode row)
+            # next prompt position to prefill per slot (one window of
+            # prefill_chunk tokens a step, or the whole uncached suffix
+            # at once); -1 = not in prefill (a decode row)
             self._prefill_pos = np.full((B,), -1, np.int64)
+            # where each row's pending token and done flag live when the
+            # next chunk is launched: on the host (_pending / _done), in
+            # the last launched chunk's outputs, or in the first-token
+            # vector a final prefill window wrote (`_stage_decode_inputs`)
+            self._src = np.full((B,), _SRC_HOST, np.int32)
+            self._flight: Optional[_Flight] = None   # launched, unread
+            self._reset_device_carry()
             # spec decoding (ISSUE 11): the optional draft-model hook —
             # the trie (when present) drafts first, the hook fills misses
             self._draft_fn = config.spec_draft \
                 if callable(config.spec_draft) else None
+
+    def _reset_device_carry(self):
+        """Placeholders for what a launch reads from the launch before
+        it, in the shapes and dtypes the real outputs have, so the first
+        chunk runs the executables every later chunk runs."""
+        B, c = self.config.max_batch, self.config.decode_chunk
+        with self._mesh_scope():
+            self._toks_prev = jnp.zeros((B, c), jnp.int64)
+            self._done_prev = jnp.zeros((B,), bool)
+            self._firsts = jnp.zeros((B,), jnp.int32)
 
     def _mesh_scope(self):
         """Activate the engine's private mp mesh (multi-chip serving) for
@@ -1002,11 +1069,12 @@ class ServingEngine:
     @property
     def busy(self) -> bool:
         """Work remains: queued requests, or (paged) live batch slots
-        still decoding — the public loop condition drain() and external
-        replayers (tools/serve_bench.py) share."""
+        still decoding, or a launched chunk whose tokens the host has not
+        read — the public loop condition drain() and external replayers
+        (tools/serve_bench.py) share."""
         # host-side deque/slot-list reads  # lint: allow(tracer-bool)
-        return bool(self._queue) or \
-            (self.config.paged and bool(self._live()))  # lint: allow(tracer-bool)
+        return bool(self._queue) or (self.config.paged and (
+            bool(self._live()) or self._flight is not None))  # lint: allow(tracer-bool)
 
     def preflight(self, prompt, max_new_tokens: Optional[int] = None):
         """Static admission check (analysis.recompile): Findings for
@@ -1372,16 +1440,30 @@ class ServingEngine:
         return [i for i, r in enumerate(self._slots) if r is not None]
 
     def _step_paged(self) -> List[Request]:
-        """One paged engine step: splice queued requests into free slots
-        (per-slot prefill into fresh blocks), then run ONE decode chunk
-        over the live batch; rows hitting EOS/budget free their blocks
-        immediately. Executable set = {prefill [1, cap], decode [B, c]} —
-        both compile once, so a steady mixed-length loop adds zero jit
-        cache misses however requests arrive."""
+        """One paged engine step: admit, launch, land.
+
+        Queued requests are spliced into free slots; every slot in
+        prefill gets its next window and the decodable rows one decode
+        chunk, all enqueued without a read; only then the host reads
+        what the step BEFORE launched (`_land`), while the chip runs what
+        this one did. A chunk's inputs never wait for the host: lengths,
+        budgets and blocks are known at launch, and each row's pending
+        token and done flag are picked on the device from the previous
+        chunk's outputs (`_stage_decode_inputs`). So a request's tokens
+        reach the host one step after their chunk was launched, a row's
+        EOS is seen one chunk late (it rides that chunk as a done row,
+        `eos_late_rows`), and a budget's end, known beforehand, costs
+        nothing. A speculative engine reads each call before the next:
+        the accepted count sets a row's next length.
+
+        Executable set = {prefill [1, cap or chunk], decode [B, c], two
+        small helpers} — each compiles once, so a steady mixed-length
+        loop adds zero jit cache misses however requests arrive."""
         miss0 = _jit_cache_misses()
         ran = set()
         self.monitor.begin_step()
         out_tokens = 0
+        live_entry: List[int] = []
         # spill/rehydrate device calls ride admission (match/evict): tag
         # them into `ran` so their one-time compiles are warmup, not
         # shape churn, in the recompile accounting below
@@ -1394,22 +1476,34 @@ class ServingEngine:
                 # RESOURCE_EXHAUSTED unwinding out of the chunk call
                 self.chaos.fire("serving.step", step=self._batch_id,
                                 queue_depth=len(self._queue))
-            finished, expired, admit_ran = self._admit_paged()
-            ran |= admit_ran
-            pf_done, pf_ran = self._advance_prefill()
-            ran |= pf_ran
-            finished.extend(pf_done)
-            live_entry = self._decodable()
-            if live_entry:
-                if self.config.spec_decode:
-                    chunk_done, out_tokens, dec_ran = \
-                        self._decode_chunk_spec(live_entry)
-                    ran |= dec_ran
-                else:
-                    chunk_done, out_tokens = self._decode_chunk_paged(
-                        live_entry)
-                    ran.add("decode")
-                finished.extend(chunk_done)
+            expired = self._admit_paged(ran)
+            flight = _Flight()
+            self._launch_prefills(flight, ran)
+            if self.config.spec_decode:
+                finished, _ = self._land(flight)
+                live_entry = self._decodable()
+                if live_entry:
+                    chunk_done, out_tokens = self._decode_chunk_spec(
+                        live_entry, ran)
+                    finished.extend(chunk_done)
+            else:
+                live_entry = self._decodable()
+                if live_entry:
+                    with _span("serving/decode_prep"):
+                        self._snapshot_kv()
+                        staged = self._stage_decode_inputs(live_entry)
+                with _span("serving/decode"):
+                    if live_entry:
+                        self._launch_decode(flight, live_entry, staged)
+                        ran.add("decode")
+                    prev, self._flight = \
+                        self._flight, self._close_flight(flight)
+                    landed = self._read_flight(prev)
+                finished, out_tokens = self._deliver(prev, landed)
+            if ran and not live_entry:
+                # a step without a chunk (admission, prefill windows)
+                # still reports the pool as it left it
+                self._snapshot_kv()
         except BaseException as step_exc:
             # OOM forensics (ISSUE 18): dump the census BEFORE the
             # recovery below resets the pool — the artifact must show the
@@ -1444,18 +1538,20 @@ class ServingEngine:
                 self._prefix.clear(release=False)
             self._pool.reset()
             self._pools = self._pool.make_pools()
+            # what was launched and not read belongs to the failed slots
+            self._flight = None
+            self._reset_device_carry()
             self.metrics.gauges["inflight"] = 0
             self.monitor.end_step(items=0)
             raise
         with _span("serving/bookkeep"):
             self.metrics.gauges["inflight"] = len(self._live())
             if ran:
-                # gauges describe the step's micro-batch: fill = rows live
-                # at decode-chunk entry (instant admission-finishes recycle
-                # one slot sequentially, so cap admission-only steps at
-                # capacity); occupancy is snapshotted at chunk entry too —
-                # the state the step actually served, not the post-free
-                # emptiness
+                # gauges describe the step's micro-batch: fill = rows of
+                # the chunk it launched (a step without one: the requests
+                # its landing finished, budget-1 / instant-EOS traffic);
+                # occupancy is snapshotted at chunk entry too — the state
+                # the step actually served, not the post-free emptiness
                 n_real = len(live_entry) if live_entry else \
                     min(len(finished), len(self._slots))
                 kv_tokens, kv_slots, kv_shared = self._kv_snapshot
@@ -1488,31 +1584,64 @@ class ServingEngine:
         self._lens[slot] = 0
         self._pending[slot] = 0
         self._done[slot] = True
+        self._src[slot] = _SRC_HOST
         self._shared_tok[slot] = 0
         self._prefill_pos[slot] = -1
 
     def _decodable(self) -> List[int]:
-        """Live slots whose prefill completed — the decode batch. Rows
-        still mid-(chunked-)prefill are excluded and neutralized in the
-        shipped device state (`_ship_decode_state`)."""
-        return [i for i in self._live() if self._prefill_pos[i] < 0]
+        """The next decode chunk's rows: live slots whose prefill has
+        been launched to its end and whose budget the chunks launched so
+        far do not exhaust. The others ride as neutral rows
+        (`_stage_decode_inputs`): a row in prefill, and a row whose last
+        tokens are launched and not read yet."""
+        return [i for i in self._live() if self._prefill_pos[i] < 0
+                and self._slots[i]._launched < self._slots[i].max_new_tokens]
 
-    def _ship_decode_state(self):
-        """The decode/verify-call view of the slot state: rows still
-        mid-chunked-prefill ship a trash table row + done=True so the
-        fixed-[B] call cannot write into (or attend) their in-progress
-        blocks; their real host state is untouched."""
-        pf = self._prefill_pos >= 0
-        if not pf.any():
-            return self._tables, self._lens, self._pending, self._done
-        tables = self._tables.copy()
-        tables[pf] = 0
-        lens = self._lens.copy()
-        lens[pf] = 0
-        pending = self._pending.copy()
-        pending[pf] = 0
-        done = self._done.copy()
-        done[pf] = True
+    def _device_helper(self, name: str, build):
+        """One of the engine's two small fixed-shape programs, kept in
+        the model's compiled-runner cache like the model's own (a build
+        counts as a jit cache miss; the graph lint sees the call)."""
+        from ..distributed import mesh as _dist_mesh
+        cfg = self.config
+        sig = (name, cfg.max_batch, cfg.decode_chunk, cfg.eos_token_id,
+               _dist_mesh.mesh_axis_size("mp"))
+        return self.model._gen_cache_get(sig, lambda: jax.jit(build))
+
+    def _stage_decode_inputs(self, live: List[int]):
+        """(tables, lens, pending, done) of a decode or verify call over
+        the rows `live`; every other row is neutral (trash table row,
+        done), so the fixed-[B] call can neither write into nor attend
+        the blocks of a slot in prefill or of one waiting for its last
+        read. Tables and lengths are the host's, copied, so later slot
+        edits cannot reach a call still in flight. `pending` and `done`
+        are picked ON THE DEVICE, row by row: from the last launched
+        chunk's last column and done flags (a row that rode it: the host
+        has not read them yet), from the first token a final prefill
+        window wrote (done if it is EOS), or from the host's own values
+        (a zero-prefill admission, a row the host has read). Nothing
+        here waits for the chip."""
+        eos = self.config.eos_token_id
+        ride = np.zeros((len(self._slots),), bool)
+        ride[live] = True
+        tables = np.where(ride[:, None], self._tables, 0)
+        lens = np.where(ride, self._lens, 0)
+        src = np.where(ride, self._src, _SRC_HOST)
+        pending_h = np.where(ride, self._pending, 0)
+        done_h = np.where(ride, self._done, True)
+
+        def stage(toks_prev, done_prev, firsts, src, pending_h, done_h):
+            chunk, first = src == _SRC_CHUNK, src == _SRC_FIRST
+            pending = jnp.where(chunk, toks_prev[:, -1].astype(jnp.int32),
+                                jnp.where(first, firsts, pending_h))
+            first_done = jnp.zeros_like(done_h) if eos is None \
+                else firsts == eos
+            done = jnp.where(chunk, done_prev,
+                             jnp.where(first, first_done, done_h))
+            return pending, done
+
+        pending, done = self._device_helper("paged_stage", stage)(
+            self._toks_prev, self._done_prev, self._firsts, src,
+            pending_h, done_h)
         return tables, lens, pending, done
 
     def _kv_physical(self):
@@ -1636,13 +1765,13 @@ class ServingEngine:
         fn = self.model._gen_cache_get(sig, build)
         self._pools = fn(self._pools, np.int32(src), np.int32(dst))
 
-    def _admit_paged(self):
+    def _admit_paged(self, ran: set) -> List[Request]:
         """Fill every free slot from the queue: consult the prefix trie,
-        map shared blocks / allocate fresh ones, prefill what the cache
-        does not already hold ([1, cap] — one fixed executable per mode),
-        splice the row into the live decode batch. Returns (finished,
-        expired, ran_tags) — a budget-1 or instant-EOS request can finish
-        here without ever joining a decode chunk.
+        map shared blocks / allocate fresh ones, set the slot up. No
+        model call is made here: what the cache does not already hold is
+        prefilled by `_launch_prefills`, this step and after. Returns
+        the requests whose queue deadline expired; device calls made
+        (copy-on-write) are tagged into `ran`.
 
         Prefix-cache admission (ISSUE 10) splits three ways on the
         matched full-block token count t vs the prompt length plen:
@@ -1662,12 +1791,10 @@ class ServingEngine:
                            shared blocks are never mutated.
 
         Every admitted prompt's full blocks are inserted into the trie
-        afterwards (dedup'd), so the NEXT identical prefix hits."""
-        cfg = self.config
+        once its last window is launched (dedup'd), so the NEXT identical
+        prefix hits."""
         bs = self._pool.block_size
-        finished: List[Request] = []
         expired: List[Request] = []
-        ran = set()
         free = [i for i, r in enumerate(self._slots) if r is None]
         while self._queue and free:
             with _span("serving/admit"):
@@ -1725,14 +1852,19 @@ class ServingEngine:
                 req.status = "active"
                 req.trace.t_admit = now
                 req.trace.batch_id = self._batch_id
-                # install into the slot BEFORE the device call: if prefill
+                # install into the slot BEFORE any device call: if one
                 # dies mid-flight, _step_paged's handler finds the request
                 # here and records it as status="error" — the engine's
                 # in-flight accounting contract
                 self._slots[slot] = req
-                table_row = self._pool.table_row(req.id, self._tables.shape[1])
-                self._tables[slot] = table_row
+                self._tables[slot] = self._pool.table_row(
+                    req.id, self._tables.shape[1])
                 self._shared_tok[slot] = len(shared) * bs
+                # tokens the host has read / tokens launched: the budget
+                # is kept against the second, so a chunk is sized before
+                # the one before it is read
+                req._chunks = []
+                req._produced = req._launched = 0
                 # probe admissions (ISSUE 19) stay out of the cache-efficiency
                 # counters: a prober's hit/miss variants are DESIGNED to
                 # always hit / always miss, so counting them would turn the
@@ -1750,8 +1882,6 @@ class ServingEngine:
                     self._lens[slot] = plen - 1
                     self._pending[slot] = int(req.prompt[plen - 1])
                     self._done[slot] = False
-                    req._chunks = []
-                    req._produced = 0
                     req.trace.t_prefill_done = now   # nothing to prefill
                     if not req.probe:
                         self.metrics.counters["prefill_tokens_saved"] += \
@@ -1760,136 +1890,37 @@ class ServingEngine:
                     # written KV here (the pending re-decode hasn't run), so
                     # the insert must not cache any fresh block yet
                     self._insert_prefix(req, blocks, t)
-                elif cfg.prefill_chunk is not None:
-                    # chunked prefill (ISSUE 11 satellite): admission only
-                    # installs the slot — _advance_prefill runs one
-                    # [1, prefill_chunk] window per engine step from position
-                    # t, so a cap-length prompt costs cap/chunk STEPS of
-                    # bounded work instead of one monopolizing call, and the
-                    # decode batch keeps stepping between windows. The slot's
-                    # decode state stays neutral (lens 0 / done) until the
-                    # final window samples the first token.
-                    self._prefill_pos[slot] = t
-                    req._chunks = []
-                    req._produced = 0
-                    if t and not req.probe:
-                        self.metrics.counters["prefill_tokens_saved"] += t
                 else:
-                    suffix = plen - t
-                    ids = np.full((1, cfg.prompt_cap), cfg.pad_token_id,
-                                  dtype=np.int64)
-                    ids[0, :suffix] = req.prompt[t:]
-                    start = None if t == 0 else np.asarray([t], np.int32)  # lint: allow(tracer-asarray)
-                    t_pf0 = self.clock()
-                    with _span("serving/prefill"):
-                        with _span("serving/prefill_launch"):
-                            self._pools, first = self.model.prefill_paged(
-                                ids, np.asarray([suffix], np.int32),  # lint: allow(tracer-asarray)
-                                self._pools, table_row[None],
-                                temperature=cfg.temperature,
-                                top_k=cfg.top_k, top_p=cfg.top_p,
-                                seed=cfg.seed + self._calls,
-                                weight_dtype=cfg.weight_dtype,
-                                cache_dtype=cfg.cache_dtype, start=start)
-                        with _span("serving/prefill_read"):
-                            tok = int(np.asarray(first.numpy())[0])  # lint: allow(tracer-asarray)
-                    self._calls += 1
-                    ran.add("prefill" if t == 0 else "prefix_prefill")
-                    req.trace.events.append(
-                        ("prefill" if t == 0 else "suffix_prefill",
-                         t_pf0, self.clock()))
+                    # `_launch_prefills` takes it from position t: one
+                    # [1, prefill_chunk] window a step (ISSUE 11 satellite:
+                    # a cap-length prompt costs cap/chunk STEPS of bounded
+                    # work and the decode batch keeps stepping between
+                    # windows), or the whole suffix in one [1, cap] call.
+                    # The slot's decode state stays neutral until the last
+                    # window samples the first token.
+                    self._prefill_pos[slot] = t
                     if t and not req.probe:
                         self.metrics.counters["prefill_tokens_saved"] += t
-                    if self._complete_prefill(slot, req, tok, self.clock()):
-                        finished.append(req)
-                        free.insert(0, slot)
                 self._batch_id += 1
         if not self._queue:
             # waiting head left some other way (deadline expiry, error
             # recovery draining the queue): close the episode truthfully
             self._mem_pressure_exit()
         self.metrics.gauges["queue_depth"] = len(self._queue)
-        if ran:
-            # admission-only steps (budget-1 / instant-EOS traffic) still
-            # report the post-admission pool state; a following decode
-            # chunk overwrites this with its own entry snapshot
-            self._snapshot_kv()
-        return finished, expired, ran
+        return expired
 
-    def _decode_chunk_paged(self, live: List[int]):
-        """One fixed-shape decode chunk over the whole slot batch (dummy
-        rows write the trash block and are ignored); finish + free every
-        row that hit EOS or its budget. Returns (finished, real tokens)."""
+    def _launch_prefills(self, flight: _Flight, ran: set):
+        """Enqueue the next prefill window of every slot in prefill; no
+        result is read. With `prefill_chunk` a window is [1, chunk] tokens
+        from the slot's offset (the offset is DATA through the start-form
+        executable: ONE program serves every (offset, remainder) of every
+        prompt length); without, the whole uncached suffix goes in one
+        [1, prompt_cap] call. A slot's LAST window samples the request's
+        first token: it stays on the device, written into the first-token
+        vector the next chunk's `pending` is picked from, and is read
+        with the flight (`_land`); intermediate windows' samples are
+        never read at all. The row joins this step's decode chunk."""
         cfg = self.config
-        c = cfg.decode_chunk
-        with _span("serving/decode_prep"):
-            self._snapshot_kv()
-            tables, lens, pending, done = self._ship_decode_state()
-        t_c0 = self.clock()
-        with _span("serving/decode"):
-            with _span("serving/decode_launch"):
-                toks, self._pools, _, done_d = self.model.decode_paged(
-                    self._pools, tables, lens, pending,
-                    done, c, temperature=cfg.temperature,
-                    top_k=cfg.top_k, top_p=cfg.top_p,
-                    seed=cfg.seed + self._calls,
-                    eos_token_id=cfg.eos_token_id,
-                    weight_dtype=cfg.weight_dtype,
-                    cache_dtype=cfg.cache_dtype)
-            with _span("serving/decode_read"):
-                arr = np.asarray(toks.numpy())      # host sync per chunk  # lint: allow(tracer-asarray)
-        self._calls += 1
-        t = self.clock()
-        with _span("serving/deliver"):
-            pend_new = arr[:, -1].astype(np.int32)
-            done_new = np.array(done_d)        # copy: slot edits need a
-            #                                    writable host array
-            pf = self._prefill_pos >= 0        # mid-prefill rows rode as
-            pend_new[pf] = self._pending[pf]   # neutralized dummies — their
-            done_new[pf] = self._done[pf]      # real state must survive
-            self._pending = pend_new
-            self._done = done_new
-            self._take_step_counters()
-            finished: List[Request] = []
-            out_tokens = 0
-            for slot in live:
-                req = self._slots[slot]
-                req.trace.events.append(("decode", t_c0, t))
-                take = min(c, req.max_new_tokens - req._produced)
-                req._chunks.append(arr[slot, :take])
-                req._produced += take
-                out_tokens += take
-                if req.trace.t_first_token is None:
-                    # zero-prefill admission (prefix cache): this chunk's
-                    # first token IS the request's first token — TTFT was
-                    # one decode step, measured not estimated
-                    req.trace.t_first_token = t
-                self._lens[slot] += c     # device wrote c rows regardless
-                # EOS scan covers only the FRESH slice: earlier chunks were
-                # checked when they landed (an EOS there already finished the
-                # row), so the per-generation host cost stays O(n)
-                row_done = req._produced >= req.max_new_tokens or \
-                    _hit_eos(arr[slot, :take], cfg.eos_token_id)
-                if row_done:
-                    self._finish_paged_row(slot, t)
-                    finished.append(req)
-        return finished, out_tokens
-
-    def _advance_prefill(self):
-        """One [1, prefill_chunk] prefill window for every slot mid-
-        chunked-prefill (ISSUE 11 satellite). The window offset is DATA
-        through the start-form prefill executable, so ONE [1, chunk]
-        program serves every (offset, remainder) of every prompt length
-        — zero new executables however prompts are sized. The final
-        window's sampled token is the request's first token (its last
-        real column is the prompt's last token) and the row joins the
-        next decode chunk. Returns (finished, ran_tags) — a budget-1 /
-        instant-EOS request can finish the moment its prefill lands."""
-        cfg = self.config
-        finished: List[Request] = []
-        ran = set()
-        if cfg.prefill_chunk is None:
-            return finished, ran
         pc = cfg.prefill_chunk
         for slot in self._live():
             off = int(self._prefill_pos[slot])
@@ -1897,78 +1928,195 @@ class ServingEngine:
                 continue
             req = self._slots[slot]
             plen = req.prompt_len
-            clen = min(pc, plen - off)
+            width = cfg.prompt_cap if pc is None else pc
+            clen = min(width, plen - off)
             final = off + clen >= plen
-            ids = np.full((1, pc), cfg.pad_token_id, dtype=np.int64)
+            ids = np.full((1, width), cfg.pad_token_id, dtype=np.int64)
             ids[0, :clen] = req.prompt[off:off + clen]
+            # the one-shot forms keep their two executables (absolute
+            # positions for a whole prompt, offset for a suffix)
+            start = None if pc is None and off == 0 \
+                else np.asarray([off], np.int32)  # lint: allow(tracer-asarray)
+            name = "prefill_chunk" if pc is not None else \
+                "prefill" if off == 0 else "suffix_prefill"
             t_pf0 = self.clock()
             with _span("serving/prefill"):
                 with _span("serving/prefill_launch"):
                     self._pools, first = self.model.prefill_paged(
                         ids, np.asarray([clen], np.int32),  # lint: allow(tracer-asarray)
-                        self._pools, self._tables[slot][None],
+                        self._pools, self._tables[slot][None].copy(),
                         temperature=cfg.temperature, top_k=cfg.top_k,
                         top_p=cfg.top_p, seed=cfg.seed + self._calls,
                         weight_dtype=cfg.weight_dtype,
-                        cache_dtype=cfg.cache_dtype,
-                        start=np.asarray([off], np.int32))  # lint: allow(tracer-asarray)
-                # only the FINAL window's sampled token is meaningful —
-                # syncing the intermediate ones would serialize every
-                # window on a host round-trip for a value that gets
-                # discarded (exactly the long-prompt stall chunked
-                # prefill exists to remove)
-                tok = 0
-                if final:
-                    with _span("serving/prefill_read"):
-                        tok = int(np.asarray(first.numpy())[0])  # lint: allow(tracer-asarray)
+                        cache_dtype=cfg.cache_dtype, start=start)
+                    if final:
+                        self._firsts = self._device_helper(
+                            "paged_put_first",
+                            lambda firsts, i, tok: firsts.at[i].set(tok[0])
+                        )(self._firsts, np.int32(slot), first._data)
             self._calls += 1
-            ran.add("prefill_chunk")
-            req.trace.events.append(("prefill_chunk", t_pf0,
-                                     self.clock()))
-            off += clen
+            ran.add("prefix_prefill" if name == "suffix_prefill" else name)
             if not final:
-                self._prefill_pos[slot] = off
+                req.trace.events.append((name, t_pf0, self.clock()))
+                self._prefill_pos[slot] = off + clen
                 continue
-            # prefill complete: the slot becomes a decode row
+            # every prompt row is written (enqueued): the slot is a decode
+            # row from here, and its full blocks can be shared. Whoever
+            # reads them does so in a later call on the same pools.
             self._prefill_pos[slot] = -1
-            if self._complete_prefill(slot, req, tok, self.clock()):
-                finished.append(req)
-        return finished, ran
+            self._lens[slot] = plen
+            self._src[slot] = _SRC_FIRST
+            req._launched = 1
+            self._insert_prefix(req, self._pool.owned(req.id), plen)
+            flight.firsts.append((slot, req, first, name, t_pf0))
 
-    def _take_step_counters(self):
-        """Add what the model's steps counted since the last read (expert
-        routing; a few floats beside tokens that were just read, so the
-        copy waits for nothing) to the metrics' counters."""
-        pop = getattr(self.model, "pop_step_counters", None)
-        if pop is not None:
-            for name, value in pop().items():
+    def _launch_decode(self, flight: _Flight, live: List[int], staged):
+        """Enqueue one fixed-shape decode chunk over the whole slot batch
+        (rows outside `live` write the trash block and are ignored) and
+        book what the host knows without reading it: every row's KV grew
+        by the chunk, and `take` of its tokens count against the row's
+        budget."""
+        cfg = self.config
+        c = cfg.decode_chunk
+        tables, lens, pending, done = staged
+        mt = self.metrics.counters
+        mt["decode_chunks"] += 1
+        if self._flight is not None and self._flight.toks is not None:
+            mt["decode_chunks_overlapped"] += 1
+        flight.t0 = self.clock()
+        with _span("serving/decode_launch"):
+            flight.toks, self._pools, _, self._done_prev = \
+                self.model.decode_paged(
+                    self._pools, tables, lens, pending, done, c,
+                    temperature=cfg.temperature, top_k=cfg.top_k,
+                    top_p=cfg.top_p, seed=cfg.seed + self._calls,
+                    eos_token_id=cfg.eos_token_id,
+                    weight_dtype=cfg.weight_dtype,
+                    cache_dtype=cfg.cache_dtype)
+        self._calls += 1
+        self._toks_prev = flight.toks._data
+        for slot in live:
+            req = self._slots[slot]
+            take = min(c, req.max_new_tokens - req._launched)
+            req._launched += take
+            flight.rows.append((slot, req, take))
+            self._lens[slot] += c     # device wrote c rows regardless
+            self._src[slot] = _SRC_CHUNK
+
+    def _close_flight(self, flight: _Flight) -> Optional[_Flight]:
+        """Seal what a step launched: None if nothing of it will be read
+        (intermediate prefill windows only). The model's step counters of
+        these calls are taken as the device array they are, and the
+        copies to the host are started."""
+        if not flight.firsts and flight.toks is None:
+            return None
+        detach = getattr(self.model, "detach_step_counters", None)
+        if detach is not None:
+            flight.stats = detach()
+        for t in [f[2] for f in flight.firsts] + [flight.toks]:
+            if t is not None:
+                t._data.copy_to_host_async()
+        return flight
+
+    def _read_flight(self, flight: Optional[_Flight]):
+        """Wait for a flight's results and copy them to the host: the
+        first tokens of its final prefill windows, then its chunk's
+        tokens (device order), each stamped with the time it arrived.
+        Returns (first tokens, their time, chunk tokens or None, their
+        time), or None for no flight."""
+        if flight is None:
+            return None
+        firsts, arr = [], None
+        if flight.firsts:
+            with _span("serving/prefill_read"):
+                firsts = [int(np.asarray(f[2].numpy())[0])  # lint: allow(tracer-asarray)
+                          for f in flight.firsts]
+        t_first = self.clock()
+        if flight.toks is not None:
+            with _span("serving/decode_read"):
+                arr = np.asarray(flight.toks.numpy())  # lint: allow(tracer-asarray)
+        t_chunk = self.clock()
+        if flight.stats is not None:
+            # a few floats beside tokens that were just read: no wait
+            names = self.model.step_counter_names
+            for name, value in zip(names, np.asarray(flight.stats).tolist()):  # lint: allow(tracer-asarray)
                 self.metrics.counters[name] += value
+        return firsts, t_first, arr, t_chunk
+
+    def _deliver(self, flight: Optional[_Flight], landed):
+        """Hand a read flight's tokens to their requests; finish + free
+        every row that hit EOS or its budget. A row whose request ended
+        at an earlier landing (its EOS was in the chunk before, or in its
+        prefill's first token) rode this chunk as a done row: its tokens
+        are dropped. Returns (finished, real tokens delivered)."""
+        finished: List[Request] = []
+        out_tokens = 0
+        if landed is None:                 # no flight: nothing was read
+            return finished, out_tokens
+        cfg = self.config
+        firsts, t_first, arr, t = landed
+        with _span("serving/deliver"):
+            for (slot, req, _, name, t_pf0), tok in zip(flight.firsts,
+                                                        firsts):
+                req.trace.events.append((name, t_pf0, t_first))
+                if self._complete_prefill(slot, req, tok, t_first):
+                    finished.append(req)
+            for slot, req, take in flight.rows:
+                if self._slots[slot] is not req:
+                    if not req.probe:   # a probe's rows are not traffic
+                        self.metrics.counters["eos_late_rows"] += 1
+                    continue
+                req.trace.events.append(("decode", flight.t0, t))
+                fresh = arr[slot, :take]
+                req._chunks.append(fresh)
+                req._produced += take
+                out_tokens += take
+                if req.trace.t_first_token is None:
+                    # zero-prefill admission (prefix cache): this chunk's
+                    # first token IS the request's first token — TTFT was
+                    # one decode step, measured not estimated
+                    req.trace.t_first_token = t
+                # EOS scan covers only the FRESH slice: earlier chunks were
+                # checked when they landed (an EOS there already finished the
+                # row), so the per-generation host cost stays O(n)
+                if req._produced >= req.max_new_tokens or \
+                        _hit_eos(fresh, cfg.eos_token_id):
+                    self._finish_paged_row(slot, t)
+                    finished.append(req)
+        return finished, out_tokens
+
+    def _land(self, flight: _Flight):
+        """Read and deliver a flight at once: the serial order a
+        speculative engine keeps."""
+        flight = self._close_flight(flight)
+        return self._deliver(flight, self._read_flight(flight))
+
+    def _decode_chunk_paged(self, live: List[int]):
+        """One plain decode chunk, launched and read at once (the
+        speculative engine's step when no row has a draft). Returns
+        (finished, real tokens)."""
+        with _span("serving/decode_prep"):
+            self._snapshot_kv()
+            staged = self._stage_decode_inputs(live)
+        flight = _Flight()
+        with _span("serving/decode"):
+            self._launch_decode(flight, live, staged)
+            flight = self._close_flight(flight)
+            landed = self._read_flight(flight)
+        return self._deliver(flight, landed)
 
     def _complete_prefill(self, slot: int, req: Request, tok: int,
                           tp: float) -> bool:
-        """Shared prefill-completion bookkeeping (one-shot admission AND
-        the final chunked-prefill window): the sampled token becomes the
-        row's pending/first token, the prompt's full blocks enter the
-        trie, and a budget-1 / instant-EOS request finishes on the spot.
-        Returns True when the request instant-finished (the slot is free
-        again)."""
+        """A prefill's first token has reached the host: stamp it, and
+        finish a budget-1 / instant-EOS request on the spot. Returns True
+        when the request finished (the slot is free again)."""
         cfg = self.config
-        plen = req.prompt_len
-        self._take_step_counters()
         req.trace.t_prefill_done = tp
         req.trace.t_first_token = tp  # sampled with the prefill
-        self._lens[slot] = plen
-        self._pending[slot] = tok
-        hit_eos = (cfg.eos_token_id is not None
-                   and tok == cfg.eos_token_id)
-        self._done[slot] = hit_eos
         req._chunks = [np.asarray([tok], np.int64)]  # lint: allow(tracer-asarray)
         req._produced = 1
-        # insert BEFORE any instant finish: the cache's retain must land
-        # while the request still holds its blocks (finishing frees the
-        # owner's references)
-        self._insert_prefix(req, self._pool.owned(req.id), plen)
-        if req._produced >= req.max_new_tokens or hit_eos:
+        if req._produced >= req.max_new_tokens or \
+                (cfg.eos_token_id is not None and tok == cfg.eos_token_id):
             self._finish_paged_row(slot, tp)
             return True
         return False
@@ -2004,7 +2152,7 @@ class ServingEngine:
                 return d[:cfg.spec_k].astype(np.int32), "model"
         return None, None
 
-    def _decode_chunk_spec(self, live: List[int]):
+    def _decode_chunk_spec(self, live: List[int], ran: set):
         """One speculative verify window over the slot batch (ISSUE 11):
         a fixed-shape [B, spec_k + 1] call through model.verify_paged.
         Rows with a draft advance by their accepted length + 1; rows
@@ -2013,8 +2161,9 @@ class ServingEngine:
         every emitted token is argmax-correct by construction). Steps
         where NO row has a draft fall back to the plain decode chunk —
         both executables are in the warm set, so the per-step choice is
-        host data, never a compile. Returns (finished, real tokens,
-        ran_tags)."""
+        host data, never a compile. Read before it returns: the accepted
+        count sets each row's next length. Returns (finished, real
+        tokens); the executable run is tagged into `ran`."""
         cfg = self.config
         B = len(self._slots)
         drafts = np.full((B, cfg.spec_k), cfg.pad_token_id, np.int32)
@@ -2025,11 +2174,12 @@ class ServingEngine:
                 drafts[slot, :len(d)] = d
                 src[slot] = (tag, len(d))
         if not src:
-            finished, out_tokens = self._decode_chunk_paged(live)
-            return finished, out_tokens, {"decode"}
+            ran.add("decode")
+            return self._decode_chunk_paged(live)
+        ran.add("spec_verify")
         with _span("serving/decode_prep"):
             self._snapshot_kv()
-            tables, lens, pending, done = self._ship_decode_state()
+            tables, lens, pending, done = self._stage_decode_inputs(live)
         t_c0 = self.clock()
         with _span("serving/decode"):
             with _span("serving/decode_launch"):
@@ -2061,9 +2211,11 @@ class ServingEngine:
                     # zero-prefill admission: this window's first token IS
                     # the request's first token
                     req.trace.t_first_token = t
+                req._launched = req._produced
                 self._lens[slot] += n_emit   # the accepted frontier
                 self._pending[slot] = np.int32(arr[slot, n_emit - 1])
                 self._done[slot] = bool(done_new[slot])  # lint: allow(tracer-bool)
+                self._src[slot] = _SRC_HOST
                 if slot in src:
                     # acceptance accounting covers DRAFTED rows only and
                     # REAL draft tokens only: a short trie draft's pad
@@ -2090,7 +2242,7 @@ class ServingEngine:
                 if row_done:
                     self._finish_paged_row(slot, t)
                     finished.append(req)
-        return finished, out_tokens, {"spec_verify"}
+        return finished, out_tokens
 
     def _finish_paged_row(self, slot: int, t: float):
         """Terminal bookkeeping for one slot: blocks free IMMEDIATELY (the

@@ -692,20 +692,20 @@ def _validate_cache_dtype(cache_dtype, cdt):
 
 
 def _coerce_prompt_lens(prompt_lens, cap, name):
-    """Shared ragged-serving lens handling: coerce to an int32 device
-    array and validate 1 <= len <= cap on the HOST (lens are concrete at
-    call time; len 0 would index the padded tail and mask every real
-    column, len > cap would un-mask garbage cache rows)."""
+    """Shared ragged-serving lens handling: validate 1 <= len <= cap on
+    the HOST (lens are concrete at call time; len 0 would index the
+    padded tail and mask every real column, len > cap would un-mask
+    garbage cache rows) and coerce to an int32 device array. Host values
+    are checked before they are uploaded: reading them back would wait
+    for the device, and the serving engine launches with it busy."""
     import numpy as _np
-    lens_arr = jnp.asarray(
-        prompt_lens._data if isinstance(prompt_lens, Tensor)
-        else _np.asarray(prompt_lens), jnp.int32)  # lint: allow(tracer-asarray)
-    host = _np.asarray(lens_arr)  # lint: allow(tracer-asarray)
+    host = _np.asarray(prompt_lens._data if isinstance(prompt_lens, Tensor)  # lint: allow(tracer-asarray)
+                       else prompt_lens)
     if host.size and (int(host.min()) < 1 or int(host.max()) > cap):
         raise ValueError(
             f"{name}: prompt_lens must satisfy 1 <= len <= P_cap ({cap}); "
             f"got range [{int(host.min())}, {int(host.max())}]")
-    return lens_arr
+    return jnp.asarray(host, jnp.int32)
 
 
 def _wrap_ragged_caches(caches, cap):

@@ -298,13 +298,16 @@ class PanguMoEForCausalLM(Layer):
 
     step_counter_names = STATS
 
-    def pop_step_counters(self) -> dict:
-        """The experts' counts since the last call, summed over the prefill
-        and decode calls made: one small device-to-host copy, made after
-        the tokens of the last call were read."""
-        v = np.asarray(self._stats, np.float32)  # lint: allow(tracer-asarray)
-        self._stats = np.zeros_like(v)
-        return dict(zip(STATS, v.tolist()))
+    def detach_step_counters(self):
+        """The experts' counts of the prefill and decode calls made since
+        the last detach, as the array [len(STATS)] the last of them
+        returned: still on the device, nothing is read. The next call
+        counts from zero, so a caller can launch on and read these counts
+        when their own calls have run (the serving engine does, with the
+        tokens)."""
+        stats, self._stats = self._stats, \
+            np.zeros((len(STATS),), np.float32)
+        return stats
 
     def _scale_rank(self):
         c = self.config

@@ -379,6 +379,42 @@ def test_latent_decode_at_the_serving_cells_geometry(one_chip):
     _assert_kernel(text, la.LATENT_DECODE_NAME)
 
 
+# -------------------------------------------- the engine's own device programs
+@pytest.mark.parametrize("b,chunk", [(32, 8), (128, 8)],
+                         ids=["gpt-cells", "pangu-cell"])
+def test_engine_step_helpers_at_the_serving_cells_geometry(one_chip, b,
+                                                           chunk):
+    """The two small programs a paged engine step adds beside the model's
+    (inference/serving.py: `paged_stage` picks each row's pending token
+    and done flag on the device, `paged_put_first` keeps a prefill's
+    first token there), at the serving cells' batch and chunk. Their
+    shapes come from the engine's settings alone, so a toy model's engine
+    warmed up on the CPU builds the cells' own programs."""
+    import numpy as np
+    import paddle_tpu as paddle
+    from paddle_tpu.analysis import lint_capture
+    from paddle_tpu.inference import ServingConfig, ServingEngine
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+
+    paddle.seed(0)
+    model = GPTForCausalLM(GPTConfig(
+        vocab_size=64, hidden_size=16, num_layers=1, num_heads=2,
+        max_position_embeddings=32, intermediate_size=32))
+    model.eval()
+    eng = ServingEngine(model, ServingConfig(
+        max_batch=b, prompt_cap=8, max_new_tokens=chunk + 2,
+        decode_chunk=chunk, paged=True, kv_block=4, prefix_cache=True,
+        prefill_chunk=4))
+    with lint_capture() as calls:
+        eng.submit(np.arange(1, 7))
+        assert [r.status for r in eng.drain()] == ["done"]
+    for name in ("paged_stage", "paged_put_first"):
+        (_, fn, (args, kw)), = [c for c in calls if c[0][0] == name][:1]
+        args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+                for a in args]
+        assert fn.lower(*args, **kw).compile().as_text()
+
+
 # ------------------------------------------------------- the kernels' names
 def test_every_kernel_has_a_name_and_none_holds_another():
     """A metric finds a kernel's events by looking for its name inside the

@@ -122,11 +122,12 @@ def test_prober_passes_with_zero_steady_state_misses(served_model):
 def _user_slice(met):
     """The user-facing accounting the ISSUE pins: every request-scoped
     counter (goodput inputs, token volumes, cache/spec efficiency) and
-    the rendered latency histograms. Excludes `batches` and the
-    occupancy gauges — those describe MACHINE state, which probe rows
-    genuinely occupy."""
+    the rendered latency histograms. Excludes `batches`, the decode
+    chunk counts and the occupancy gauges — those describe MACHINE
+    state, which probe rows genuinely occupy."""
     from paddle_tpu.profiler._metrics import histogram_lines
-    counters = {k: v for k, v in met.counters.items() if k != "batches"}
+    machine = ("batches", "decode_chunks", "decode_chunks_overlapped")
+    counters = {k: v for k, v in met.counters.items() if k not in machine}
     hists = "\n".join(
         "\n".join(histogram_lines("u", name, met.hists[name], help_))
         for name, help_ in met.HISTS)
