@@ -31,7 +31,7 @@ import time
 
 import numpy as np
 
-# --- sizes: GPT-1.3B (models/gpt.py "gpt3-1.3b") at bench.py's flagship
+# --- sizes: GPT-1.3B (models/gpt.py "gpt3-1.3b") at the flagship training
 # point, and the serving envelope of the paged-kernel compile tests ---------
 PRESET = "gpt3-1.3b"
 TRAIN_B, TRAIN_S = 3, 2048
@@ -328,7 +328,7 @@ def kernels_phase(shapes: dict, seed: int, *, interpret: bool = False,
 
 # ------------------------------------------------------------------- train
 def _build_train(cfg, seed, *, bf16, mesh=None, monitor=None):
-    """bench.py's flagship step: bf16 parameters, AdamW with bf16 moments,
+    """The flagship step: bf16 parameters, AdamW with bf16 moments,
     TrainStep over the fused-head loss."""
     import paddle_tpu as paddle
     from paddle_tpu.jit.train_step import TrainStep
@@ -492,7 +492,7 @@ def serve_phase(cfg, serve: dict, prompt_lens, seed: int, *,
         f" tokens in {time.perf_counter() - t0:.1f}s")
 
     eng = ServingEngine(model, ServingConfig(
-        paged=True, prefix_cache=True, **serve))
+        prefix_cache=True, **serve))
     t0 = time.perf_counter()
     with lint_capture() as calls:
         eng.warmup_prefix_cache(cfg.vocab_size)

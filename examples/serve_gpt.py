@@ -1,4 +1,4 @@
-"""GPT serving walkthrough: the full static-serving matrix in one script.
+"""GPT serving walkthrough: one-shot generation, then the serving engine.
 
 Every path compiles ONCE and replays with fixed shapes (the TPU-native
 analog of the reference's fused_multi_transformer CacheKV serving):
@@ -7,12 +7,13 @@ analog of the reference's fused_multi_transformer CacheKV serving):
   2. generate_static_ragged   — ANY prompt length <= cap, one executable
   3. weight_dtype="int8"      — Pallas in-register-dequant GEMM weights
   4. cache_dtype="int8"       — int8 KV cache, factored-scale attention
-  5. prefill_static/decode_static — shared prefix paid ONCE, N samples
-     (composes with ragged prompts and both int8 knobs)
-  6. ServingEngine — request-level continuous batching over the same
-     executables, driven by open-loop synthetic traffic, ending in the
-     real /metrics payload a frontend scrapes (TTFT/TPOT/e2e histograms,
-     queue/batch/KV gauges, zero-recompile steady state)
+  5. launch-level stats around live generate_static calls
+  6. ServingEngine — request-level continuous batching over a paged KV
+     pool with the prefix cache on (a shared prefix is prefilled ONCE,
+     every later request maps its blocks), driven by open-loop
+     system-prompt traffic, ending in the real /metrics payload a
+     frontend scrapes (TTFT/TPOT/e2e histograms, queue/batch/KV gauges,
+     zero-recompile steady state)
   7. the telemetry SERVER (obs, ISSUE 12) — the same engine scraped over
      HTTP: `curl /metrics` (collision-checked Prometheus page),
      `/healthz` (the autoscaler inputs: drain state + queue depth +
@@ -35,12 +36,12 @@ def main():
     paddle.seed(0)
     if len(sys.argv) > 1:
         cfg = gpt_config(sys.argv[1])
-        B, cap, new = 8, 128, 32
+        B, cap, new, kv_block = 8, 128, 32, 16
     else:
         cfg = GPTConfig(vocab_size=128, hidden_size=64, num_layers=2,
                         num_heads=4, max_position_embeddings=96,
                         intermediate_size=128)
-        B, cap, new = 2, 12, 8
+        B, cap, new, kv_block = 2, 12, 8, 4
     model = GPTForCausalLM(cfg)
     if paddle.device.on_tpu():
         model.to(dtype="bfloat16")
@@ -64,23 +65,7 @@ def main():
     agree = float((q.numpy()[:, cap:] == out.numpy()[:, cap:]).mean())
     print(f"int8 weights+KV: greedy agreement {agree:.3f}")
 
-    # 5. prefix reuse: one prefill, many sampled continuations
-    st = model.prefill_static(ids, max_len=cap + new)
-    greedy = model.decode_static(st, max_new_tokens=new)
-    assert (greedy.numpy() == out.numpy()[:, cap:]).all()
-    samples = [model.decode_static(st, max_new_tokens=new,
-                                   temperature=0.9, seed=s).numpy()
-               for s in range(3)]
-    print("prefix-reuse: greedy tail parity OK;",
-          len({s.tobytes() for s in samples}), "distinct samples")
-
-    # 5b. ragged + prefix reuse compose
-    str_ = model.prefill_static(ids, max_len=cap + new, prompt_lens=lens)
-    dr = model.decode_static(str_, max_new_tokens=new)
-    assert (dr.numpy() == r.numpy()[:, cap:]).all()
-    print("ragged prefix-reuse: per-row greedy parity OK")
-
-    # 6. launch-level stats: a StepMonitor bracketing live decode launches —
+    # 5. launch-level stats: a StepMonitor bracketing live decode launches —
     # steady tokens/s, device memory, and the recompile counter (a
     # shape-unstable serving loop shows up here immediately).
     from paddle_tpu.profiler import StepMonitor
@@ -91,24 +76,29 @@ def main():
             _ = out.numpy()
     print(mon.metrics_text(), end="")
 
-    # 7. request-level serving: the ServingEngine admits ragged prompts
-    # into a bounded queue, assembles fixed-shape micro-batches and drives
-    # the SAME prefill/decode executables — now with per-request traces
+    # 6. request-level serving: the ServingEngine admits ragged prompts
+    # into a bounded queue and splices each into a free batch slot over
+    # the paged KV pool — with per-request traces
     # (enqueue→admit→prefill→first-token→finish), TTFT/TPOT/e2e latency
-    # histograms and queue/batch/KV gauges. Open-loop synthetic traffic:
-    # arrivals follow their own schedule regardless of service speed, so
-    # queue wait is a real measurement, not an artifact of the replayer.
+    # histograms and queue/batch/KV gauges. The prefix cache pays each
+    # system prompt's prefill once. Open-loop traffic: arrivals follow
+    # their own schedule regardless of service speed, so queue wait is a
+    # real measurement, not an artifact of the replayer.
     from paddle_tpu.inference import (ServingEngine, ServingConfig,
-                                      synthetic_traffic)
+                                      shared_prefix_traffic)
     engine = ServingEngine(model, ServingConfig(
         max_batch=B, prompt_cap=cap, max_new_tokens=new,
-        decode_chunk=max(1, new // 2)))
+        decode_chunk=max(1, new // 2), kv_block=kv_block,
+        prefix_cache=True))
     # boot the ops surface FIRST (ISSUE 12) — a real replica's telemetry
     # server is up before traffic lands, so /tracez sees every request
     srv = engine.serve_telemetry()
-    traffic = synthetic_traffic(4 * B, prompt_cap=cap,
-                                vocab_size=cfg.vocab_size, rate=200.0,
-                                seed=3, min_len=max(1, cap // 3))
+    traffic = shared_prefix_traffic(4 * B, n_prefixes=2,
+                                    prefix_len=cap // 2 // kv_block
+                                    * kv_block,
+                                    prompt_cap=cap,
+                                    vocab_size=cfg.vocab_size, rate=200.0,
+                                    seed=3)
     import time
     t0 = engine.clock()
     finished = []
@@ -122,15 +112,17 @@ def main():
     finished += engine.drain()
     n_ok = sum(1 for r in finished if r.status == "done")
     s = engine.summary()
-    print(f"engine: {n_ok} requests over {s['batches_total']} batches, "
+    print(f"engine: {n_ok} requests over {s['batches_total']} steps, "
           f"fill {s['batch_fill_ratio']:.2f}, "
-          f"kv occupancy {s['kv_occupancy']:.2f} (true tokens)")
+          f"kv occupancy {s['kv_occupancy']:.2f} (true tokens), "
+          f"{s['prefix_hit_total']} prefix hits saved "
+          f"{s['prefill_tokens_saved_total']} prompt tokens of prefill")
     if s.get("ttft_seconds"):
         print(f"TTFT p50/p99: {s['ttft_seconds']['p50'] * 1e3:.1f} / "
               f"{s['ttft_seconds']['p99'] * 1e3:.1f} ms")
     assert s["batch_step"]["recompiles"] == 0   # steady loop never reshapes
 
-    # 8. the ops surface over the wire (ISSUE 12): what a router /
+    # 7. the ops surface over the wire (ISSUE 12): what a router /
     # autoscaler / dashboard actually scrapes. serve_telemetry() wires
     # /metrics (unified registry), /healthz, /statusz and /tracez around
     # the live engine on an ephemeral port — this is the in-process

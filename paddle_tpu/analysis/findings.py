@@ -42,7 +42,7 @@ class Finding:
     message: one human sentence; says what AND where.
     where: the location — a source summary ("gpt.py:123 (forward)"), a
         layer path ("GPTForCausalLM/gpt/h/0/attn"), or an argument name.
-    executable: name of the audited executable ("decode_static[...]").
+    executable: name of the audited executable ("decode_paged[...]").
     data: pass-specific details (shapes, dtypes, byte counts, indices).
     allowed/allow_reason: set when an Allowlist entry matched.
     """

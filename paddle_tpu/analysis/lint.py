@@ -19,7 +19,7 @@ instead of an anonymous tracer error.
 builds while the context is active (models' `_gen_cache_get` feeds it):
 
     with lint_capture() as calls:
-        model.prefill_static(...); model.decode_static(...)   # warmup
+        model.prefill_paged(...); model.decode_paged(...)     # warmup
     findings = lint.check_calls(calls)
 
 which is how the serving engine and the graph_lint CLI audit the real

@@ -51,7 +51,7 @@ def _source_summary(eqn, max_frames: int = 4) -> str:
     """Caller chain 'file.py:123 (fn) < file.py:88 (caller) < ...' for an
     eqn, innermost first — naming the chain (not just the innermost frame)
     is what lets an allowlist entry match on the MEANINGFUL function
-    (layer_norm, attention_reference, decode_static) instead of a lambda
+    (layer_norm, attention_reference, decode_paged) instead of a lambda
     or closure body three frames down."""
     from jax._src import source_info_util
     frames = []

@@ -73,7 +73,7 @@ def is_compiled_with_cuda() -> bool:
 # -------- accelerator capability + memory telemetry ------------------------
 
 # bf16 peak matmul FLOP/s per chip by TPU generation (public spec sheets) —
-# the denominator of every MFU figure (bench.py, profiler.StepMonitor)
+# the denominator of every MFU figure (profiler.StepMonitor)
 _PEAK_FLOPS = {"v2": 46e12, "v3": 123e12, "v4": 275e12,
                "v5 lite": 197e12, "v5e": 197e12, "v5litepod": 197e12,
                "v5p": 459e12, "v6e": 918e12, "v6p": 918e12}
@@ -101,8 +101,8 @@ _COMPILE_CACHE_DIRNAME = ".jax_compile_cache"
 def enable_compile_cache() -> str:
     """Keep compiled executables across processes; returns the directory.
 
-    Entry points call this (chip_smoke.py, bench.py, tools/serve_bench.py,
-    the GPT examples), never package import. Where JAX_COMPILATION_CACHE_DIR
+    Entry points call this (chip_smoke.py, benchmarks/harness.py, the GPT
+    examples), never package import. Where JAX_COMPILATION_CACHE_DIR
     is set JAX already reads it and nothing is set here. Otherwise the
     cache lives at one fixed, git-ignored path in the checkout: the path
     is part of the cache key's environment, so a directory named after a

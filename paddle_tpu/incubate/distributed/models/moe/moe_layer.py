@@ -42,7 +42,7 @@ def _capacity(num_tokens: int, num_experts: int, top_k: int,
 # assignments overflowed their expert's static capacity — the quantity the
 # capacity_factor knob trades against padding compute. Tracer-safe: inside
 # jit traces the values are symbolic and recording is skipped, so enable it
-# and run one eager forward (bench.py bench_moe does exactly that).
+# and run one eager forward.
 _DROP_REC = {"on": False, "kept": 0, "assigned": 0}
 
 

@@ -295,13 +295,11 @@ class FleetRouter:
     # ---------------------------------------------------------- routing
     def _block_tokens(self) -> int:
         """Routing-key width: one kv block of the replicas' config (the
-        trie's node key width) — falls back to the prompt cap for
-        non-paged fleets."""
+        trie's node key width)."""
         if self._key_tokens is not None:
             return self._key_tokens
         for h in self.registry.handles(("serving", "draining")):
-            cfg = h.engine.config
-            return cfg.kv_block if cfg.paged else cfg.prompt_cap
+            return h.engine.config.kv_block
         return 16
 
     def routing_key(self, prompt) -> bytes:

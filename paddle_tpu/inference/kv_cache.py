@@ -1,11 +1,11 @@
 """paddle_tpu.inference.kv_cache — block-paged KV-cache pool for serving.
 
-The static serving stack (generate_static_ragged / prefill_static +
-decode_static) right-pads every ragged prompt to a fixed cap and reserves a
-full [B, max_len] KV slab per batch slot, so mixed-length traffic holds HBM
-hostage for padding and a finished row's slot stays pinned until the whole
-micro-batch drains. The TPU-idiomatic fix (Ragged Paged Attention,
-arxiv 2604.15464; PAPERS.md serving studies) is a BLOCK pool:
+One-shot static generation (generate_static_ragged) right-pads every
+ragged prompt to a fixed cap and reserves a full [B, max_len] KV slab per
+batch row, so mixed-length traffic would hold HBM hostage for padding and a
+finished row's slab would stay pinned until the whole batch ends. The
+serving engine's KV (Ragged Paged Attention, arxiv 2604.15464; PAPERS.md
+serving studies) is a BLOCK pool:
 
   * device state is ONE fixed-shape tensor per layer and plane —
     ``[num_blocks, block_size, num_heads, head_dim]`` K and V planes, or

@@ -1,55 +1,73 @@
 """paddle_tpu.inference.serving — an instrumented continuous-batching
-engine over the static decode stack, with request-level observability as
+engine over a paged KV block pool, with request-level observability as
 the headline.
 
 The training side has step metrics (profiler.StepMonitor, r7) and numerics
 sentinels (debugging, r8); serving quality is judged by a DIFFERENT set of
 signals — TTFT/TPOT latency distributions, queue wait, batch fill and
-KV-slot utilization under load (cf. the ragged-paged-attention and
-Gemma-on-TPU serving studies, PAPERS.md). This module provides:
+KV occupancy under load (cf. the ragged-paged-attention and Gemma-on-TPU
+serving studies, PAPERS.md). This module provides:
 
-  ServingEngine   admits per-request prompts into a bounded queue,
-                  assembles FIXED-SHAPE micro-batches (right-padded ragged
-                  prompts + per-row lens), and drives the model's
-                  `prefill_static` / `decode_static` executables. Decode
-                  runs in chunks of [1, c, c, ...]: the 1-token first
-                  chunk makes time-to-first-token a measured host fact
-                  (not an estimate), later chunks let a batch stop as soon
-                  as every row finished. Every shape is pinned by the
-                  config, so after one warmup batch the loop adds ZERO jit
-                  compilations — guarded at runtime via the PR-2 cache-miss
-                  counter, with a shape-delta warning through
+  ServingEngine   admits per-request prompts into a bounded queue and
+                  runs each of `max_batch` batch SLOTS against blocks of
+                  a KV pool (inference/kv_cache.py + the ragged paged
+                  attention op) through the model's `prefill_paged` /
+                  `decode_paged` executables. One `step()` is admit,
+                  launch, land:
+
+                  admit   queued requests are spliced into free slots
+                          mid-flight (`_admit_paged`: trie match, block
+                          mapping, copy-on-write; no model call). EOS
+                          or budget frees a slot's blocks immediately,
+                          so nothing waits for a batch to drain, and
+                          anything that fits the pool is admittable.
+                  launch  every slot in prefill gets its next window
+                          ([1, prompt_cap], or [1, prefill_chunk]) and
+                          the decodable rows one fixed-shape [B,
+                          decode_chunk] decode chunk, enqueued without a
+                          read: each row's pending token is picked on the
+                          device from the last chunk's outputs.
+                  land    only then are the tokens of the step BEFORE
+                          copied to the host, while the chip works.
+                          Tokens reach a request one step after their
+                          launch; a budget's end is known at launch, an
+                          EOS one chunk late (`eos_late_rows`).
+
+                  Every shape is pinned by the config, so after the
+                  {prefill, decode, two small helpers} set compiles once
+                  the loop adds ZERO jit compilations — guarded at
+                  runtime via the PR-2 cache-miss counter, with a
+                  shape-delta warning through
                   `StepMonitor.record_compile` when a request would force
-                  a new executable (it is rejected instead).
+                  a new executable (it is rejected instead). The pool
+                  buffers are DONATED through every call, so XLA updates
+                  KV in place.
 
   RequestTrace    per-request span timestamps (enqueue → admit → prefill →
                   first token → finish); each engine phase also runs under
                   a `jax.profiler.TraceAnnotation` so a device trace gives
                   kernel time, and every gap between kernels, to what the
-                  host was doing. One engine step is "serving/step". The
-                  padded engine opens "serving/prefill" and
-                  "serving/decode" around a model call and the read that
-                  waits for it. The paged engine launches before it reads
-                  (`_step_paged`): "serving/prefill" encloses one window's
-                  "serving/prefill_launch"; "serving/decode" encloses
-                  "serving/decode_launch" (this step's chunk enqueued)
-                  and then the reads of what the step BEFORE launched,
-                  "serving/prefill_read" (its final windows' first
-                  tokens) and "serving/decode_read" (its chunk's tokens:
-                  the host waits here while the chip runs this step's
-                  chunk). Its own work between two calls: "serving/admit"
-                  (one queued request: trie match, block mapping,
-                  copy-on-write, slot set-up), "serving/decode_prep" (KV
-                  snapshot, the chunk's inputs staged),
-                  "serving/deliver" (tokens handed to requests, finished
-                  rows freed and recorded), "serving/bookkeep" (batch
-                  gauges, compile accounting, the monitor's step). PERF.md
-                  section 3 names the metric that reads each.
+                  host was doing. One engine step is "serving/step".
+                  "serving/admit" is one queued request (trie match,
+                  block mapping, copy-on-write, slot set-up);
+                  "serving/prefill" encloses one window's
+                  "serving/prefill_launch"; "serving/decode_prep" is the
+                  KV snapshot and the chunk's inputs staged;
+                  "serving/decode" encloses "serving/decode_launch" (this
+                  step's chunk enqueued) and then the reads of what the
+                  step BEFORE launched, "serving/prefill_read" (its final
+                  windows' first tokens) and "serving/decode_read" (its
+                  chunk's tokens: the host waits here while the chip runs
+                  this step's chunk); "serving/deliver" (tokens handed to
+                  requests, finished rows freed and recorded) and
+                  "serving/bookkeep" (batch gauges, compile accounting,
+                  the monitor's step) close the step. PERF.md section 3
+                  names the metric that reads each.
 
   ServingMetrics  log-bucketed latency histograms (TTFT, per-output-token
                   time, end-to-end, queue wait — p50/p90/p99 derived from
                   buckets, no per-request retention), gauges (queue depth,
-                  batch-fill ratio, KV-slot occupancy) and counters
+                  batch-fill ratio, KV occupancy) and counters
                   (requests/tokens in+out/rejections/timeouts/batches),
                   rendered to Prometheus exposition text by the SAME
                   `profiler._metrics` formatter StepMonitor uses, plus one
@@ -57,28 +75,9 @@ Gemma-on-TPU serving studies, PAPERS.md). This module provides:
                   convention: a nested payload under "request" + "ts").
 
 Greedy engine output is bit-identical to `model.generate_static_ragged`
-on the same prompts (tested): padding rows to the fixed batch and chunking
-the decode change nothing — attention masks make cache length and batch
-company value-invariant, and chunked greedy decode replays the same
-argmax chain.
-
-`ServingConfig(paged=True)` (ISSUE 5) swaps the per-slot padded KV slabs
-for a BLOCK POOL (inference/kv_cache.py + the ragged paged attention op):
-each batch slot runs its own request against blocks it owns, EOS/budget
-frees those blocks immediately, and `_admit_paged` splices a queued
-request into the vacated slot mid-flight — prefill into fresh blocks
-([1, cap], one executable), then the row simply joins the next decode
-chunk. No waiting for the batch to drain, no bucket-mismatch rejection
-for anything that fits the pool, and the same two guarantees hold:
-greedy output bit-identical to generate_static_ragged per row, zero jit
-cache misses after the {prefill, decode} pair compiles once. The pool
-buffers are DONATED through every call, so XLA updates KV in place.
-A paged step launches before it reads: this step's prefill windows and
-decode chunk are enqueued, with each row's pending token picked on the
-device from the last chunk's outputs, and only then are the tokens of the
-step before copied to the host, while the chip works (`_step_paged`).
-Tokens reach a request one step after their launch; a budget's end is
-known at launch, an EOS one chunk late (`eos_late_rows`).
+on the same prompts (tested): slot company and chunking change nothing —
+attention masks make cache length and batch company value-invariant, and
+chunked greedy decode replays the same argmax chain.
 (Bit-identity caveat: bf16 models on TPU route through the f32-score
 Pallas paged kernel while the static path stores bf16 scores, so parity
 there is approximate near argmax ties — exact whenever both sides share
@@ -86,19 +85,18 @@ a numerics class: f32 models anywhere, or the CPU reference path; see
 ops/pallas/paged_attention.py, and chip_smoke.py's serve phase for the
 agreement required of a bf16 model on the chip.)
 
-`ServingConfig(paged=True, prefix_cache=True)` (ISSUE 10) adds the
-radix-trie PREFIX CACHE (inference/prefix_cache.py): admission matches
-each prompt against cached full-block token prefixes, maps shared
-refcounted pool blocks into the request's table, and prefills only the
-uncached suffix — a full hit skips prefill entirely (the last prompt
-token re-enters as the decode pending token, so TTFT is one decode
-step, with copy-on-write of the last shared block when the hit is
-block-aligned). `cache_dtype="int8"` now composes with paged=True: the
-pools carry int8 codes + per-block factored scales (the static int8-KV
-trick ported to the paged kernel), holding ~2x the resident requests.
-Greedy output stays bit-identical with the cache on vs off, and the
-steady loop still adds zero compilations — the suffix-prefill and COW
-executables are part of the warmup set.
+`ServingConfig(prefix_cache=True)` (ISSUE 10) adds the radix-trie PREFIX
+CACHE (inference/prefix_cache.py): admission matches each prompt against
+cached full-block token prefixes, maps shared refcounted pool blocks into
+the request's table, and prefills only the uncached suffix — a full hit
+skips prefill entirely (the last prompt token re-enters as the decode
+pending token, so TTFT is one decode step, with copy-on-write of the
+last shared block when the hit is block-aligned). With
+`cache_dtype="int8"` the pools carry int8 codes + per-block factored
+scales, holding ~2x the resident requests. Greedy output stays
+bit-identical with the cache on vs off, and the steady loop still adds
+zero compilations — the suffix-prefill and COW executables are part of
+the warmup set.
 
 `ServingConfig(spec_decode=True)` (ISSUE 11) turns each decode step into
 a DRAFT-VERIFY window through the ragged [B, k] multi-token
@@ -114,6 +112,8 @@ agentic traffic drafts its own future with no draft model at all
 GPT). Rejected-position KV writes land below the next window's start
 (or in the trash block past a row's budget), so acceptance is data, not
 shape: one verify executable per window size, zero steady recompiles.
+A speculative engine reads each call before the next (the accepted
+count sets a row's next length).
 `prefill_chunk=N` additionally caps per-step prefill work at [1, N]
 tokens through the same start-offset executable, so a cap-length prompt
 no longer monopolizes the engine for one monolithic prefill call.
@@ -122,7 +122,6 @@ from __future__ import annotations
 
 import json
 import logging
-import math
 import time
 import uuid
 from collections import deque
@@ -153,7 +152,7 @@ _SRC_HOST, _SRC_CHUNK, _SRC_FIRST = 0, 1, 2
 
 @dataclass
 class _Flight:
-    """What one paged engine step launched and has not read: the final
+    """What one engine step launched and has not read: the final
     prefill windows' first tokens and one decode chunk's tokens, still on
     the device. The step after reads them (`ServingEngine._land`)."""
     # (slot, request, first-token Tensor [1], event name, launch time)
@@ -171,13 +170,13 @@ class _Flight:
 class RequestTrace:
     """Span TREE of one request's life (engine clock seconds).
 
-    enqueue → admit is queue wait; admit → prefill_done is the batched
-    prefill; first_token lands after the 1-token decode chunk; finish is
+    enqueue → admit is queue wait; admit → prefill_done is the request's
+    prefill windows, the last of which samples first_token; finish is
     stamped when the host has read the decode CHUNK in which the row hit
     EOS or its budget (chunk granularity — a short request co-batched
     with long ones is not charged for decode chunks past its own
-    completion). The paged engine stamps first_token and finish with the
-    time the tokens reached the host, one step after their launch.
+    completion). first_token and finish are stamped with the time the
+    tokens reached the host, one step after their launch.
 
     `trace_id` names the request across export surfaces (JSONL rows, the
     /tracez ring, logs); `events` are the engine-call WINDOWS the request
@@ -275,7 +274,7 @@ class Request:
 
     @property
     def n_produced(self) -> int:
-        """Tokens the paged engine has delivered to this request so far
+        """Tokens the engine has delivered to this request so far
         (0 until its first lands; tokens launched and not yet read by the
         host do not count); `n_out` is final, this one moves."""
         return getattr(self, "_produced", 0)
@@ -525,12 +524,10 @@ class ServingMetrics:
                      queue_depth: int, kv_shared_tokens: int = 0):
         """kv_tokens = PHYSICAL live (attendable) KV rows — a block
         mapped into several requests' tables (prefix sharing) counts
-        ONCE; kv_slots = rows the allocation granularity pins (padded
-        slots / reserved blocks); kv_capacity = total pooled rows.
-        kv_occupancy is the true-token gauge (ISSUE 5 satellite —
-        padded-slot accounting could not go above the padding ratio);
-        kv_slots_occupancy keeps the old slot-granular value for
-        dashboard continuity. kv_shared_tokens (ISSUE 10) is the LOGICAL
+        ONCE; kv_slots = rows the allocation granularity pins (reserved
+        blocks); kv_capacity = total pooled rows. kv_occupancy is the
+        true-token gauge; kv_slots_occupancy the block-granular one.
+        kv_shared_tokens (ISSUE 10) is the LOGICAL
         volume served out of shared blocks — summed over requests, so
         (kv_shared_tokens - distinct shared rows) is exactly the HBM the
         prefix cache is saving right now."""
@@ -633,7 +630,7 @@ class ServingMetrics:
                  "kv_occupancy": "live (attendable) KV rows / pooled "
                                  "capacity — true-token occupancy",
                  "kv_slots_occupancy": "allocation-granular KV rows "
-                                       "(padded slots / reserved blocks) "
+                                       "(reserved blocks) "
                                        "/ pooled capacity",
                  "kv_shared_tokens": "logical KV rows served from "
                                      "shared prefix blocks (summed over "
@@ -654,8 +651,8 @@ class ServingConfig:
     compiled signature lives here — the engine NEVER recompiles to fit a
     request; requests that don't fit are rejected with a logged shape
     delta."""
-    max_batch: int = 4              # micro-batch rows (padded with dummies)
-    prompt_cap: int = 64            # right-padding cap; longer = rejected
+    max_batch: int = 4              # batch slots (one request each)
+    prompt_cap: int = 64            # longest prompt; longer = rejected
     max_new_tokens: int = 32        # per-request budget ceiling
     decode_chunk: Optional[int] = None  # tokens per post-first-token call;
     #                                 default max_new_tokens-1 = one chunk
@@ -676,7 +673,10 @@ class ServingConfig:
     weight_dtype: Optional[str] = None   # "int8" -> weight-only int8 GEMMs
     cache_dtype: Optional[str] = None    # "int8" -> int8 KV cache
     # --- paged KV pool (ISSUE 5): slot-level continuous batching ---
-    paged: bool = False             # block-pool KV + mid-flight admission
+    # the padded engine this once switched away from is gone; the keyword
+    # stays, as a value that can only be True, until the benchmark's
+    # runners stop passing it (ROADMAP D2)
+    paged: bool = True
     kv_block: int = 16              # KV rows per pool block
     kv_blocks: Optional[int] = None  # total pool blocks INCL. trash block;
     #                            default = worst case for max_batch rows
@@ -685,14 +685,14 @@ class ServingConfig:
     # executables run through the mpu tensor-parallel layers; block
     # tables, the allocator, refcounts and the radix trie stay host-side
     # and replicated. None/1 = single-chip (no mesh built). Requires
-    # paged=True and num_heads % shards == 0; greedy output is
-    # bit-identical across shard counts (the per-shard invariant suite).
+    # num_heads % shards == 0; greedy output is bit-identical across
+    # shard counts (the per-shard invariant suite).
     shards: Optional[int] = None
     # --- prefix cache (ISSUE 10): radix-trie prefix reuse over the pool.
     # A full-block-aligned cached prefix maps shared (refcounted) blocks
     # straight into the new request's table — full hit skips prefill
     # entirely (TTFT = one decode step, COW on the last block), partial
-    # hit prefills only the suffix. Requires paged=True.
+    # hit prefills only the suffix.
     prefix_cache: bool = False
     prefix_cache_bytes: Optional[int] = None  # LRU eviction budget for
     #                            cached (refcount-free) blocks; None =
@@ -709,8 +709,8 @@ class ServingConfig:
     # scores `spec_k` drafted tokens + the pending token in ONE
     # fixed-shape verify call; the longest-accepted-prefix rule keeps
     # greedy output bit-identical to the plain chain, and rows advance
-    # 1..spec_k+1 tokens per launch. Requires paged=True and greedy
-    # sampling (temperature 0 — acceptance IS argmax equality).
+    # 1..spec_k+1 tokens per launch. Requires greedy sampling
+    # (temperature 0 — acceptance IS argmax equality).
     spec_decode: bool = False
     spec_k: int = 4                 # draft tokens per verify window
     # draft source: "trie" = prompt-lookup from the prefix radix trie (a
@@ -729,8 +729,8 @@ class ServingConfig:
     # at [1, prefill_chunk] tokens so one long prompt never monopolizes
     # the engine for a whole prefill — offsets are DATA through the
     # start-form prefill executable (zero new executables per prompt
-    # length). None = whole-prompt/suffix prefill at admission (the
-    # ISSUE-5/10 behavior).
+    # length). None = the whole prompt (or uncached suffix) in one
+    # window.
     prefill_chunk: Optional[int] = None
     # --- static analysis (ISSUE 6): True / "error" / analysis.GraphLint —
     # the engine audits each of its {prefill, decode} executables with
@@ -741,6 +741,10 @@ class ServingConfig:
 
     def __post_init__(self):
         from ..analysis.findings import ConfigValidationError, Finding
+        if self.paged is not True:
+            raise ValueError(
+                f"paged={self.paged!r}: the padded engine was removed; "
+                f"ServingEngine is the paged engine (drop the keyword)")
         if self.max_batch < 1 or self.prompt_cap < 1 \
                 or self.max_new_tokens < 1:
             raise ValueError("max_batch, prompt_cap and max_new_tokens "
@@ -755,33 +759,14 @@ class ServingConfig:
             raise ValueError(
                 f"queue_high_watermark must be in [1, queue_capacity="
                 f"{self.queue_capacity}], got {self.queue_high_watermark}")
-        if self.shards is not None:
-            if self.shards < 1:
-                raise ValueError(f"shards must be >= 1, got {self.shards}")
-            if self.shards > 1 and not self.paged:
-                raise ConfigValidationError(Finding(
-                    "config", "sharded_requires_paged", "error",
-                    f"shards={self.shards} requires paged=True: tensor-"
-                    f"parallel serving shards the paged block pools' head "
-                    f"axis over the mp mesh; the padded static engine has "
-                    f"no pools to shard",
-                    executable="ServingConfig",
-                    data={"shards": self.shards, "paged": False}))
-        if self.prefix_cache and not self.paged:
-            raise ValueError("prefix_cache=True requires paged=True (the "
-                             "trie shares BLOCK-pool blocks; the padded "
-                             "engine has no blocks to share)")
+        if self.shards is not None and self.shards < 1:
+            raise ValueError(f"shards must be >= 1, got {self.shards}")
         if self.spill_host_bytes is not None and not self.prefix_cache:
             raise ValueError("spill_host_bytes requires prefix_cache="
                              "True (the spill tier holds EVICTED trie "
                              "blocks; without the trie nothing is ever "
                              "evicted into it)")
         if self.spec_decode:
-            if not self.paged:
-                raise ValueError("spec_decode=True requires paged=True "
-                                 "(the verify call runs the [B, k] "
-                                 "multi-token kernel over the block "
-                                 "pool)")
             if not (1 <= self.spec_k <= 31):
                 # the upper bound keeps the spec_accept_len histogram's
                 # exact-integer buckets (bounds cover counts <= 32 =
@@ -807,43 +792,32 @@ class ServingConfig:
                 raise ValueError(f"spec_draft must be 'trie' or a "
                                  f"callable (context, k) -> tokens; got "
                                  f"{self.spec_draft!r}")
-        if self.prefill_chunk is not None:
-            if not self.paged:
-                raise ValueError("prefill_chunk requires paged=True (the "
-                                 "chunk windows write pool blocks via "
-                                 "the start-offset executable)")
-            if not (1 <= self.prefill_chunk <= self.prompt_cap):
-                raise ValueError(
-                    f"prefill_chunk must be in [1, prompt_cap="
-                    f"{self.prompt_cap}], got {self.prefill_chunk}")
-        if self.paged:
-            if self.cache_dtype not in (None, "int8"):
-                # int8 paged KV landed (ISSUE 10: per-block factored
-                # scales, the static int8 trick ported to the paged
-                # kernel); every OTHER narrow dtype is still refused with
-                # a structured config-validation finding (same schema as
-                # the graph passes) so tools print WHY — ConfigValidation-
-                # Error is a ValueError, existing callers keep working
-                raise ConfigValidationError(Finding(
-                    "config", "paged_cache_dtype", "error",
-                    f"cache_dtype={self.cache_dtype!r} with paged=True is "
-                    f"not supported: paged pools carry the MODEL dtype or "
-                    f"the int8 (codes, factored-scale) form. Use "
-                    f"cache_dtype='int8' (halves resident KV), "
-                    f"cache_dtype=None, or paged=False with "
-                    f"cache_dtype={self.cache_dtype!r}",
-                    executable="ServingConfig",
-                    data={"cache_dtype": str(self.cache_dtype),
-                          "paged": True}))
-            if self.kv_block < 1:
-                raise ValueError(f"kv_block must be >= 1, "
-                                 f"got {self.kv_block}")
-            if self.kv_blocks is None:
-                # worst case: every slot holds a cap prompt decoding its
-                # full budget (+1 for the reserved trash block). Smaller
-                # pools oversubscribe deliberately — admission then waits
-                # on freed blocks.
-                self.kv_blocks = self.max_batch * self.table_width + 1
+        if self.prefill_chunk is not None and \
+                not (1 <= self.prefill_chunk <= self.prompt_cap):
+            raise ValueError(
+                f"prefill_chunk must be in [1, prompt_cap="
+                f"{self.prompt_cap}], got {self.prefill_chunk}")
+        if self.cache_dtype not in (None, "int8"):
+            # a structured config-validation finding (same schema as the
+            # graph passes) so tools print WHY — ConfigValidationError is
+            # a ValueError
+            raise ConfigValidationError(Finding(
+                "config", "paged_cache_dtype", "error",
+                f"cache_dtype={self.cache_dtype!r} is not supported: the "
+                f"pools carry the MODEL dtype or the int8 (codes, "
+                f"factored-scale) form. Use cache_dtype='int8' (halves "
+                f"resident KV) or cache_dtype=None",
+                executable="ServingConfig",
+                data={"cache_dtype": str(self.cache_dtype)}))
+        if self.kv_block < 1:
+            raise ValueError(f"kv_block must be >= 1, "
+                             f"got {self.kv_block}")
+        if self.kv_blocks is None:
+            # worst case: every slot holds a cap prompt decoding its
+            # full budget (+1 for the reserved trash block). Smaller
+            # pools oversubscribe deliberately — admission then waits
+            # on freed blocks.
+            self.kv_blocks = self.max_batch * self.table_width + 1
 
     @property
     def row_kv_rows(self) -> int:
@@ -856,34 +830,18 @@ class ServingConfig:
         """Block-table columns per batch slot (worst-case blocks/row)."""
         return -(-self.row_kv_rows // self.kv_block)
 
-    @property
-    def chunk_schedule(self) -> List[int]:
-        """Decode-call sizes per batch: [1, c, c, ...] covering
-        max_new_tokens (the tail chunk still runs full width — fixed
-        shapes — and over-generated tokens are truncated per row)."""
-        if self.max_new_tokens == 1:
-            return [1]
-        k = math.ceil((self.max_new_tokens - 1) / self.decode_chunk)
-        return [1] + [self.decode_chunk] * k
-
-    @property
-    def max_len(self) -> int:
-        """KV rows per batch slot: prompt cap + the chunk schedule's
-        worst-case cache writes (the last sampled token is never
-        written)."""
-        return self.prompt_cap + max(sum(self.chunk_schedule), 2) - 1
-
 
 class ServingEngine:
-    """Continuous-batching serving loop over the static decode stack.
+    """Continuous-batching serving loop over a paged KV block pool.
 
-    Synchronous by design: `submit()` enqueues, `step()` runs ONE
-    micro-batch to completion, `drain()` loops until the queue empties.
+    Synchronous by design: `submit()` enqueues, `step()` admits, launches
+    one round of model calls and reads the round before, `drain()` loops
+    until the queue empties and every slot finished.
     The engine is NOT internally synchronized — submit/step touch shared
     state beyond the queue (request ids, metrics counters/gauges, the
     JSONL stream), so a frontend thread driving submit while a worker
     loops step() must hold one lock around every engine call. The calls
-    are short on the submit side; step() blocks for a batch.
+    are short on the submit side; step() blocks for one read.
 
     `clock` is injectable (tests drive deadlines deterministically).
     """
@@ -896,7 +854,7 @@ class ServingEngine:
         self.model = model
         self.config = config
         # a model family that implements only part of the engine's surface
-        # (models/pangu_moe.py: the paged path alone) refuses the rest here
+        # (models/pangu_moe.py) refuses the rest here
         if hasattr(model, "check_serving_config"):
             model.check_serving_config(config)
         self.metrics = metrics or ServingMetrics()
@@ -945,20 +903,19 @@ class ServingEngine:
         # collectors merge many replicas' JSONL/tracez streams, where a
         # bare per-engine request counter would collide instantly
         self._run_id = uuid.uuid4().hex[:8]
-        self._max_depth = 0        # deepest (prefill + k chunks) run so far
         self._rejected_shapes = set()   # shape-delta warned once per shape
         # the engine's one-and-only batch signature (leaves shaped like
         # StepMonitor.record_compile expects for shape_delta rendering)
         self._shape_sig = (((config.max_batch, config.prompt_cap), "int64"),
                            ((config.max_batch,), "int32"))
-        self._spill = None     # host spill tier (paged + prefix + spill)
+        self._spill = None     # host spill tier (prefix_cache + spill)
         # multi-chip serving (ISSUE 16): a private mp mesh over the first
         # `shards` devices. The engine activates it around pool creation
         # and every step — NOT globally — so interleaved engines at
         # different shard counts (the bit-identity suite, the bench's
         # single-chip twin) never see each other's mesh.
         self._mesh = None
-        if config.paged and (config.shards or 1) > 1:
+        if (config.shards or 1) > 1:
             from ..distributed import mesh as _dist_mesh
             shards = int(config.shards)
             devs = jax.devices()
@@ -974,69 +931,68 @@ class ServingEngine:
                     f"shard the head axis)")
             self._mesh = _dist_mesh.build_mesh({"mp": shards},
                                                devs[:shards])
-        if config.paged:
-            # slot-level continuous batching over a paged block pool: each
-            # batch slot runs its own request; EOS/budget frees the slot's
-            # blocks immediately and _admit_paged splices a queued request
-            # into the vacancy mid-flight. Device state is the donated
-            # per-layer pools; tables/lens are tiny host vectors edited
-            # per slot and shipped with every chunk; pending/done are the
-            # host's only for rows it has read (`_src`).
-            from .kv_cache import BlockPool
-            B, MB = config.max_batch, config.table_width
-            self._pool = BlockPool.for_model(model,
-                                             num_blocks=config.kv_blocks,
-                                             block_size=config.kv_block,
-                                             cache_dtype=config.cache_dtype)
-            with self._mesh_scope():
-                self._pools = self._pool.make_pools()
-            self._slots: List[Optional[Request]] = [None] * B
-            self._tables = np.zeros((B, MB), np.int32)
-            self._lens = np.zeros((B,), np.int32)
-            self._pending = np.zeros((B,), np.int32)
-            self._done = np.ones((B,), bool)
-            self._calls = 0            # PRNG stream cursor (sampling mode)
-            self._paged_seen = set()   # executables already compiled
-            self._kv_snapshot = (0, 0, 0)  # (physical live tokens, slot
-            #                      rows, logical shared tokens) at the
-            #                      last step's decode entry
-            # prefix cache (ISSUE 10): per-slot count of lens tokens that
-            # live in blocks the request mapped SHARED from the trie —
-            # the kv_shared_tokens gauge and the hit bookkeeping
-            self._shared_tok = np.zeros((B,), np.int64)
-            self._prefix = None
-            if config.prefix_cache:
-                from .prefix_cache import PrefixCache
-                self._prefix = PrefixCache(
-                    self._pool, byte_budget=config.prefix_cache_bytes)
-                if config.spill_host_bytes is not None:
-                    # host-RAM spill tier (ISSUE 14): the cache owns the
-                    # trie mechanics; the engine owns the device pools,
-                    # so both transfer directions are closures over it
-                    from .kv_cache import HostSpillTier
-                    self._spill = HostSpillTier(
-                        bytes_per_block=self._pool.bytes_per_block,
-                        byte_budget=config.spill_host_bytes)
-                    self._prefix.attach_spill(
-                        self._spill,
-                        reader=lambda blk: self._pool.read_block(
-                            self._pools, blk),
-                        writer=self._spill_write)
-            # next prompt position to prefill per slot (one window of
-            # prefill_chunk tokens a step, or the whole uncached suffix
-            # at once); -1 = not in prefill (a decode row)
-            self._prefill_pos = np.full((B,), -1, np.int64)
-            # where each row's pending token and done flag live when the
-            # next chunk is launched: on the host (_pending / _done), in
-            # the last launched chunk's outputs, or in the first-token
-            # vector a final prefill window wrote (`_stage_decode_inputs`)
-            self._src = np.full((B,), _SRC_HOST, np.int32)
-            self._flight: Optional[_Flight] = None   # launched, unread
-            self._reset_device_carry()
-            # spec decoding (ISSUE 11): the optional draft-model hook —
-            # the trie (when present) drafts first, the hook fills misses
-            self._draft_fn = config.spec_draft \
-                if callable(config.spec_draft) else None
+        # slot-level continuous batching over a paged block pool: each
+        # batch slot runs its own request; EOS/budget frees the slot's
+        # blocks immediately and _admit_paged splices a queued request
+        # into the vacancy mid-flight. Device state is the donated
+        # per-layer pools; tables/lens are tiny host vectors edited
+        # per slot and shipped with every chunk; pending/done are the
+        # host's only for rows it has read (`_src`).
+        from .kv_cache import BlockPool
+        B, MB = config.max_batch, config.table_width
+        self._pool = BlockPool.for_model(model,
+                                         num_blocks=config.kv_blocks,
+                                         block_size=config.kv_block,
+                                         cache_dtype=config.cache_dtype)
+        with self._mesh_scope():
+            self._pools = self._pool.make_pools()
+        self._slots: List[Optional[Request]] = [None] * B
+        self._tables = np.zeros((B, MB), np.int32)
+        self._lens = np.zeros((B,), np.int32)
+        self._pending = np.zeros((B,), np.int32)
+        self._done = np.ones((B,), bool)
+        self._calls = 0            # PRNG stream cursor (sampling mode)
+        self._paged_seen = set()   # executables already compiled
+        self._kv_snapshot = (0, 0, 0)  # (physical live tokens, slot
+        #                      rows, logical shared tokens) at the
+        #                      last step's decode entry
+        # prefix cache (ISSUE 10): per-slot count of lens tokens that
+        # live in blocks the request mapped SHARED from the trie —
+        # the kv_shared_tokens gauge and the hit bookkeeping
+        self._shared_tok = np.zeros((B,), np.int64)
+        self._prefix = None
+        if config.prefix_cache:
+            from .prefix_cache import PrefixCache
+            self._prefix = PrefixCache(
+                self._pool, byte_budget=config.prefix_cache_bytes)
+            if config.spill_host_bytes is not None:
+                # host-RAM spill tier (ISSUE 14): the cache owns the
+                # trie mechanics; the engine owns the device pools,
+                # so both transfer directions are closures over it
+                from .kv_cache import HostSpillTier
+                self._spill = HostSpillTier(
+                    bytes_per_block=self._pool.bytes_per_block,
+                    byte_budget=config.spill_host_bytes)
+                self._prefix.attach_spill(
+                    self._spill,
+                    reader=lambda blk: self._pool.read_block(
+                        self._pools, blk),
+                    writer=self._spill_write)
+        # next prompt position to prefill per slot (one window of
+        # prefill_chunk tokens a step, or the whole uncached suffix
+        # at once); -1 = not in prefill (a decode row)
+        self._prefill_pos = np.full((B,), -1, np.int64)
+        # where each row's pending token and done flag live when the
+        # next chunk is launched: on the host (_pending / _done), in
+        # the last launched chunk's outputs, or in the first-token
+        # vector a final prefill window wrote (`_stage_decode_inputs`)
+        self._src = np.full((B,), _SRC_HOST, np.int32)
+        self._flight: Optional[_Flight] = None   # launched, unread
+        self._reset_device_carry()
+        # spec decoding (ISSUE 11): the optional draft-model hook —
+        # the trie (when present) drafts first, the hook fills misses
+        self._draft_fn = config.spec_draft \
+            if callable(config.spec_draft) else None
 
     def _reset_device_carry(self):
         """Placeholders for what a launch reads from the launch before
@@ -1068,13 +1024,12 @@ class ServingEngine:
 
     @property
     def busy(self) -> bool:
-        """Work remains: queued requests, or (paged) live batch slots
-        still decoding, or a launched chunk whose tokens the host has not
-        read — the public loop condition drain() and external replayers
-        (tools/serve_bench.py) share."""
+        """Work remains: queued requests, or live batch slots still
+        decoding, or a launched chunk whose tokens the host has not read —
+        the public loop condition drain() and external replayers share."""
         # host-side deque/slot-list reads  # lint: allow(tracer-bool)
-        return bool(self._queue) or (self.config.paged and (
-            bool(self._live()) or self._flight is not None))  # lint: allow(tracer-bool)
+        return bool(self._queue) or bool(self._live()) \
+            or self._flight is not None  # lint: allow(tracer-bool)
 
     def preflight(self, prompt, max_new_tokens: Optional[int] = None):
         """Static admission check (analysis.recompile): Findings for
@@ -1116,7 +1071,7 @@ class ServingEngine:
                 f"prompt length {plen} would force a new prefill "
                 f"executable: {why}", executable="serving_batch",
                 data={"prompt_len": plen, "cap": cfg.prompt_cap}))
-        if cfg.paged and plen >= 1 and want >= 1 \
+        if plen >= 1 and want >= 1 \
                 and not self._pool.fits_ever(plen + want - 1):
             msg = (f"request needs {plen + want - 1} KV rows — more than "
                    f"the whole pool holds even fully drained")
@@ -1143,7 +1098,7 @@ class ServingEngine:
         Returns the Request; check `.status` — "queued" on success,
         "rejected" (queue full, or a shape the engine's executables cannot
         serve) otherwise. `enqueue_at` backdates the enqueue span for
-        open-loop replay (tools/serve_bench.py): queue-wait/TTFT are then
+        open-loop replay: queue-wait/TTFT are then
         measured from the request's SCHEDULED arrival, not from when the
         single-threaded replayer got around to calling submit. Backdating
         only — a future timestamp clamps to now (a request cannot be
@@ -1223,39 +1178,16 @@ class ServingEngine:
         self.metrics.gauges["queue_depth"] = len(self._queue)
         return req
 
-    def _admit(self):
-        """Pop up to max_batch live requests; expire the deadline-blown.
-        Returns (admitted, expired) — both are terminal outcomes the
-        caller must surface (a timed-out request is a served SLO miss,
-        not something to silently drop from the accounting)."""
-        now = self.clock()
-        admitted: List[Request] = []
-        expired: List[Request] = []
-        while self._queue and len(admitted) < self.config.max_batch:
-            req = self._queue.popleft()
-            if req.deadline_s is not None and \
-                    now - req.trace.t_enqueue > req.deadline_s:
-                req.status, req.reason = "timeout", "queue_deadline"
-                req.trace.t_finish = now       # terminal time: its queue
-                self.metrics.record_request(req)  # wait IS its life
-                expired.append(req)
-                continue
-            req.status = "active"
-            req.trace.t_admit = now
-            req.trace.batch_id = self._batch_id
-            admitted.append(req)
-        self.metrics.gauges["queue_depth"] = len(self._queue)
-        return admitted, expired
-
     # -- the batch loop -------------------------------------------------
     def step(self) -> List[Request]:
-        """Assemble and run ONE micro-batch; returns every request that
-        reached a terminal status this step — served rows AND queue-
-        deadline timeouts (excluding expired traffic from the results
-        would hide exactly the overload signal the metrics exist for).
+        """Run ONE engine step (`_step_paged`: admit, launch, land);
+        returns every request that reached a terminal status this step —
+        served rows AND queue-deadline timeouts (excluding expired traffic
+        from the results would hide exactly the overload signal the
+        metrics exist for).
 
-        If the batch dies mid-flight (device OOM, interrupt), the admitted
-        requests are recorded as status="error" before the exception
+        If a call dies mid-flight (device OOM, interrupt), the requests in
+        the slots are recorded as status="error" before the exception
         propagates — an accounting layer must not lose in-flight requests.
 
         With `ServingConfig(lint=...)`, every step runs under
@@ -1273,12 +1205,13 @@ class ServingEngine:
 
     def _step_inner(self) -> List[Request]:
         if self._lint is None:
-            return self._step_dispatch()
+            with _span("serving/step"):
+                return self._step_paged()
         from ..analysis import lint_capture
         from ..analysis.findings import Findings
         from ..analysis.lint import _kind_name
-        with lint_capture() as calls:
-            out = self._step_dispatch()
+        with lint_capture() as calls, _span("serving/step"):
+            out = self._step_paged()
         new = [c for c in calls
                if (id(c[1]), _kind_name(c[0])) not in self._lint_seen]
         if new:
@@ -1291,156 +1224,12 @@ class ServingEngine:
             self._lint._guard(fs, "serving executables")
         return out
 
-    def _step_dispatch(self) -> List[Request]:
-        with _span("serving/step"):
-            if self.config.paged:
-                return self._step_paged()
-            reqs, expired = self._admit()
-            if not reqs:
-                return expired
-            try:
-                return expired + self._run_batch(reqs)
-            except BaseException:
-                now = self.clock()
-                for r in reqs:
-                    if r.status == "active":
-                        r.status, r.reason = "error", "engine_exception"
-                        r.trace.t_finish = now
-                        self.metrics.record_request(r)
-                self.metrics.gauges["inflight"] = 0
-                self.monitor.end_step(items=0)   # no-op if begin never ran
-                raise
-
-    def _run_batch(self, reqs: List[Request]) -> List[Request]:
-        cfg = self.config
-        self.metrics.gauges["inflight"] = len(reqs)
-        batch_id = self._batch_id
-        self._batch_id += 1
-
-        # fixed-shape assembly: right-padded [B, prompt_cap] int64 + lens;
-        # unfilled rows are 1-token pad dummies (their outputs are dropped,
-        # and per-row attention/masks keep them from touching real rows)
-        B, cap = cfg.max_batch, cfg.prompt_cap
-        ids = np.full((B, cap), cfg.pad_token_id, dtype=np.int64)
-        lens = np.ones((B,), dtype=np.int32)
-        for i, r in enumerate(reqs):
-            ids[i, :r.prompt_len] = r.prompt
-            lens[i] = r.prompt_len
-
-        miss0 = _jit_cache_misses()
-        need = max(r.max_new_tokens for r in reqs)
-        self.monitor.begin_step()
-        t_pf0 = self.clock()
-        with _span("serving/prefill"):
-            st = self.model.prefill_static(
-                ids, max_len=cfg.max_len, prompt_lens=lens,
-                weight_dtype=cfg.weight_dtype, cache_dtype=cfg.cache_dtype)
-            jax.block_until_ready(st["last_logits"])
-        t_prefill = self.clock()
-        for r in reqs:
-            r.trace.t_prefill_done = t_prefill
-            r.trace.events.append(("prefill", t_pf0, t_prefill))
-
-        parts: List[np.ndarray] = []
-        schedule = cfg.chunk_schedule
-        for ci, chunk in enumerate(schedule):
-            t_c0 = self.clock()
-            with _span("serving/decode"):
-                # per-(batch, chunk) seed: every decode_static call builds
-                # a fresh PRNG stream from its seed, so reusing one seed
-                # across chunks would replay the same draws
-                # donate_cache: the state is used LINEARLY here (st is
-                # replaced every chunk, the prefill state never reused),
-                # so XLA updates the KV tuples in place instead of
-                # re-threading them by value each chunk
-                toks, st = self.model.decode_static(
-                    st, chunk, temperature=cfg.temperature,
-                    top_k=cfg.top_k, top_p=cfg.top_p,
-                    seed=cfg.seed + batch_id * len(schedule) + ci,
-                    eos_token_id=cfg.eos_token_id, return_state=True,
-                    donate_cache=True)
-                part = np.asarray(toks.numpy())     # host sync per chunk  # lint: allow(tracer-asarray)
-            parts.append(part)
-            t_chunk = self.clock()
-            if ci == 0:
-                for r in reqs:
-                    r.trace.t_first_token = t_chunk
-            # the decode window rides every row still in flight at chunk
-            # entry — a row finished in an EARLIER chunk is not charged
-            # this one (same rule as the t_finish stamp below)
-            for r in reqs:
-                if r.trace.t_finish is None:
-                    r.trace.events.append(("decode", t_c0, t_chunk))
-            # per-row finish at chunk granularity: a row is complete once
-            # it hit EOS or its own budget — its e2e/TPOT must not be
-            # charged for chunks the batch ran for OTHER rows
-            produced = sum(p.shape[1] for p in parts)
-            so_far = part if len(parts) == 1 else \
-                np.concatenate(parts, axis=1)
-            for i, r in enumerate(reqs):
-                if r.trace.t_finish is None and \
-                        (produced >= r.max_new_tokens or
-                         _hit_eos(so_far[i, :r.max_new_tokens],
-                                  cfg.eos_token_id)):
-                    r.trace.t_finish = t_chunk
-            if produced >= need:
-                break
-            if cfg.eos_token_id is not None:
-                done = np.asarray(st["done"])  # lint: allow(tracer-asarray)
-                if done[:len(reqs)].all():
-                    break               # every real row hit EOS: stop early
-
-        gen = np.concatenate(parts, axis=1)
-        out_tokens = 0
-        for i, r in enumerate(reqs):
-            row = gen[i, :r.max_new_tokens]
-            r.tokens = row
-            r.n_out = _n_out(row, cfg.eos_token_id)
-            r.status = "done"
-            if r.trace.t_finish is None:    # unreachable in practice: both
-                r.trace.t_finish = t_chunk  # loop exits finish every row
-            out_tokens += r.n_out
-            self.metrics.record_request(r)
-        # true live tokens: real prompt rows + decode rows actually
-        # written (prompt + produced - 1 each; the last sampled token is
-        # returned but never written). Slots accounting: every admitted
-        # row pins a FULL padded [max_len] slab — that gap between the two
-        # gauges is exactly what the paged engine exists to close.
-        kv_tokens = int(lens[:len(reqs)].sum()) + \
-            int((gen.shape[1] - 1) * len(reqs))
-        self.metrics.record_batch(
-            n_real=len(reqs), capacity=B, kv_tokens=kv_tokens,
-            kv_slots=len(reqs) * cfg.max_len,
-            kv_capacity=B * cfg.max_len, queue_depth=len(self._queue))
-        self.metrics.gauges["inflight"] = 0
-
-        # compile accounting BEFORE closing the step so the monitor marks
-        # this record `compiled` and keeps it out of the steady-state
-        # median/throughput: warmup's wall time is compile-dominated.
-        # Warmth is per chunk DEPTH, not per engine — an EOS early-exit or
-        # small-budget batch may stop before the deeper chunk executables
-        # ever compiled, and their eventual first compile is not shape
-        # churn. A jit miss at an already-seen depth is: every executable
-        # at that depth was cached, so something reshaped — log it as a
-        # recompile through the r7 detector.
-        depth = 1 + len(parts)               # prefill + decode calls made
-        dm = _jit_cache_misses() - miss0
-        if dm:
-            self.monitor.record_compile(
-                "serving_batch",
-                (("jit_cache_misses", dm),),
-                prev_sig=(("jit_cache_misses", 0),)
-                if depth <= self._max_depth else None)
-        self._max_depth = max(self._max_depth, depth)
-        self.monitor.end_step(items=out_tokens)
-        return reqs
-
-    # ------------------------------------- paged slot-level batching loop
+    # ------------------------------------------- slot-level batching loop
     def _live(self) -> List[int]:
         return [i for i, r in enumerate(self._slots) if r is not None]
 
     def _step_paged(self) -> List[Request]:
-        """One paged engine step: admit, launch, land.
+        """One engine step: admit, launch, land.
 
         Queued requests are spliced into free slots; every slot in
         prefill gets its next window and the decodable rows one decode
@@ -1531,7 +1320,7 @@ class ServingEngine:
                     self._pool.free(r.id)
                     self._clear_slot(i)
             # the failed call may have CONSUMED the donated pools — rebuild
-            # so the engine stays usable (the padded engine's contract).
+            # so the engine stays usable.
             # pool.reset() wiped the refcounts, so the prefix cache's
             # entries point at reissued blocks: drop them WITHOUT deref
             if self._prefix is not None:
@@ -1566,9 +1355,13 @@ class ServingEngine:
                     ran.add("spill")
                 if self._spill.rehydrated_total > spill0[1]:
                     ran.add("rehydrate")
-            # compile accounting, same convention as the static engine: a
-            # miss while every executable this step ran was already seen is
-            # shape churn — log it through the r7 recompile detector
+            # compile accounting BEFORE closing the step, so the monitor
+            # marks this record `compiled` and keeps it out of the steady-
+            # state median. Warmth is per executable, not per engine: a
+            # request that ends at its first token leaves decode uncompiled,
+            # and its eventual first compile is not shape churn. A miss
+            # while every executable this step ran was already seen is:
+            # log it as a recompile through the r7 detector
             dm = _jit_cache_misses() - miss0
             if dm:
                 self.monitor.record_compile(
@@ -1702,9 +1495,8 @@ class ServingEngine:
         prompt's decode drafts the first run's cached chain from the
         trie — and with prefill_chunk the chunked-window executable
         replaces the one-shot prefill pair. `clear=True` then drops the
-        warmup's cached prefixes so measured traffic starts cold. The
-        shared choreography serve_bench / bench.py / graph_lint use —
-        steady-state zero-recompile assertions are only meaningful after
+        warmup's cached prefixes so measured traffic starts cold.
+        Steady-state zero-recompile assertions are only meaningful after
         this whole set has lowered."""
         if self._prefix is None:
             raise ValueError("warmup_prefix_cache needs "
@@ -2344,14 +2136,12 @@ class ServingEngine:
         shed thresholds, inflight rows, and the overloaded counter. Pure
         host-side reads; safe from any thread at scrape rate."""
         cfg, m = self.config, self.metrics
-        inflight = len(self._live()) if cfg.paged \
-            else m.gauges["inflight"]
         return {"status": "draining" if self._draining else "ok",
                 "draining": self._draining,
                 "queue_depth": len(self._queue),
                 "queue_capacity": cfg.queue_capacity,
                 "queue_high_watermark": cfg.queue_high_watermark,
-                "inflight": inflight,
+                "inflight": len(self._live()),
                 "overloaded_total": m.counters["overloaded"],
                 "rejected_total": m.counters["rejected"],
                 # goodput inputs (ISSUE 14): the autoscale controller
@@ -2381,7 +2171,6 @@ class ServingEngine:
                           "uptime_s": round(self.clock() - self._t_start,
                                             3),
                           "draining": self._draining,
-                          "paged": self.config.paged,
                           "requests_submitted": self._next_id,
                           "batches": self._batch_id},
                "config": {k: (v if isinstance(v, (int, float, str, bool,
@@ -2394,25 +2183,24 @@ class ServingEngine:
                "fingerprint": self.fingerprint(),
                "counters": dict(self.metrics.counters),
                "gauges": dict(self.metrics.gauges)}
-        if self.config.paged:
-            pool = self._pool
-            kv_tokens, kv_slots, kv_shared = self._kv_snapshot
-            out["kv"] = {"blocks_total": pool.num_blocks,
-                         "block_size": pool.block_size,
-                         "used_blocks": pool.used_blocks,
-                         "capacity_tokens": pool.capacity_tokens,
-                         "live_tokens": kv_tokens,
-                         "slot_tokens": kv_slots,
-                         "shared_tokens": kv_shared,
-                         "cache_dtype": pool.cache_dtype}
-            if self._prefix is not None:
-                out["prefix_cache"] = {
-                    "cached_blocks": self._prefix.cached_blocks,
-                    "cached_bytes": self._prefix.cached_bytes,
-                    "spilled_blocks": self._prefix.spilled_blocks,
-                    "byte_budget": self._prefix.byte_budget}
-            if self._spill is not None:
-                out["spill"] = self._spill.stats()
+        pool = self._pool
+        kv_tokens, kv_slots, kv_shared = self._kv_snapshot
+        out["kv"] = {"blocks_total": pool.num_blocks,
+                     "block_size": pool.block_size,
+                     "used_blocks": pool.used_blocks,
+                     "capacity_tokens": pool.capacity_tokens,
+                     "live_tokens": kv_tokens,
+                     "slot_tokens": kv_slots,
+                     "shared_tokens": kv_shared,
+                     "cache_dtype": pool.cache_dtype}
+        if self._prefix is not None:
+            out["prefix_cache"] = {
+                "cached_blocks": self._prefix.cached_blocks,
+                "cached_bytes": self._prefix.cached_bytes,
+                "spilled_blocks": self._prefix.spilled_blocks,
+                "byte_budget": self._prefix.byte_budget}
+        if self._spill is not None:
+            out["spill"] = self._spill.stats()
         if self._memz is not None:
             # one curl shows compute, KV, and memory state together
             # (ISSUE 18 satellite): ledger summary + spill occupancy
@@ -2452,36 +2240,34 @@ class ServingEngine:
                            for _, p in self.model.named_parameters()))
         ledger.register("model_params", _params_bytes, kind="params",
                         replace=True)
-        if self.config.paged:
-            pool = self._pool
-            shards = int(self.config.shards or 1)
+        pool = self._pool
+        shards = int(self.config.shards or 1)
 
-            def _pool_bytes():
-                bpb = pool.bytes_per_block
-                return {"bytes": pool.num_blocks * bpb,
-                        "used_bytes": pool.used_blocks * bpb,
-                        "used_blocks": pool.used_blocks,
-                        "free_blocks": pool.free_blocks}
-            ledger.register("kv_pool", _pool_bytes, kind="kv",
-                            meta={"shards": shards,
-                                  "block_size": pool.block_size,
-                                  "num_blocks": pool.num_blocks},
-                            replace=True)
-            pool.on_change = lambda: ledger.sample("kv_pool",
-                                                   "prefix_cache")
-            if self._prefix is not None:
-                prefix = self._prefix
-                ledger.register(
-                    "prefix_cache",
-                    lambda: {"bytes": prefix.cached_bytes,
-                             "cached_blocks": prefix.cached_blocks,
-                             "spilled_blocks": prefix.spilled_blocks},
-                    kind="kv", overlay=True, replace=True)
-            if self._spill is not None:
-                spill = self._spill
-                ledger.register("spill_host",
-                                lambda: int(spill.host_bytes),
-                                kind="spill", device=False, replace=True)
+        def _pool_bytes():
+            bpb = pool.bytes_per_block
+            return {"bytes": pool.num_blocks * bpb,
+                    "used_bytes": pool.used_blocks * bpb,
+                    "used_blocks": pool.used_blocks,
+                    "free_blocks": pool.free_blocks}
+        ledger.register("kv_pool", _pool_bytes, kind="kv",
+                        meta={"shards": shards,
+                              "block_size": pool.block_size,
+                              "num_blocks": pool.num_blocks},
+                        replace=True)
+        pool.on_change = lambda: ledger.sample("kv_pool", "prefix_cache")
+        if self._prefix is not None:
+            prefix = self._prefix
+            ledger.register(
+                "prefix_cache",
+                lambda: {"bytes": prefix.cached_bytes,
+                         "cached_blocks": prefix.cached_blocks,
+                         "spilled_blocks": prefix.spilled_blocks},
+                kind="kv", overlay=True, replace=True)
+        if self._spill is not None:
+            spill = self._spill
+            ledger.register("spill_host",
+                            lambda: int(spill.host_bytes),
+                            kind="spill", device=False, replace=True)
         if ledger.on_row is None:
             ledger.on_row = self.metrics._emit
         # the StepMonitor's per-record memory sample reads the ledger's
@@ -2585,7 +2371,7 @@ class ServingEngine:
         this engine; it mounts /probez, exports the probe_* families,
         and with `probe_interval` the server drives golden-canary
         cycles on a poller thread. `invariant_interval` schedules the
-        deep InvariantAuditor audits (paged engines) the same way —
+        deep InvariantAuditor audits the same way —
         both pollers hold the prober's lock; an external step-loop
         thread must share it (`srv.prober.lock`), per the engine's
         one-lock threading contract."""
@@ -2602,16 +2388,12 @@ class ServingEngine:
             prober = Prober(self)
         if prober is not None:
             self._prober = prober
-        if self.config.paged and (prober is not None or
-                                  invariant_interval is not None):
+        if prober is not None or invariant_interval is not None:
             auditor = InvariantAuditor(
                 self, lock=prober.lock if prober is not None else None)
             self._invariants = auditor
             if prober is not None:
                 prober.auditor = auditor
-        elif invariant_interval is not None:
-            raise ValueError("invariant_interval needs a paged engine "
-                             "(the audits walk the block pool)")
         reg = registry if registry is not None else self.metrics_registry()
         if isinstance(slo, str):
             slo = SLOMonitor(slo, self.metrics)
@@ -2669,16 +2451,13 @@ def synthetic_traffic(n_requests: int, *, prompt_cap: int, vocab_size: int,
                       length_dist: str = "uniform") -> List[dict]:
     """Open-loop synthetic workload: Poisson arrivals at `rate` req/s,
     ragged prompt lengths in [min_len, prompt_cap]. Returns
-    [{"at": arrival_offset_s, "prompt": ids}] sorted by arrival — shared
-    by examples/serve_gpt.py and tools/serve_bench.py.
+    [{"at": arrival_offset_s, "prompt": ids}] sorted by arrival.
 
     length_dist:
       "uniform"  — lengths uniform over [min_len, prompt_cap];
       "longtail" — Pareto-shaped (alpha≈1.1) lengths clipped to the cap:
                    mostly-short traffic with a heavy tail of cap-length
-                   prompts, the mix where right-padding wastes the most
-                   HBM and the paged pool shows its gap (serve_bench's
-                   padded-vs-paged comparison profile)."""
+                   prompts."""
     if length_dist not in ("uniform", "longtail"):
         raise ValueError(f"unknown length_dist {length_dist!r}")
     rng = np.random.RandomState(seed)
@@ -2706,8 +2485,7 @@ def shared_prefix_traffic(n_requests: int, *, n_prefixes: int,
     `rate` req/s. The traffic shape prefix caching exists for: after each
     prefix's first request, every later request sharing it should admit
     with only its suffix prefilled. Returns [{"at", "prompt",
-    "prefix_id"}] sorted by arrival — serve_bench's --shared-prefix
-    profile and the bench decode-paged-prefix row replay this."""
+    "prefix_id"}] sorted by arrival."""
     if not (1 <= prefix_len < prompt_cap):
         raise ValueError(f"prefix_len must be in [1, prompt_cap), got "
                          f"{prefix_len} vs cap {prompt_cap}")
@@ -2740,8 +2518,7 @@ def repeated_traffic(n_requests: int, *, n_prompts: int, prompt_len: int,
     zero-prefills its KV from the trie AND drafts its entire greedy
     continuation from the cached chain, so verify windows accept
     end-to-end. Returns [{"at", "prompt", "prompt_id"}] sorted by
-    arrival — the bench decode-spec row and serve_bench --repeat replay
-    this."""
+    arrival."""
     if n_prompts < 1 or prompt_len < 1:
         raise ValueError(f"need n_prompts >= 1 and prompt_len >= 1, got "
                          f"{n_prompts}, {prompt_len}")

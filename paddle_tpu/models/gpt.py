@@ -711,9 +711,7 @@ def _coerce_prompt_lens(prompt_lens, cap, name):
 def _wrap_ragged_caches(caches, cap):
     """Flat carry tuples (ending in the raw lens vector) -> the forward's
     cache format, whose ragged marker is the nested (lens, cap) LAST
-    element. The single definition keeps the three serving entry points
-    (generate_static_ragged, prefill_static, decode_static) from drifting
-    on this pytree convention."""
+    element (generate_static_ragged's carry convention)."""
     return [tuple(Tensor(e) for e in c[:-1]) + ((Tensor(c[-1]), cap),)
             for c in caches]
 
@@ -978,287 +976,6 @@ class GPTForCausalLM(Layer):
         out = fn(payload, ids._data, jax.random.PRNGKey(seed))
         return Tensor(out)
 
-    # ----------------------------------------------- prefix-reuse serving
-    def prefill_static(self, input_ids, max_len: int,
-                       weight_dtype: str = None, cache_dtype: str = None,
-                       prompt_lens=None):
-        """Run the prompt ONCE and return a reusable prefill state.
-
-        Serving loops that share a prompt prefix (a system prompt, a
-        few-shot template, best-of-N sampling over one prompt) pay the
-        prefill forward a single time; every `decode_static` call then
-        continues from the returned state without recomputing it. The
-        reference serves the same pattern by retaining the CacheKV
-        workspace between fused_multi_transformer launches
-        (operators/fused/fused_multi_transformer_op.cu).
-
-        Returns an opaque state dict. The state is immutable — each
-        decode_static writes into its own copy of the cache buffers (XLA
-        copy-on-write), so one prefill fans out to any number of
-        continuations.
-
-        prompt_lens (optional, [B] host ints): RAGGED prompts right-padded
-        to input_ids' width — rows in [len, width) hold garbage k/v that
-        the per-row cache masks exclude, and each row's continuation
-        starts at its TRUE length (same contract as
-        generate_static_ragged)."""
-        import jax
-        from ..jit.api import _swap_params, _trace_guard
-        from ..core import autograd
-
-        cfg = self.config
-        ids = input_ids if isinstance(input_ids, Tensor) else Tensor(input_ids)
-        b, p_len = ids.shape
-        if max_len <= p_len:
-            raise ValueError(f"max_len ({max_len}) must exceed the prompt "
-                             f"length ({p_len}) to leave room for decode")
-        params = list(self.parameters())
-        cdt = self.gpt.wte.weight._data.dtype
-        nh, hd, nl = cfg.num_heads, cfg.head_dim, cfg.num_layers
-        q8 = weight_dtype == "int8"
-        c8 = _validate_cache_dtype(cache_dtype, cdt)
-        qmap = self._decode_quantized_params() if q8 else {}
-        expand = self._make_expand(q8, cdt)
-
-        lens_arr = None
-        if prompt_lens is not None:
-            lens_arr = _coerce_prompt_lens(prompt_lens, p_len,
-                                           "prefill_static")
-
-        def run(pa, prompt, lens):
-            caches = _make_static_caches(c8, nl, b, max_len, nh, hd, cdt,
-                                         lens=lens)
-            ex, pays = expand(pa)
-            with _trace_guard(), _swap_params(params, ex), \
-                    _q8_bind(params, pays), autograd.no_grad():
-                if lens is None:
-                    logits, nc = self.forward(
-                        Tensor(prompt),
-                        caches=[tuple(Tensor(e) for e in c)
-                                for c in caches])
-                    nc_out = [tuple(e._data for e in c) for c in nc]
-                    last = logits._data[:, -1].astype(jnp.float32)
-                else:
-                    pos0 = jnp.broadcast_to(
-                        jnp.arange(p_len, dtype=jnp.int32)[None], (b, p_len))
-                    logits, nc = self.forward(
-                        Tensor(prompt), position_ids=Tensor(pos0),
-                        caches=_wrap_ragged_caches(caches, p_len))
-                    nc_out = _unwrap_ragged_caches(nc)
-                    last = logits._data[jnp.arange(b),
-                                        lens - 1].astype(jnp.float32)
-            return nc_out, last
-
-        sig = ("prefill", b, p_len, int(max_len), str(cdt),
-               "q8" if q8 else "full", "c8" if c8 else "cfull",
-               "ragged" if lens_arr is not None else "fixed")
-        fn = self._gen_cache_get(sig, lambda: jax.jit(run))
-        payload = tuple(qmap[i] if i in qmap else p._data
-                        for i, p in enumerate(params)) if q8 else \
-            tuple(p._data for p in params)
-        caches, last_logits = fn(payload, ids._data, lens_arr)
-        # cdt is captured at PREFILL time: a model.to(dtype=...) between
-        # prefill and decode must not mix the state's arrays with a new
-        # live dtype (decode_static validates against this). param_ids
-        # snapshots the identity of the prefill-time parameter arrays so
-        # decode_static can reject decode against mutated weights (ADVICE
-        # r5): decode replays state["payload"], i.e. the PREFILL-time
-        # weights, so silently continuing after an optimizer step would
-        # sample from a model the caller no longer holds.
-        return {"caches": caches, "last_logits": last_logits,
-                "prompt": ids._data, "max_len": int(max_len),
-                "q8": q8, "c8": c8, "payload": payload, "cdt": str(cdt),
-                "param_ids": tuple(id(p._data) for p in params),
-                "lens": lens_arr}
-
-    def decode_static(self, state, max_new_tokens: int,
-                      temperature: float = 0.0, top_k: int = 0,
-                      top_p: float = 1.0, seed: int = 0,
-                      eos_token_id: int = None, return_state: bool = False,
-                      donate_cache: bool = False):
-        """Continue from a `prefill_static` state: ONE compiled lax.scan of
-        fixed-shape decode steps. Repeated calls (different seeds /
-        sampling configs) reuse the SAME prefill — greedy output equals
-        the tail of `generate_static` on the same prompt.
-
-        return_state=True additionally returns a RESUMABLE state: the next
-        decode_static call on it continues exactly where this one stopped
-        (the un-written last token rides along as `pending`, the EOS mask
-        as `done`, and ragged wpe positions offset by `generated`). Chunked
-        greedy decode is bit-identical to one decode of the summed length
-        — the serving engine decodes [1, chunk, chunk, ...] to measure
-        time-to-first-token truthfully and to stop early once every row
-        finished, with each chunk size compiling once. Sampled
-        (temperature > 0) chunked output differs from one-shot by design:
-        every call seeds its own PRNG stream.
-
-        donate_cache=True (requires return_state) DONATES the state's KV
-        buffers to XLA, which then updates them in place instead of
-        re-threading the whole cache tuple by value every chunk — the
-        serving engine's chunk loop sets it. It CONSUMES the input state:
-        the passed-in state's cache arrays are invalid afterwards, so the
-        prefill fan-out pattern (one prefill, many continuations) must
-        keep the default. Tokens are bit-identical either way (donation is
-        an aliasing hint, not a numerics change)."""
-        import jax
-        from jax import lax
-        from ..jit.api import _swap_params, _trace_guard
-        from ..core import autograd
-
-        b, p_len = state["prompt"].shape
-        L = state["max_len"]
-        resume = state.get("pending") is not None
-        gen0 = int(state.get("generated", 0))
-        if donate_cache and not return_state:
-            raise ValueError("donate_cache=True needs return_state=True: "
-                             "without the returned state the donated "
-                             "buffers would simply be destroyed")
-        if max_new_tokens <= 0:
-            raise ValueError("decode_static needs max_new_tokens >= 1 "
-                             "(the state already holds the prompt)")
-        # capacity: the LAST sampled token is returned but never written to
-        # the KV cache (scan steps 1..max_new_tokens-1 write positions
-        # p_len..p_len+max_new_tokens-2), so a state sized L admits
-        # p_len + max_new_tokens - 1 cache rows — not p_len + max_new_tokens
-        # (ADVICE r5: the stricter check wasted the buffer's last row).
-        # A resumed state's pending token occupies the cursor row first, so
-        # its `generated` count joins the prompt on the left side.
-        if p_len + gen0 + max_new_tokens - 1 > L:
-            raise ValueError(
-                f"decode_static: prompt ({p_len}) + generated ({gen0}) + "
-                f"max_new_tokens ({max_new_tokens}) needs "
-                f"{p_len + gen0 + max_new_tokens - 1} cache rows, "
-                f"exceeding the prefill state's max_len ({L})")
-        params = list(self.parameters())
-        cdt = self.gpt.wte.weight._data.dtype
-        if str(cdt) != state["cdt"]:
-            raise ValueError(
-                f"decode_static: the model's dtype changed since prefill "
-                f"({state['cdt']} -> {cdt}); re-run prefill_static")
-        # stale-weight guard (ADVICE r5): decode replays the PREFILL-time
-        # parameter snapshot carried in the state. If the live parameter
-        # arrays are no longer the ones prefill saw (optimizer step,
-        # set_value, load_dict), continuing would silently sample from
-        # stale weights — reject instead. Identity comparison is exact for
-        # the full-precision path (the state's payload pins the prefill
-        # arrays alive, so their ids cannot be recycled); under q8 the
-        # un-quantized prefill arrays are not pinned, so a freed id could
-        # in principle be recycled by a replacement array — a best-effort
-        # guard there (every param would have to collide, in order).
-        snap = state.get("param_ids")
-        if snap is not None and tuple(id(p._data) for p in params) != snap:
-            raise ValueError(
-                "decode_static: the model's parameters changed since "
-                "prefill_static; decode would replay the prefill-time "
-                "weight snapshot. Re-run prefill_static after mutating "
-                "weights (or decode before updating them).")
-        q8 = state["q8"]
-        ragged = state.get("lens") is not None
-        expand = self._make_expand(q8, cdt)
-
-        def model_step(pa, tokens, caches, pos_ids=None):
-            ex, pays = expand(pa)
-            with _trace_guard(), _swap_params(params, ex), \
-                    _q8_bind(params, pays), autograd.no_grad():
-                if ragged:
-                    logits, nc = self.forward(
-                        Tensor(tokens),
-                        position_ids=Tensor(pos_ids),
-                        caches=_wrap_ragged_caches(caches, p_len))
-                    return logits._data, _unwrap_ragged_caches(nc)
-                logits, nc = self.forward(
-                    Tensor(tokens),
-                    caches=[tuple(Tensor(e) for e in c) for c in caches])
-                return logits._data, [tuple(e._data for e in c)
-                                      for c in nc]
-
-        def pick(last, key):
-            return sample_logits(last, key, temperature=temperature,
-                                 top_k=top_k, top_p=top_p)
-
-        def body_fn(pa, lens):
-            # shared scan body: `step` counts generated tokens 1-indexed, so
-            # the token fed at `step` sits at sequence position
-            # lens + step - 1 in its (ragged) row
-            def body(carry, step):
-                caches, cur, key, done = carry
-                pos = None if lens is None else (lens + step - 1)[:, None]
-                logits, caches = model_step(pa, cur[:, None], caches, pos)
-                key, kk = jax.random.split(key)
-                new = pick(logits[:, -1].astype(jnp.float32), kk)
-                new = new.astype(jnp.int32)
-                if eos_token_id is not None:
-                    new = jnp.where(done, jnp.asarray(eos_token_id,
-                                                      new.dtype), new)
-                    done = done | (new == eos_token_id)
-                return (caches, new, key, done), new
-            return body
-
-        def run(pa, caches, last_logits, lens, done0, key0):
-            key0, k1 = jax.random.split(key0)
-            nxt = pick(last_logits, k1).astype(jnp.int32)
-            done = done0 if eos_token_id is None else \
-                (done0 | (nxt == eos_token_id))
-            (caches, _, _, done), toks = lax.scan(
-                body_fn(pa, lens), (caches, nxt, key0, done),
-                jnp.arange(1, max_new_tokens, dtype=jnp.int32))
-            out = jnp.concatenate([nxt[:, None], jnp.moveaxis(toks, 0, 1)],
-                                  axis=1).astype(jnp.int64)
-            # stateless callers get a tokens-only executable — the cache
-            # pytree must not ride out as live output buffers they drop
-            return (out, caches, done) if return_state else out
-
-        def run_resume(pa, caches, pending, lens, g0, done0, key0):
-            # the resumed chunk has no un-sampled logits to start from: it
-            # FEEDS the previous chunk's pending token first. The body's
-            # invariant is `step s feeds the s-th generated token` (at row
-            # position lens + s - 1); pending is token gen0, so this
-            # chunk's steps are gen0 .. gen0+max_new_tokens-1. gen0 rides
-            # in as a DATA input (g0), not a trace constant: one resume
-            # executable per chunk SIZE serves every resume depth, so a
-            # serving loop decoding [1, c, c, ...] compiles two decode
-            # programs total however long the schedule is.
-            (caches, _, _, done), toks = lax.scan(
-                body_fn(pa, lens),
-                (caches, pending.astype(jnp.int32), key0, done0),
-                g0 + jnp.arange(max_new_tokens, dtype=jnp.int32))
-            out = jnp.moveaxis(toks, 0, 1).astype(jnp.int64)
-            return (out, caches, done) if return_state else out
-
-        # return_state is part of the signature: the stateless executable
-        # returns ONLY the tokens (as before resume existed), the stateful
-        # one adds the cache pytree + done mask it hands to the next chunk
-        sig = ("decode", b, p_len, L, int(max_new_tokens),
-               float(temperature), int(top_k), float(top_p),
-               None if eos_token_id is None else int(eos_token_id),
-               str(cdt), "q8" if q8 else "full",
-               "c8" if state["c8"] else "cfull",
-               "ragged" if ragged else "fixed",
-               "resume" if resume else "fresh",
-               "st" if return_state else "nost",
-               "don" if donate_cache else "nodon")
-        fn = self._gen_cache_get(
-            sig, lambda: jax.jit(
-                run_resume if resume else run,
-                donate_argnums=(1,) if donate_cache else ()))
-        done0 = state.get("done")
-        if done0 is None:
-            done0 = jnp.zeros((b,), bool)
-        args = (state["payload"], state["caches"],
-                state["pending"] if resume else state["last_logits"],
-                state.get("lens"))
-        if resume:
-            args += (jnp.int32(gen0),)
-        res = fn(*args, done0, jax.random.PRNGKey(seed))
-        if not return_state:
-            return Tensor(res)
-        toks, caches, done = res
-        new_state = dict(state)
-        new_state.update(caches=caches, pending=toks[:, -1], done=done,
-                         generated=gen0 + int(max_new_tokens),
-                         last_logits=None)
-        return Tensor(toks), new_state
-
     # ------------------------------------------------ paged-pool serving
     def kv_pool_geometry(self, block_size: int) -> dict:
         """What `BlockPool.for_model` builds: K and V planes a layer, a
@@ -1372,9 +1089,8 @@ class GPTForCausalLM(Layer):
                      cache_dtype: str = None):
         """One compiled chunk of ragged decode against the paged pool.
 
-        Feeds `pending` (each row's last sampled-but-unwritten token,
-        same resume convention as decode_static's return_state), writes
-        its K/V at each row's own position `lens[b]`, and scans
+        Feeds `pending` (each row's last sampled-but-unwritten token),
+        writes its K/V at each row's own position `lens[b]`, and scans
         `max_new_tokens` fixed-shape steps. block_tables/lens/pending/done
         are DATA inputs — the serving engine edits them per batch slot
         between chunks (slot-level splicing) without ever changing a
@@ -1622,12 +1338,12 @@ class GPTForCausalLM(Layer):
         return expand
 
     def _gen_cache_get(self, sig, build):
-        """LRU-capped compiled-runner cache shared by every static-serving
-        entry point (generate_static/_ragged, prefill/decode_static). A
-        build here is a new serving executable — it feeds the process-wide
-        jit cache-miss counter so StepMonitor (and the serving engine's
-        steady-state guard) see serving compiles exactly like training
-        recompiles."""
+        """LRU-capped compiled-runner cache shared by every serving
+        entry point (generate_static/_ragged, prefill/decode/verify_paged
+        and the engine's helpers). A build here is a new serving
+        executable — it feeds the process-wide jit cache-miss counter so
+        StepMonitor (and the serving engine's steady-state guard) see
+        serving compiles exactly like training recompiles."""
         import collections
         from ..jit.api import _note_cache_miss
         cache = getattr(self, "_gen_static_cache", None)
@@ -1637,9 +1353,9 @@ class GPTForCausalLM(Layer):
         if fn is None:
             _note_cache_miss()
             fn = cache[sig] = build()
-            # 16 comfortably holds a serving engine's working set: one
-            # prefill + one fresh-decode + one resume-decode executable
-            # per chunk size (resume depth is a data input, not a sig key)
+            # 16 comfortably holds a serving engine's working set: the
+            # prefill forms, one decode and one verify executable per
+            # chunk size, and the engine's small helpers
             while len(cache) > 16:
                 cache.popitem(last=False)
         else:

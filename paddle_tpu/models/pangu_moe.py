@@ -12,12 +12,12 @@ cache holds one latent `[N_kv(c_kv) | RoPE(k_pe)]` a token and layer
 scores and the context through W_uv after them, so no key or value head is
 ever built.
 
-The model implements what `inference.ServingEngine(paged=True)` calls:
+The model implements what `inference.ServingEngine` calls:
 `config`, `prefill_paged`, `decode_paged`, `_gen_cache_get`, the pool
 geometry (`kv_pool_geometry`) and the per-step expert counters
 (`pop_step_counters`), plus a plain differentiable `forward` for tests.
-Speculative decoding, head-sharded pools, int8 latents and the static
-(non-paged) engine are refused by `check_serving_config`.
+Speculative decoding, head-sharded pools and int8 latents are refused by
+`check_serving_config`.
 """
 from __future__ import annotations
 
@@ -276,7 +276,6 @@ class PanguMoEForCausalLM(Layer):
     def check_serving_config(self, cfg) -> None:
         """Refuses what this model does not implement, at engine build."""
         bad = [why for cond, why in (
-            (not cfg.paged, "paged=False (only the paged engine)"),
             (cfg.spec_decode, "spec_decode=True (no verify_paged)"),
             ((cfg.shards or 1) > 1, "shards > 1 (the latent pool has no "
                                     "head axis to shard)"),

@@ -21,7 +21,7 @@ that surface, stdlib-only:
                      evaluated as multi-window burn rates over the
                      existing log-bucket histograms, alerting through
                      the structured JSONL path (slo.py; `parse_slo` /
-                     `evaluate_slo` back the serve_bench --slo gate).
+                     `evaluate_slo` judge a whole run).
 
 Fleet scope (ISSUE 13) — one replica's surface is not a fleet's:
 

@@ -255,7 +255,7 @@ class Prober:
             return rng.randint(1, vocab, (n,)).astype(np.int64)
 
         out = [("decode", prompt(max(1, min(cfg.prompt_cap, 8))))]
-        if cfg.paged and cfg.prefix_cache:
+        if cfg.prefix_cache:
             bs = cfg.kv_block
             aligned = min(2 * bs, (cfg.prompt_cap // bs) * bs)
             if aligned >= bs:
@@ -629,8 +629,6 @@ class InvariantAuditor:
 
     def _run_checks(self) -> Dict[str, List[str]]:
         eng = self.engine
-        if not eng.config.paged:
-            return {c: [] for c in self.CHECKS}
         pool = eng._pool
         prefix = getattr(eng, "_prefix", None)
         pools = getattr(eng, "_pools", None)

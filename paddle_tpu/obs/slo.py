@@ -140,7 +140,7 @@ def _target_counts(target: SLOTarget, metrics) -> Tuple[int, int]:
 
 
 def evaluate_slo(targets: List[SLOTarget], metrics) -> List[dict]:
-    """Whole-history evaluation (the serve_bench gate): burn over
+    """Whole-history evaluation: burn over
     everything the metrics saw. `ok` iff burn <= 1.0 — i.e. the run as a
     whole met the objective."""
     rows = []

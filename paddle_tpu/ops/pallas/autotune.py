@@ -26,8 +26,7 @@ for GPT-1.3B S=2048 the tuner picks (256,512) which wins in isolation but
 loses 6 MFU points inside the full training step (smaller K/V tiles
 re-read HBM; the bandwidth they steal is invisible when the kernel runs
 alone). `tune_in_step` closes this trap: it times candidates inside a
-caller-supplied FULL step (bench.py wires it for the flagship via
-PADDLE_TPU_BENCH_AUTOTUNE=step). The isolated `tune_flash_blocks` remains
+caller-supplied FULL step. The isolated `tune_flash_blocks` remains
 for quick exploration.
 """
 from __future__ import annotations

@@ -403,7 +403,7 @@ def test_engine_step_helpers_at_the_serving_cells_geometry(one_chip, b,
     model.eval()
     eng = ServingEngine(model, ServingConfig(
         max_batch=b, prompt_cap=8, max_new_tokens=chunk + 2,
-        decode_chunk=chunk, paged=True, kv_block=4, prefix_cache=True,
+        decode_chunk=chunk, kv_block=4, prefix_cache=True,
         prefill_chunk=4))
     with lint_capture() as calls:
         eng.submit(np.arange(1, 7))

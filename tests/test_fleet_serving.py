@@ -40,7 +40,7 @@ def served_model():
 
 def _engine(m, **kw):
     base = dict(max_batch=2, prompt_cap=CAP, max_new_tokens=NEW,
-                decode_chunk=2, paged=True, kv_block=4,
+                decode_chunk=2, kv_block=4,
                 prefix_cache=True)
     base.update(kw)
     return ServingEngine(m, ServingConfig(**base))
@@ -133,7 +133,7 @@ class TestRetriableTagging:
         m, _ = served_model
         path = tmp_path / "reqs.jsonl"
         eng = ServingEngine(m, ServingConfig(
-            max_batch=2, prompt_cap=CAP, max_new_tokens=NEW, paged=True,
+            max_batch=2, prompt_cap=CAP, max_new_tokens=NEW,
             kv_block=4, prefix_cache=True),
             metrics=ServingMetrics(jsonl_path=str(path)))
         eng.begin_drain()

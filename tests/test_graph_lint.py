@@ -284,37 +284,12 @@ _LINT = dict(upcast_bytes=256, const_bytes=2048, donate_bytes=2048)
 
 # ------------------------------------- acceptance pin: core executables
 
-def test_gpt_static_engine_lint_clean():
-    """The padded engine's {prefill_static, decode_static} executables
-    pass every pass (non-allowlisted findings = 0), audited through the
-    engine's own lint= wiring on the warmup batch."""
-    from paddle_tpu.inference import ServingConfig, ServingEngine
-    model, _ = _tiny_gpt()
-    eng = ServingEngine(model, ServingConfig(
-        max_batch=2, prompt_cap=8, max_new_tokens=4, decode_chunk=2,
-        lint=GraphLint(**_LINT)))
-    eng.submit(np.arange(1, 6))
-    eng.submit(np.arange(2, 9))
-    done = eng.drain()
-    assert all(r.status == "done" for r in done)
-    fs = eng.lint_findings
-    assert fs is not None, "engine never audited its executables"
-    active = fs.active("warn")
-    assert not active, f"padded executables not lint-clean: " \
-                       f"{[str(f) for f in active]}"
-    # the audit must have SEEN the graphs: the documented bf16 exceptions
-    # (attention softmax, layernorm moments, sampling head) show up
-    # allowed — an empty report would mean the capture missed the calls
-    assert any(f.allowed for f in fs)
-    assert {f.pass_name for f in fs} >= {"dtype_promotion"}
-
-
 def test_gpt_paged_engine_lint_clean_and_donation_aliased():
     from paddle_tpu.inference import ServingConfig, ServingEngine
     model, _ = _tiny_gpt()
     eng = ServingEngine(model, ServingConfig(
         max_batch=2, prompt_cap=8, max_new_tokens=4, decode_chunk=2,
-        paged=True, kv_block=4, lint=GraphLint(**_LINT)))
+        kv_block=4, lint=GraphLint(**_LINT)))
     eng.submit(np.arange(1, 6))
     eng.submit(np.arange(2, 9))
     done = eng.drain()
@@ -324,6 +299,11 @@ def test_gpt_paged_engine_lint_clean_and_donation_aliased():
     active = fs.active("warn")
     assert not active, f"paged executables not lint-clean: " \
                        f"{[str(f) for f in active]}"
+    # the audit must have SEEN the graphs: the documented bf16 exceptions
+    # (attention softmax, layernorm moments, sampling head) show up
+    # allowed — an empty report would mean the capture missed the calls
+    assert any(f.allowed for f in fs)
+    assert {f.pass_name for f in fs} >= {"dtype_promotion"}
     # r10's donated pools must be ALIASED, not silently copied: the
     # donation pass ran over the paged pair and reported no misses
     assert not [f for f in fs if f.code == "donated_unaliased"]
@@ -489,7 +469,7 @@ def test_serving_lint_audits_late_built_executables():
     model, _ = _tiny_gpt()
     eng = ServingEngine(model, ServingConfig(
         max_batch=2, prompt_cap=8, max_new_tokens=4, decode_chunk=2,
-        paged=True, kv_block=4, lint=GraphLint(**_LINT)))
+        kv_block=4, lint=GraphLint(**_LINT)))
     # budget-1 request: finishes inside _admit_paged, decode never runs
     eng.submit(np.arange(1, 5), max_new_tokens=1)
     eng.drain()
@@ -506,32 +486,38 @@ def test_serving_lint_audits_late_built_executables():
 
 
 def test_paged_cache_dtype_config_finding():
-    """ISSUE 6 satellite, updated by ISSUE 10: int8+paged now SERVES
-    (the paged int8 pool landed); a cache dtype the paged engine still
-    cannot hold keeps the structured config-validation finding (same
-    schema as the lint), still a ValueError for existing callers, and
-    says WHY + what to do."""
+    """int8 pools SERVE; a cache dtype the engine cannot hold gets the
+    structured config-validation finding (same schema as the lint),
+    still a ValueError for existing callers, and says WHY + what to do."""
     from paddle_tpu.inference import ServingConfig
-    cfg = ServingConfig(paged=True, cache_dtype="int8")
+    cfg = ServingConfig(cache_dtype="int8")
     assert cfg.cache_dtype == "int8"       # the ISSUE-10 mode
     with pytest.raises(ConfigValidationError) as ei:
-        ServingConfig(paged=True, cache_dtype="float16")
+        ServingConfig(cache_dtype="float16")
     assert isinstance(ei.value, ValueError)
     f = ei.value.finding
     assert f.pass_name == "config"
     assert f.code == "paged_cache_dtype"
     assert "model dtype" in f.message.lower()
-    assert "paged=False" in f.message      # the actionable way out
-    assert f.data == {"cache_dtype": "float16", "paged": True}
+    assert "cache_dtype='int8'" in f.message   # the actionable way out
+    assert f.data == {"cache_dtype": "float16"}
 
 
 def test_lint_capture_records_serving_executables():
     model, _ = _tiny_gpt("float32")
+    from paddle_tpu.inference import BlockPool
+    pool = BlockPool.for_model(model, num_blocks=4, block_size=4)
+    pool.alloc(0, 6)
+    tables = pool.table_row(0, 2)[None]
     with lint_capture() as calls:
-        st = model.prefill_static(np.ones((1, 4), np.int64), max_len=8)
-        model.decode_static(st, 2)
+        pools, first = model.prefill_paged(
+            np.ones((1, 4), np.int64), np.int32([4]), pool.make_pools(),
+            tables)
+        model.decode_paged(pools, tables, np.int32([4]),
+                           first.numpy().astype(np.int32),
+                           np.zeros((1,), bool), 2)
     kinds = [k[0] for k, _, _ in calls]
-    assert "prefill" in kinds and "decode" in kinds
+    assert "paged_prefill" in kinds and "paged_decode" in kinds
     fs = GraphLint(**_LINT).check_calls(calls)
     assert not fs.active("warn")
 

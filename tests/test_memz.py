@@ -235,7 +235,7 @@ def live():
     model.eval()
     engine = ServingEngine(model, ServingConfig(
         max_batch=2, prompt_cap=8, max_new_tokens=4, decode_chunk=2,
-        paged=True, kv_block=4, kv_blocks=16, prefix_cache=True))
+        kv_block=4, kv_blocks=16, prefix_cache=True))
     ledger = engine.attach_memory_ledger(
         MemoryLedger(capacity_bytes=1 << 30))
     rng = np.random.RandomState(0)
@@ -320,7 +320,7 @@ class TestLiveEngine:
     def test_kv_oom_reject_names_top_owners(self, live):
         eng = ServingEngine(live["model"], ServingConfig(
             max_batch=2, prompt_cap=12, max_new_tokens=8, decode_chunk=4,
-            paged=True, kv_block=4, kv_blocks=5))
+            kv_block=4, kv_blocks=5))
         eng.attach_memory_ledger()
         # 12 + 8 - 1 = 19 rows > the whole pool (4 usable blocks = 16)
         f = eng.preflight(np.arange(1, 13, dtype=np.int64), 8)
@@ -333,7 +333,7 @@ class TestLiveEngine:
     def test_mem_pressure_rows_paired_per_episode(self, live):
         eng = ServingEngine(live["model"], ServingConfig(
             max_batch=2, prompt_cap=12, max_new_tokens=4, decode_chunk=2,
-            paged=True, kv_block=4, kv_blocks=6))
+            kv_block=4, kv_blocks=6))
         eng.attach_memory_ledger()
         rows = []
         eng.metrics.on_record = rows.append

@@ -527,85 +527,6 @@ def test_generate_static_int8_weights_and_kv_compose(monkeypatch):
     assert not np.isnan(both.astype(np.float64)).any()
 
 
-def test_prefill_decode_static_prefix_reuse():
-    """prefill_static/decode_static (r5 prefix-reuse serving): one prompt
-    forward fans out to many continuations — greedy decode equals
-    generate_static's tail, repeated decodes from one state are identical
-    (the state is immutable), different sampling seeds diverge, int8
-    weights+cache compose, and capacity overflow raises."""
-    import numpy as np
-    import pytest
-    paddle.seed(3)
-    cfg = GPTConfig(vocab_size=96, hidden_size=128, num_layers=2,
-                    num_heads=4, max_position_embeddings=64,
-                    intermediate_size=256)
-    m = GPTForCausalLM(cfg)
-    m.eval()
-    ids = paddle.to_tensor(
-        np.random.RandomState(4).randint(1, 96, (2, 8)).astype(np.int64))
-    full = m.generate_static(ids, max_new_tokens=8).numpy()
-    st = m.prefill_static(ids, max_len=16)
-    d1 = m.decode_static(st, max_new_tokens=8).numpy()
-    assert (d1 == full[:, 8:]).all()
-    d2 = m.decode_static(st, max_new_tokens=8).numpy()
-    assert (d1 == d2).all()
-    s1 = m.decode_static(st, max_new_tokens=8, temperature=0.9,
-                         seed=1).numpy()
-    s2 = m.decode_static(st, max_new_tokens=8, temperature=0.9,
-                         seed=2).numpy()
-    assert not (s1 == s2).all()
-    # eos handling inside the reused-state decode
-    eos = int(d1[0, 0])
-    de = m.decode_static(st, max_new_tokens=8, eos_token_id=eos).numpy()
-    assert (de[0] == eos).all()          # row 0 hits eos immediately
-    with pytest.raises(ValueError):
-        m.decode_static(st, max_new_tokens=64)     # 8 + 64 > max_len 16
-    with pytest.raises(ValueError):
-        m.prefill_static(ids, max_len=8)           # no decode room
-    # int8 cache composes with the prefix-reuse path
-    st8 = m.prefill_static(ids, max_len=16, cache_dtype="int8")
-    d8 = m.decode_static(st8, max_new_tokens=8).numpy()
-    assert d8.shape == d1.shape
-    assert (d8 == full[:, 8:]).mean() >= 0.5
-    # RAGGED prompts compose: per-row greedy tail equals
-    # generate_static_ragged on the same padded prompts/lens
-    lens = [3, 8]
-    r_full = m.generate_static_ragged(ids, lens, max_new_tokens=6).numpy()
-    str_ = m.prefill_static(ids, max_len=16, prompt_lens=lens)
-    dr = m.decode_static(str_, max_new_tokens=6).numpy()
-    assert (dr == r_full[:, 8:]).all()
-    with pytest.raises(ValueError):
-        m.prefill_static(ids, max_len=16, prompt_lens=[0, 8])  # len 0
-
-
-def test_decode_static_capacity_and_stale_weight_guard():
-    """r6 (ADVICE r5): the last sampled token is never written to the KV
-    cache, so p_len + max_new_tokens - 1 == max_len is admissible; and
-    decode against parameters mutated since prefill is rejected (decode
-    replays the prefill-time snapshot)."""
-    import numpy as np
-    import pytest
-    paddle.seed(5)
-    cfg = GPTConfig(vocab_size=96, hidden_size=64, num_layers=2,
-                    num_heads=4, max_position_embeddings=64,
-                    intermediate_size=128)
-    m = GPTForCausalLM(cfg)
-    m.eval()
-    ids = paddle.to_tensor(
-        np.random.RandomState(6).randint(1, 96, (2, 8)).astype(np.int64))
-    st = m.prefill_static(ids, max_len=16)
-    out = m.decode_static(st, max_new_tokens=9)    # 8 + 9 - 1 == 16 == L
-    assert tuple(out.shape) == (2, 9)
-    with pytest.raises(ValueError):
-        m.decode_static(st, max_new_tokens=10)     # 8 + 10 - 1 > 16
-    # stale-weight replay guard: a same-dtype weight swap must be caught
-    st2 = m.prefill_static(ids, max_len=16)
-    p = next(iter(m.parameters()))
-    p.set_value(p.numpy())                         # same values, new array
-    with pytest.raises(ValueError, match="parameters changed"):
-        m.decode_static(st2, max_new_tokens=4)
-
-
 def test_attention_q8_cache_matches_dequant():
     """attention_q8_cache's factored scales (q·cᵀ·s_k; (p·s_v)·c_v) must be
     numerically equivalent to attending over explicitly dequantized K/V."""

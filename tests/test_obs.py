@@ -486,7 +486,8 @@ class TestTelemetryServer:
                         h = _get_json(srv.url("/healthz"))
                         assert h["status"] == "ok"
                         s = _get_json(srv.url("/statusz"))
-                        assert s["engine"]["paged"] is False
+                        assert s["kv"]["blocks_total"] == \
+                            eng.config.kv_blocks
                         _get_json(srv.url("/tracez"))
                         results["passes"] += 1
                 except Exception as e:           # noqa: BLE001
@@ -615,7 +616,7 @@ class TestPagedTraceEvents:
         m, cfg = served_model
         eng = ServingEngine(m, ServingConfig(
             max_batch=2, prompt_cap=8, max_new_tokens=4, decode_chunk=2,
-            paged=True, kv_block=4, prefix_cache=True))
+            kv_block=4, prefix_cache=True))
         rng = np.random.RandomState(5)
         p = rng.randint(1, cfg.vocab_size, (8,)).astype(np.int64)
         eng.submit(p)

@@ -4,8 +4,8 @@ Covers the block-pool allocator (alloc/free/reuse, fragmentation, OOM →
 reject with reason), paged-vs-reference attention parity across ragged
 lengths (including a row at an exact block boundary), the Pallas kernel in
 interpret mode, model-level bit-parity of paged prefill/decode with
-generate_static_ragged, buffer donation (decode_static satellite + the
-paged pools), the true-token occupancy gauges, and the engine's
+generate_static_ragged, buffer donation (the paged pools), the
+true-token occupancy gauges, and the engine's
 slot-level continuous batching: a short request finishes early, frees its
 blocks immediately, and a queued request is spliced into the vacated slot
 mid-flight with ZERO recompiles.
@@ -343,41 +343,11 @@ def test_paged_pools_are_donated(served_model):
         m.prefill_paged(ids, np.int32([5]), bad, tables)
 
 
-def test_decode_static_donates_cache_buffers(served_model):
-    """Satellite: donate_cache=True updates the static KV tuples in place
-    (input buffers consumed, tokens bit-identical); the default keeps the
-    prefill fan-out contract (buffers intact, decodes repeatable)."""
-    m, cfg = served_model
-    lens = [CAP, 5]
-    ids = _prompts(cfg, lens)
-    t = paddle.to_tensor(ids)
-    ref = m.generate_static_ragged(t, lens, max_new_tokens=NEW).numpy()[:, CAP:]
-
-    st = m.prefill_static(t, max_len=CAP + NEW, prompt_lens=np.int32(lens))
-    buf0 = st["caches"][0][0]
-    t1, st = m.decode_static(st, 1, return_state=True, donate_cache=True)
-    assert buf0.is_deleted()            # donated: consumed, not copied
-    t2, st = m.decode_static(st, NEW - 1, return_state=True,
-                             donate_cache=True)
-    got = np.concatenate([t1.numpy(), t2.numpy()], axis=1)
-    np.testing.assert_array_equal(got, ref)
-
-    # default: NOT donated — one prefill fans out to many continuations
-    st = m.prefill_static(t, max_len=CAP + NEW, prompt_lens=np.int32(lens))
-    buf0 = st["caches"][0][0]
-    a, _ = m.decode_static(st, 3, return_state=True)
-    b, _ = m.decode_static(st, 3, return_state=True)
-    assert not buf0.is_deleted()
-    np.testing.assert_array_equal(a.numpy(), b.numpy())
-    with pytest.raises(ValueError, match="donate_cache"):
-        m.decode_static(st, 1, donate_cache=True)   # needs return_state
-
-
 # ------------------------------------------------------ the paged engine
 
 def _engine(m, **kw):
     base = dict(max_batch=2, prompt_cap=CAP, max_new_tokens=NEW,
-                decode_chunk=2, paged=True, kv_block=4)
+                decode_chunk=2, kv_block=4)
     base.update(kw)
     return ServingEngine(m, ServingConfig(**base))
 
@@ -476,24 +446,10 @@ def test_engine_kv_oom_reject_reason(served_model):
 
 def test_occupancy_gauges_pinned_math(served_model):
     """kv_occupancy = live tokens / pooled capacity; kv_slots_occupancy =
-    allocation-granular rows / capacity — pinned on both engines."""
+    allocation-granular rows / capacity."""
     m, cfg = served_model
-    # padded engine: 1 request (len 4) in a 2-slot batch, full budget
-    eng = ServingEngine(m, ServingConfig(max_batch=2, prompt_cap=CAP,
-                                         max_new_tokens=NEW,
-                                         decode_chunk=3))
-    eng.submit(_prompts(cfg, [4])[0, :4])
-    eng.drain()
-    s = eng.summary()
-    L = eng.config.max_len
-    # device-side decode runs the full chunk schedule (fixed shapes), so
-    # written rows = prompt + schedule_sum - 1 even when the row's budget
-    # truncates the returned tokens
-    written = 4 + sum(eng.config.chunk_schedule) - 1
-    assert s["kv_occupancy"] == written / (2 * L)
-    assert s["kv_slots_occupancy"] == L / (2 * L)
-    # paged engine: 1 request (len 5, budget 2) -> snapshot at the decode
-    # chunk entry holds 5 live rows over (kv_blocks-1)*kv_block capacity,
+    # 1 request (len 5, budget 2) -> snapshot at the decode chunk entry
+    # holds 5 live rows over (kv_blocks-1)*kv_block capacity,
     # with ceil((5+2-1)/4)=2 blocks reserved
     eng = _engine(m)
     cap_tokens = (eng.config.kv_blocks - 1) * 4
@@ -507,7 +463,7 @@ def test_occupancy_gauges_pinned_math(served_model):
 def test_engine_paged_exception_recovers(served_model):
     """A batch dying mid-flight records the in-flight requests as errors
     AND rebuilds the (possibly consumed, donated) pools — the engine stays
-    usable, matching the padded engine's contract."""
+    usable."""
     m, cfg = served_model
     eng = _engine(m)
     ids = _prompts(cfg, [5])
@@ -546,7 +502,7 @@ def test_longtail_traffic_profile():
 def test_engine_paged_under_load_open_loop(served_model):
     """Open-loop long-tail replay through the paged engine: everything
     completes, outputs stay bit-identical per row, zero steady-state
-    recompiles (the serve_bench --paged path minus the CLI)."""
+    recompiles."""
     m, cfg = served_model
     eng = _engine(m)
     traffic = synthetic_traffic(24, prompt_cap=CAP,

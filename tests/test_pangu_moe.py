@@ -50,7 +50,7 @@ def model(config):
 
 
 def _engine(model, **kw):
-    cfg = dict(paged=True, prefix_cache=True, max_batch=3, prompt_cap=40,
+    cfg = dict(prefix_cache=True, max_batch=3, prompt_cap=40,
                max_new_tokens=8, decode_chunk=3, kv_block=4, kv_blocks=64,
                prefill_chunk=16)
     cfg.update(kw)
@@ -161,10 +161,12 @@ def test_the_engine_reports_the_experts_counters(model):
 
 @pytest.mark.parametrize("bad", [
     dict(spec_decode=True, spec_k=2), dict(shards=2), dict(cache_dtype="int8"),
-    dict(paged=False, prefix_cache=False, prefill_chunk=None)])
+    dict(weight_dtype="int8")])
 def test_what_the_model_does_not_serve_is_refused(model, bad):
     with pytest.raises(ValueError, match="PanguMoEForCausalLM does not serve"):
         _engine(model, **bad)
+    with pytest.raises(ValueError, match="padded engine was removed"):
+        _engine(model, paged=False)
 
 
 def test_gpt_engines_have_no_expert_counters():
@@ -174,7 +176,7 @@ def test_gpt_engines_have_no_expert_counters():
                              vocab_size=64, hidden_size=32, num_layers=1,
                              num_heads=2, intermediate_size=64,
                              max_position_embeddings=32))
-    eng = ServingEngine(gpt, ServingConfig(paged=True, max_batch=2,
+    eng = ServingEngine(gpt, ServingConfig(max_batch=2,
                                            prompt_cap=8, max_new_tokens=2,
                                            kv_block=4))
     assert "expert_layer_calls" not in eng.metrics.counters

@@ -325,22 +325,23 @@ def _prompts(cfg, lens, seed=1):
 
 def _engine(m, **kw):
     base = dict(max_batch=2, prompt_cap=CAP, max_new_tokens=NEW,
-                decode_chunk=2, paged=True, kv_block=4, prefix_cache=True)
+                decode_chunk=2, kv_block=4, prefix_cache=True)
     base.update(kw)
     return ServingEngine(m, ServingConfig(**base))
 
 
 def test_config_paged_cache_dtype_validation():
-    """int8 + paged is now a served combination; other narrow dtypes
-    keep the structured config-validation finding."""
+    """int8 pools are a served combination; other narrow dtypes keep
+    the structured config-validation finding."""
     from paddle_tpu.analysis.findings import ConfigValidationError
-    cfg = ServingConfig(paged=True, cache_dtype="int8")
+    cfg = ServingConfig(cache_dtype="int8")
     assert cfg.cache_dtype == "int8"
     with pytest.raises(ConfigValidationError) as ei:
-        ServingConfig(paged=True, cache_dtype="float16")
+        ServingConfig(cache_dtype="float16")
     assert ei.value.finding.code == "paged_cache_dtype"
-    with pytest.raises(ValueError, match="requires paged"):
-        ServingConfig(prefix_cache=True)
+    with pytest.raises(ValueError, match="padded engine was removed"):
+        ServingConfig(prefix_cache=True, paged=False)
+    assert ServingConfig(prefix_cache=True).prefix_cache
 
 
 def test_zero_prefill_admission_repeated_prefix(served_model):
@@ -617,7 +618,7 @@ def test_warmup_prefix_cache_covers_every_executable(served_model):
     assert compile_cache_misses() - miss0 == 0
     with pytest.raises(ValueError, match="prefix_cache=True"):
         ServingEngine(m, ServingConfig(max_batch=1, prompt_cap=CAP,
-                                       max_new_tokens=2, paged=True,
+                                       max_new_tokens=2,
                                        kv_block=4)) \
             .warmup_prefix_cache(cfg.vocab_size)
 

@@ -51,7 +51,7 @@ def served_model():
 
 def _engine(m, **kw):
     base = dict(max_batch=2, prompt_cap=CAP, max_new_tokens=NEW,
-                decode_chunk=2, paged=True, kv_block=4,
+                decode_chunk=2, kv_block=4,
                 prefix_cache=True)
     base.update(kw)
     return ServingEngine(m, ServingConfig(**base))

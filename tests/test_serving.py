@@ -1,7 +1,6 @@
-"""Serving observability: ServingEngine over the static decode stack,
-request metrics (histograms/gauges/counters + JSONL records), the shared
-Prometheus renderer, resumable decode_static, and the wired
-inference.Config.enable_profile().
+"""Serving observability: the ServingEngine, request metrics
+(histograms/gauges/counters + JSONL records), the shared Prometheus
+renderer, and the wired inference.Config.enable_profile().
 
 Engine acceptance (ISSUE 4): greedy outputs bit-identical to
 generate_static_ragged on the same prompts; ZERO jit cache misses across a
@@ -182,45 +181,6 @@ def _prompts(cfg, lens, seed=1):
     return ids
 
 
-# ------------------------------------------------------- resumable decode
-
-def test_decode_static_resume_greedy_parity(served_model):
-    """Chunked decode over return_state must replay the one-shot argmax
-    chain bit-for-bit (ragged positions offset by `generated`)."""
-    m, cfg = served_model
-    lens = [CAP, 5]
-    ids = _prompts(cfg, lens)
-    t = paddle.to_tensor(ids)
-    ref = m.generate_static_ragged(t, lens, max_new_tokens=NEW).numpy()[:, CAP:]
-    st = m.prefill_static(t, max_len=CAP + NEW, prompt_lens=np.int32(lens))
-    t1, st = m.decode_static(st, 1, return_state=True)
-    t2, st = m.decode_static(st, 2, return_state=True)
-    t3, st = m.decode_static(st, 3, return_state=True)
-    got = np.concatenate([t1.numpy(), t2.numpy(), t3.numpy()], axis=1)
-    np.testing.assert_array_equal(got, ref)
-    assert st["generated"] == NEW
-    with pytest.raises(ValueError, match="cache rows"):
-        m.decode_static(st, 100)       # resumed capacity accounting
-
-
-def test_decode_static_resume_carries_eos_mask(served_model):
-    m, cfg = served_model
-    lens = [CAP, 5]
-    ids = _prompts(cfg, lens)
-    t = paddle.to_tensor(ids)
-    ref = m.generate_static_ragged(t, lens, max_new_tokens=NEW).numpy()
-    eos = int(ref[0, CAP])             # row 0 "emits EOS" on token 1
-    refe = m.generate_static_ragged(t, lens, max_new_tokens=NEW,
-                                    eos_token_id=eos).numpy()[:, CAP:]
-    st = m.prefill_static(t, max_len=CAP + NEW, prompt_lens=np.int32(lens))
-    a, st = m.decode_static(st, 1, eos_token_id=eos, return_state=True)
-    b, st = m.decode_static(st, NEW - 1, eos_token_id=eos,
-                            return_state=True)
-    got = np.concatenate([a.numpy(), b.numpy()], axis=1)
-    np.testing.assert_array_equal(got, refe)
-    assert (got[0] == eos).all()       # done row kept emitting EOS
-
-
 # ------------------------------------------------------------ the engine
 
 def test_engine_greedy_parity_with_ragged(served_model):
@@ -245,9 +205,134 @@ def test_engine_greedy_parity_with_ragged(served_model):
         assert tr.ttft_s >= 0 and tr.e2e_s >= tr.ttft_s
 
 
+def test_default_config_is_the_engine_the_cells_run():
+    """`ServingConfig()` with no arguments is the paged engine: a pool for
+    the worst case of every slot, one decode chunk per budget. The
+    `paged` keyword survives as a value that can only be True."""
+    c = ServingConfig()
+    assert c.paged is True
+    assert c.decode_chunk == c.max_new_tokens - 1 == 31
+    # a cap prompt decoding its whole budget never writes its last token
+    assert c.row_kv_rows == c.prompt_cap + c.max_new_tokens - 1 == 95
+    assert c.table_width == -(-95 // c.kv_block) == 6
+    assert c.kv_blocks == c.max_batch * c.table_width + 1 == 25
+    assert ServingConfig(max_new_tokens=1).decode_chunk == 1
+    assert ServingConfig(paged=True).kv_blocks == 25
+    with pytest.raises(ValueError, match="padded engine was removed"):
+        ServingConfig(paged=False)
+
+
+def test_default_engine_fits_a_full_batch_of_worst_case_rows(served_model):
+    """The default pool admits max_batch cap-length prompts at once, each
+    decoding its full budget, with no wait on freed blocks."""
+    m, cfg = served_model
+    eng = ServingEngine(m, ServingConfig(max_batch=3, prompt_cap=CAP,
+                                         max_new_tokens=NEW, kv_block=4))
+    ids = _prompts(cfg, [CAP] * 3)
+    for row in ids:
+        eng.submit(row)
+    eng.step()
+    assert eng.queue_depth == 0 and len(eng._live()) == 3
+    assert eng._pool.free_blocks == 3 * eng.config.table_width \
+        - 3 * eng._pool.blocks_needed(CAP + NEW - 1) == 0
+    done = eng.drain()
+    assert [r.n_out for r in done] == [NEW] * 3
+    assert eng.summary()["mem_pressure_episodes_total"] == 0
+
+
+@pytest.mark.parametrize("prefill_chunk", [None, 4])
+@pytest.mark.parametrize("decode_chunk", [1, 3, None])
+def test_engine_greedy_parity_over_chunkings(served_model, decode_chunk,
+                                             prefill_chunk):
+    """How the work is cut (tokens per decode call, prompt tokens per
+    prefill window) never changes a token: more requests than slots, so
+    rows are spliced in mid-flight at every chunking."""
+    m, cfg = served_model
+    lens = [CAP, 5, 1, 7, 3]
+    ids = _prompts(cfg, lens, seed=11)
+    eng = _engine(m, decode_chunk=decode_chunk, prefill_chunk=prefill_chunk,
+                  kv_block=4)
+    if decode_chunk is None:
+        assert eng.config.decode_chunk == NEW - 1
+    for i, ln in enumerate(lens):
+        eng.submit(ids[i, :ln])
+    done = sorted(eng.drain(), key=lambda r: r.id)
+    ref = m.generate_static_ragged(paddle.to_tensor(ids), lens,
+                                   max_new_tokens=NEW).numpy()[:, CAP:]
+    np.testing.assert_array_equal(np.stack([r.tokens for r in done]), ref)
+    if prefill_chunk is not None:
+        # the cap-length prompt went in as CAP / 4 windows, not one
+        names = [e[0] for e in done[0].trace.events]
+        assert names.count("prefill_chunk") == CAP // prefill_chunk
+    assert eng._pool.free_blocks == eng._pool.capacity_blocks
+
+
+@pytest.mark.parametrize("sampling", [dict(temperature=0.9),
+                                      dict(temperature=0.9, top_k=8),
+                                      dict(temperature=0.9, top_p=0.7)])
+def test_engine_sampling_is_reproducible_from_its_seed(served_model,
+                                                       sampling):
+    """Sampled output is a function of (config seed, traffic): the same
+    seed replays the same tokens, another seed draws others, and top_k
+    keeps every draw inside the k best."""
+    m, cfg = served_model
+    lens = [CAP, 5, 6]
+    ids = _prompts(cfg, lens, seed=5)
+
+    def serve(seed):
+        eng = _engine(m, seed=seed, **sampling)
+        for i, ln in enumerate(lens):
+            eng.submit(ids[i, :ln])
+        return np.stack([r.tokens for r in
+                         sorted(eng.drain(), key=lambda r: r.id)])
+
+    a, b, c = serve(3), serve(3), serve(4)
+    np.testing.assert_array_equal(a, b)
+    assert (a != c).any()
+    greedy = m.generate_static_ragged(paddle.to_tensor(ids), lens,
+                                      max_new_tokens=NEW).numpy()[:, CAP:]
+    assert (a != greedy).any() or (c != greedy).any()
+    if sampling.get("top_k"):
+        # a request's first token is drawn from the prompt's own logits
+        for row, ln in enumerate(lens):
+            logits = m(paddle.to_tensor(ids[row:row + 1, :ln])).numpy()
+            best = np.argsort(logits[0, -1])[-sampling["top_k"]:]
+            assert a[row, 0] in best and c[row, 0] in best
+
+
+def test_n_continuations_of_one_prefix_pay_its_prefill_once(served_model):
+    """One shared prefix, N continuations: the trie prefills the prefix
+    once, every repeat maps its blocks (`prefill_tokens_saved` grows by
+    the prefix per repeat) and each output equals the oracle's."""
+    m, cfg = served_model
+    kb, n = 4, 4
+    rng = np.random.RandomState(9)
+    prefix = rng.randint(1, cfg.vocab_size, (kb,)).astype(np.int64)
+    lens = [CAP, 6, 7, 6]
+    ids = np.zeros((n, CAP), np.int64)
+    for i, ln in enumerate(lens):
+        ids[i, :kb] = prefix
+        ids[i, kb:ln] = rng.randint(1, cfg.vocab_size, (ln - kb,))
+    eng = _engine(m, kv_block=kb, prefix_cache=True)
+    done = []
+    for i, ln in enumerate(lens):      # one at a time: the first caches it
+        eng.submit(ids[i, :ln])
+        done += eng.drain()
+    ref = m.generate_static_ragged(paddle.to_tensor(ids), lens,
+                                   max_new_tokens=NEW).numpy()[:, CAP:]
+    np.testing.assert_array_equal(np.stack([r.tokens for r in done]), ref)
+    s = eng.summary()
+    assert s["prefix_miss_total"] == 1 and s["prefix_hit_total"] == n - 1
+    assert s["prefill_tokens_saved_total"] == (n - 1) * kb
+    # the repeats prefilled their suffix alone
+    assert [e[0] for e in done[0].trace.events][0] == "prefill"
+    for r in done[1:]:
+        assert [e[0] for e in r.trace.events][0] == "suffix_prefill"
+
+
 def test_engine_zero_recompiles_after_warmup(served_model):
     """Acceptance: a steady-state serving loop adds ZERO jit cache misses
-    after the warmup batch — including partial batches (padded rows keep
+    after the warmup batch — including partial batches (idle slots keep
     every shape pinned)."""
     m, cfg = served_model
     eng = _engine(m)
@@ -259,7 +344,7 @@ def test_engine_zero_recompiles_after_warmup(served_model):
     for i in range(3):
         eng.submit(ids[0, :CAP])
         if i != 1:
-            eng.submit(ids[1, :5])     # batch 2 is partial: dummy-padded
+            eng.submit(ids[1, :5])     # round 2 is partial: an idle slot
         eng.drain()
     assert compile_cache_misses() - miss0 == 0
     assert eng.monitor.recompiles == 0
@@ -276,12 +361,16 @@ def test_engine_batch_gauges_and_counters(served_model):
     s = eng.summary()
     assert s["batch_fill_ratio"] == 0.5
     assert 0 < s["kv_occupancy"] <= 1.0
-    # padded engine: each admitted row pins a full max_len slab
-    assert s["kv_slots_occupancy"] == 1 * eng.config.max_len / \
-        (BATCH * eng.config.max_len)
+    # the one admitted row reserves its worst-case blocks and no more
+    pool = eng._pool
+    assert s["kv_slots_occupancy"] == \
+        pool.blocks_needed(4 + NEW - 1) * pool.block_size / \
+        pool.capacity_tokens
     assert s["tokens_in_total"] == 4 and s["tokens_out_total"] == NEW
-    assert s["batches_total"] == 1 and s["completed_total"] == 1
-    assert s["batch_step"]["steps"] == 1
+    assert s["completed_total"] == 1
+    # launch prefill + chunk 1, launch chunk 2 + read, read: every step
+    # that ran a model call or read one is a batch record
+    assert s["batches_total"] == s["batch_step"]["steps"] - 1 == 2
 
 
 def test_engine_rejects_overlong_prompt_with_shape_delta(served_model):
@@ -370,29 +459,35 @@ def test_engine_eos_early_exit_and_token_counts(served_model):
 
 
 def test_warmup_depth_extension_is_not_a_recompile(served_model):
-    """An EOS early-exit can truncate the warmup batch before the deeper
-    chunk executables ever compiled; their eventual first compile is NOT
-    shape churn and must not trip the steady-state recompile guard."""
+    """A request that ends at its first token leaves the decode executable
+    uncompiled; its eventual first compile is NOT shape churn and must not
+    trip the steady-state recompile guard. Once both are warm, a miss is."""
     m, cfg = served_model
-    lens = [CAP, 5, 3, 7, 2, 6, 1, 4]
-    ids = _prompts(cfg, lens)
-    ref = m.generate_static_ragged(paddle.to_tensor(ids), lens,
-                                   max_new_tokens=NEW).numpy()[:, CAP:]
-    # a row whose FIRST greedy token serves as EOS, and a row that does
-    # not meet that token early — picked from what the seed's weights make
-    # of these prompts (under jax 0.9's draws the old fixed pair emitted
-    # the same first token, so the second row never went deeper)
-    first, deep = next((a, b) for a in range(len(lens))
-                       for b in range(len(lens))
-                       if ref[a, 0] not in ref[b, :4])
-    eng = _engine(m, eos_token_id=int(ref[first, 0]))
-    eng.submit(ids[first, :lens[first]])
-    eng.drain()                        # warmup stops after chunk 1
-    assert eng._max_depth == 2         # prefill + first-token chunk only
-    eng.submit(ids[deep, :lens[deep]])  # decodes deeper than warmup did
+    getattr(m, "_gen_static_cache", {}).clear()   # earlier tests' builds
+    eng = _engine(m)
+    ids = _prompts(cfg, [CAP, 5])
+    eng.submit(ids[0, :CAP], max_new_tokens=1)
+    eng.drain()                        # warmup ends at the prefill's token
+    assert eng._paged_seen == {"prefill"}
+    eng.submit(ids[1, :5])             # decodes: goes deeper than warmup did
     eng.drain()
-    assert eng._max_depth > 2
-    assert eng.monitor.recompiles == 0
+    assert "decode" in eng._paged_seen
+    assert eng.monitor.compiles >= 2 and eng.monitor.recompiles == 0
+    # and the guard is live: a build under a warm step counts as churn
+    from paddle_tpu.jit.api import _note_cache_miss
+    real = m.decode_paged
+
+    def rebuilt(*a, **kw):
+        _note_cache_miss()
+        return real(*a, **kw)
+
+    m.decode_paged = rebuilt
+    try:
+        eng.submit(ids[1, :5])
+        eng.drain()
+    finally:
+        del m.decode_paged
+    assert eng.monitor.recompiles >= 1
 
 
 def test_engine_respects_per_request_budget(served_model):
@@ -411,25 +506,29 @@ def test_engine_respects_per_request_budget(served_model):
 
 
 def test_engine_exception_records_inflight_requests(served_model):
-    """A batch dying mid-flight must not lose the admitted requests from
-    the accounting: they land as status='error' before the raise."""
+    """A call dying mid-flight must not lose the admitted requests from
+    the accounting: they land as status='error' before the raise, and the
+    engine serves the next request on rebuilt pools."""
     m, cfg = served_model
     eng = _engine(m)
-    eng.submit(_prompts(cfg, [4])[0, :4])
-    real_prefill = m.prefill_static
+    ids = _prompts(cfg, [4])
+    eng.submit(ids[0, :4])
 
     def boom(*a, **kw):
         raise RuntimeError("injected device failure")
 
-    m.prefill_static = boom
+    m.prefill_paged = boom
     try:
         with pytest.raises(RuntimeError, match="injected"):
             eng.step()
     finally:
-        m.prefill_static = real_prefill
+        del m.prefill_paged
     s = eng.summary()
     assert s["errors_total"] == 1 and s["inflight"] == 0
-    assert eng.queue_depth == 0
+    assert eng.queue_depth == 0 and not eng.busy
+    assert eng._pool.free_blocks == eng._pool.capacity_blocks
+    eng.submit(ids[0, :4])
+    assert [r.status for r in eng.drain()] == ["done"]
 
 
 def test_request_jsonl_schema(served_model, tmp_path):
@@ -513,7 +612,7 @@ def test_synthetic_traffic_shape():
 def test_engine_under_load_open_loop(served_model):
     """Load generation: open-loop replay of 24 requests; everything
     completes, latency histograms fill, and the steady loop never
-    recompiles (the serve_bench path minus the CLI)."""
+    recompiles."""
     m, cfg = served_model
     eng = _engine(m)
     traffic = synthetic_traffic(24, prompt_cap=CAP,

@@ -110,7 +110,7 @@ def mix(served_model):
 
 def _engine(m, eos, **kw):
     base = dict(max_batch=2, prompt_cap=CAP, max_new_tokens=NEW,
-                decode_chunk=CHUNK, paged=True, kv_block=KB,
+                decode_chunk=CHUNK, kv_block=KB,
                 prefix_cache=True, prefill_chunk=WINDOW, eos_token_id=eos)
     base.update(kw)
     return ServingEngine(m, ServingConfig(**base))

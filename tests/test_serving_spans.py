@@ -1,7 +1,7 @@
 """The engine step's host spans, as a profiler trace shows them.
 
 `inference/serving.py` opens `jax.profiler.TraceAnnotation` spans around
-each part of a paged engine step (its module docstring lists them); the
+each part of an engine step (its module docstring lists them); the
 benchmark's per-layer metrics give every gap in the device's timeline to
 the innermost span open over it (PERF.md section 3). That attribution
 holds only while the spans form a tree: each inside its parent, no two
@@ -129,12 +129,14 @@ def _prompts(cfg, lens, seed=3):
     # a speculative window takes the plain chunk's children, and every
     # call is read before the next is launched
     dict(prefix_cache=True, prefill_chunk=4, spec_decode=True, spec_k=3),
-], ids=["chunked-prefill", "one-shot-prefill", "spec-decode"])
+    # no trie: every admission prefills its whole prompt
+    dict(),
+], ids=["chunked-prefill", "one-shot-prefill", "spec-decode", "no-trie"])
 def test_paged_step_span_tree(served_model, tmp_path, kw):
     m, cfg = served_model
     eng = ServingEngine(m, ServingConfig(
         max_batch=2, prompt_cap=CAP, max_new_tokens=NEW, decode_chunk=2,
-        paged=True, kv_block=4, kv_blocks=96, **kw))
+        kv_block=4, kv_blocks=96, **kw))
     prompts = _prompts(cfg, [CAP, CAP, 5, CAP, 3])
     eng.submit(prompts[0])
     eng.drain()                     # compile outside the traced steps
@@ -181,24 +183,6 @@ def test_paged_step_span_tree(served_model, tmp_path, kw):
         assert both == len(decodes)
 
 
-def test_padded_engine_steps_under_the_step_span(served_model, tmp_path):
-    """The padded engine keeps its two spans; both now lie in a step."""
-    m, cfg = served_model
-    eng = ServingEngine(m, ServingConfig(
-        max_batch=2, prompt_cap=CAP, max_new_tokens=NEW, decode_chunk=2))
-    prompts = _prompts(cfg, [CAP, 5])
-    eng.submit(prompts[0])
-    eng.drain()
-
-    def run():
-        for p in prompts:
-            eng.submit(p)
-        assert len(eng.drain()) == len(prompts)
-
-    _check_tree(_traced_spans(tmp_path, run),
-                {"serving/step", "serving/prefill", "serving/decode"})
-
-
 def test_request_n_produced_counts_delivered_tokens(served_model):
     """`Request.n_produced` is the public face of the engine's running
     count: 0 while queued and while its first launches are unread, rising
@@ -206,7 +190,7 @@ def test_request_n_produced_counts_delivered_tokens(served_model):
     m, cfg = served_model
     eng = ServingEngine(m, ServingConfig(
         max_batch=2, prompt_cap=CAP, max_new_tokens=NEW, decode_chunk=2,
-        paged=True, kv_block=4))
+        kv_block=4))
     req = eng.submit(_prompts(cfg, [5])[0])
     assert req.n_produced == 0
     seen = []

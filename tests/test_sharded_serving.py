@@ -42,7 +42,7 @@ def served_model():
 
 def _engine(m, shards, **kw):
     base = dict(max_batch=2, prompt_cap=CAP, max_new_tokens=NEW,
-                decode_chunk=2, paged=True, kv_block=4, shards=shards)
+                decode_chunk=2, kv_block=4, shards=shards)
     base.update(kw)
     return ServingEngine(m, ServingConfig(**base))
 
@@ -273,13 +273,12 @@ def test_spill_payload_shard_consistent_round_trip(served_model):
 # ------------------------------------------------------- validation
 
 def test_shards_config_validation(served_model):
-    from paddle_tpu.analysis.findings import ConfigValidationError
     m, cfg = served_model
     with pytest.raises(ValueError, match="shards must be >= 1"):
-        ServingConfig(paged=True, shards=0)
-    with pytest.raises(ConfigValidationError) as ei:
-        ServingConfig(shards=2)
-    assert ei.value.finding.code == "sharded_requires_paged"
+        ServingConfig(shards=0)
+    assert ServingConfig(shards=2).shards == 2     # the one engine shards
+    with pytest.raises(ValueError, match="padded engine was removed"):
+        ServingConfig(shards=2, paged=False)
     # head divisibility is an ENGINE check (needs the model)
     with pytest.raises(ValueError, match="num_heads"):
         _engine(m, 3)

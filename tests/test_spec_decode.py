@@ -318,7 +318,7 @@ def test_spec_engine_bit_identical_and_zero_misses(served_model):
     m, cfg = served_model
     eng = ServingEngine(m, ServingConfig(
         max_batch=2, prompt_cap=CAP, max_new_tokens=NEW, decode_chunk=2,
-        paged=True, kv_block=4, kv_blocks=96, prefix_cache=True,
+        kv_block=4, kv_blocks=96, prefix_cache=True,
         spec_decode=True, spec_k=3))
     eng.warmup_prefix_cache(cfg.vocab_size, clear=False)
 
@@ -363,7 +363,7 @@ def test_spec_engine_parity_with_eos(served_model):
     eos = 11
     eng = ServingEngine(m, ServingConfig(
         max_batch=2, prompt_cap=CAP, max_new_tokens=NEW, decode_chunk=2,
-        paged=True, kv_block=4, kv_blocks=96, prefix_cache=True,
+        kv_block=4, kv_blocks=96, prefix_cache=True,
         spec_decode=True, spec_k=3, eos_token_id=eos))
     eng.warmup_prefix_cache(cfg.vocab_size, clear=False)
     lens = [CAP, CAP, 6, 2]
@@ -399,7 +399,7 @@ def test_spec_engine_model_draft_and_source_split(served_model):
     new = 5
     eng = ServingEngine(m, ServingConfig(
         max_batch=2, prompt_cap=CAP, max_new_tokens=new, decode_chunk=2,
-        paged=True, kv_block=4, spec_decode=True, spec_k=3,
+        kv_block=4, spec_decode=True, spec_k=3,
         spec_draft=model_draft_fn(m, window=16)))
     lens = [CAP, 5, 3]
     rng = np.random.RandomState(2)
@@ -433,7 +433,7 @@ def test_spec_request_jsonl_row_carries_acceptance(served_model, tmp_path):
     from paddle_tpu.inference import ServingMetrics
     eng = ServingEngine(m, ServingConfig(
         max_batch=2, prompt_cap=CAP, max_new_tokens=NEW, decode_chunk=2,
-        paged=True, kv_block=4, kv_blocks=96, prefix_cache=True,
+        kv_block=4, kv_blocks=96, prefix_cache=True,
         spec_decode=True, spec_k=3),
         metrics=ServingMetrics(jsonl_path=path))
     prompt = np.random.RandomState(4).randint(
@@ -451,28 +451,31 @@ def test_spec_request_jsonl_row_carries_acceptance(served_model, tmp_path):
 
 
 def test_spec_config_validation():
-    with pytest.raises(ValueError, match="requires paged"):
-        ServingConfig(spec_decode=True)
+    with pytest.raises(ValueError, match="padded engine was removed"):
+        ServingConfig(prefix_cache=True, spec_decode=True, paged=False)
     with pytest.raises(ValueError, match="prefix_cache"):
-        ServingConfig(paged=True, spec_decode=True, spec_draft="trie")
+        ServingConfig(spec_decode=True)            # the default trie draft
+    with pytest.raises(ValueError, match="prefix_cache"):
+        ServingConfig(spec_decode=True, spec_draft="trie")
     with pytest.raises(ValueError, match="greedy"):
-        ServingConfig(paged=True, prefix_cache=True, spec_decode=True,
+        ServingConfig(prefix_cache=True, spec_decode=True,
                       temperature=0.7)
     with pytest.raises(ValueError, match="spec_k"):
-        ServingConfig(paged=True, prefix_cache=True, spec_decode=True,
+        ServingConfig(prefix_cache=True, spec_decode=True,
                       spec_k=0)
     with pytest.raises(ValueError, match="spec_k"):
         # cap keeps the accept-length histogram's exact integer buckets
-        ServingConfig(paged=True, prefix_cache=True, spec_decode=True,
+        ServingConfig(prefix_cache=True, spec_decode=True,
                       spec_k=32)
     with pytest.raises(ValueError, match="callable"):
-        ServingConfig(paged=True, spec_decode=True, spec_draft="ngram")
+        ServingConfig(spec_decode=True, spec_draft="ngram")
     with pytest.raises(ValueError, match="prefill_chunk"):
-        ServingConfig(paged=True, prompt_cap=8, prefill_chunk=9)
-    with pytest.raises(ValueError, match="requires paged"):
-        ServingConfig(prefill_chunk=4)
+        ServingConfig(prompt_cap=8, prefill_chunk=9)
+    with pytest.raises(ValueError, match="padded engine was removed"):
+        ServingConfig(prefill_chunk=4, paged=False)
+    assert ServingConfig(prefill_chunk=4).prefill_chunk == 4
     # a callable drafter needs no prefix cache
-    ServingConfig(paged=True, spec_decode=True, spec_draft=lambda c, k: [])
+    ServingConfig(spec_decode=True, spec_draft=lambda c, k: [])
 
 
 def test_spec_int8_paged_parity(served_model):
@@ -481,7 +484,7 @@ def test_spec_int8_paged_parity(served_model):
     m, cfg = served_model
     eng = ServingEngine(m, ServingConfig(
         max_batch=2, prompt_cap=CAP, max_new_tokens=NEW, decode_chunk=2,
-        paged=True, kv_block=4, kv_blocks=96, prefix_cache=True,
+        kv_block=4, kv_blocks=96, prefix_cache=True,
         cache_dtype="int8", spec_decode=True, spec_k=3))
     eng.warmup_prefix_cache(cfg.vocab_size, clear=False)
     lens = [CAP, 5]
@@ -517,7 +520,7 @@ def test_chunked_prefill_parity_and_one_executable(served_model, pc):
     ref = _ref_chains(m, ids, lens)
     eng = ServingEngine(m, ServingConfig(
         max_batch=2, prompt_cap=CAP, max_new_tokens=NEW, decode_chunk=2,
-        paged=True, kv_block=4, prefill_chunk=pc))
+        kv_block=4, prefill_chunk=pc))
     eng.submit(ids[0, :lens[0]])
     eng.drain()                                  # warm
     miss0 = compile_cache_misses()
@@ -536,7 +539,7 @@ def test_chunked_prefill_interleaves_decode(served_model):
     m, cfg = served_model
     eng = ServingEngine(m, ServingConfig(
         max_batch=2, prompt_cap=CAP, max_new_tokens=NEW, decode_chunk=1,
-        paged=True, kv_block=4, prefill_chunk=2))
+        kv_block=4, prefill_chunk=2))
     rng = np.random.RandomState(8)
     a = rng.randint(1, cfg.vocab_size, (3,)).astype(np.int64)
     b = rng.randint(1, cfg.vocab_size, (CAP,)).astype(np.int64)
@@ -567,7 +570,7 @@ def test_chunked_prefill_composes_with_prefix_cache_and_spec(served_model):
     m, cfg = served_model
     eng = ServingEngine(m, ServingConfig(
         max_batch=2, prompt_cap=CAP, max_new_tokens=NEW, decode_chunk=2,
-        paged=True, kv_block=4, kv_blocks=96, prefix_cache=True,
+        kv_block=4, kv_blocks=96, prefix_cache=True,
         spec_decode=True, spec_k=3, prefill_chunk=4))
     eng.warmup_prefix_cache(cfg.vocab_size, clear=False)
     lens = [CAP, CAP, 5]
@@ -621,7 +624,7 @@ def test_spec_throughput_exceeds_plain_on_repeat_traffic(served_model):
     def run(spec):
         eng = ServingEngine(m, ServingConfig(
             max_batch=2, prompt_cap=CAP, max_new_tokens=NEW,
-            decode_chunk=1, paged=True, kv_block=4, kv_blocks=96,
+            decode_chunk=1, kv_block=4, kv_blocks=96,
             prefix_cache=True, spec_decode=spec, spec_k=3))
         eng.warmup_prefix_cache(cfg.vocab_size)
         eng.metrics = type(eng.metrics)()

@@ -78,7 +78,7 @@ def main(argv=None) -> int:
         # constant LRU eviction -> the spill tier cycles for real
         return ServingEngine(model, ServingConfig(
             max_batch=2, prompt_cap=16, max_new_tokens=6, decode_chunk=3,
-            paged=True, prefix_cache=True, kv_block=KB, kv_blocks=48,
+            prefix_cache=True, kv_block=KB, kv_blocks=48,
             prefix_cache_bytes=3 * BPB if spill else None,
             spill_host_bytes=1 << 22 if spill else None))
 

@@ -4,12 +4,11 @@ suite (paddle_tpu.analysis) and print a findings table.
 
 Targets (--all = every one):
 
-  gpt-static   the padded serving engine's {prefill_static, decode_static}
-               executables, captured from a real warmup batch (bf16 model:
-               the serving dtype story the dtype-promotion pass audits)
-  gpt-paged    the paged engine's {prefill_paged, decode_paged} pair —
-               donated block pools cross-checked against the lowered
-               modules' input_output_alias tables
+  gpt-paged    the serving engine's {prefill_paged, decode_paged} pair,
+               captured from a real warmup batch (bf16 model: the serving
+               dtype story the dtype-promotion pass audits) — donated
+               block pools cross-checked against the lowered modules'
+               input_output_alias tables
   gpt-paged-int8  the int8 paged engine WITH the prefix cache: the int8
                {prefill, decode} pair plus the suffix-prefill and COW
                executables (warmup traffic repeats + diverges a prompt
@@ -91,7 +90,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-TARGETS = ("gpt-static", "gpt-paged", "gpt-paged-int8", "gpt-paged-spec",
+TARGETS = ("gpt-paged", "gpt-paged-int8", "gpt-paged-spec",
            "train-step", "resnet50",
            "train-step-dp", "train-step-tp", "train-step-int8",
            "comm-xcheck", "gpt-paged-sharded")
@@ -116,7 +115,7 @@ def _tiny_gpt(dtype="bfloat16"):
     return model, cfg
 
 
-def audit_gpt_engine(lint, *, paged: bool, int8: bool = False,
+def audit_gpt_engine(lint, *, int8: bool = False,
                      prefix: bool = False, spec: bool = False):
     """Serve one warmup batch through the real engine with lint enabled;
     the engine captures + audits its executables itself. With `prefix`
@@ -132,7 +131,7 @@ def audit_gpt_engine(lint, *, paged: bool, int8: bool = False,
     from paddle_tpu.inference import ServingConfig, ServingEngine
     model, _ = _tiny_gpt()
     cfg = ServingConfig(max_batch=2, prompt_cap=8, max_new_tokens=6,
-                        decode_chunk=2, eos_token_id=None, paged=paged,
+                        decode_chunk=2, eos_token_id=None,
                         kv_block=4, lint=lint,
                         cache_dtype="int8" if int8 else None,
                         prefix_cache=prefix,
@@ -199,7 +198,7 @@ def audit_gpt_engine_sharded(lint, shards: int = 4, audits=None):
     from paddle_tpu.jit.api import compile_cache_misses
     model, mcfg = _tiny_gpt()
     cfg = ServingConfig(max_batch=2, prompt_cap=8, max_new_tokens=6,
-                        decode_chunk=2, eos_token_id=None, paged=True,
+                        decode_chunk=2, eos_token_id=None,
                         kv_block=4, shards=shards)
     eng = ServingEngine(model, cfg)
     rng = np.random.RandomState(0)
@@ -589,12 +588,11 @@ def main(argv=None) -> int:
     # dp trains on grad-sync all-reduces ALONE; the hybrid tp mesh adds
     # the TP activation all-gathers. Anything else = partitioner crept.
     runners = {
-        "gpt-static": lambda: audit_gpt_engine(lint, paged=False),
-        "gpt-paged": lambda: audit_gpt_engine(lint, paged=True),
-        "gpt-paged-int8": lambda: audit_gpt_engine(lint, paged=True,
-                                                   int8=True, prefix=True),
-        "gpt-paged-spec": lambda: audit_gpt_engine(lint, paged=True,
-                                                   prefix=True, spec=True),
+        "gpt-paged": lambda: audit_gpt_engine(lint),
+        "gpt-paged-int8": lambda: audit_gpt_engine(lint, int8=True,
+                                                   prefix=True),
+        "gpt-paged-spec": lambda: audit_gpt_engine(lint, prefix=True,
+                                                   spec=True),
         "train-step": lambda: audit_train_step(lint),
         "resnet50": lambda: audit_resnet50(lint,
                                            train=args.vision_train),

@@ -2,7 +2,7 @@
 
 Timing traps handled: per-step input varies via a runtime scale vector (no
 loop-invariant hoisting), and outputs are consumed via sum-of-squares (no
-slice-narrowing through the matmuls). bench.py protocol otherwise: one
+slice-narrowing through the matmuls). Otherwise: one
 fused scan launch, host-read fence, best of 3.
 """
 import os, sys, time

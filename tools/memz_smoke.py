@@ -113,7 +113,7 @@ def main(argv=None) -> int:
     model.eval()
     engine = ServingEngine(model, ServingConfig(
         max_batch=2, prompt_cap=12, max_new_tokens=8, decode_chunk=4,
-        paged=True, kv_block=4, kv_blocks=24, prefix_cache=True))
+        kv_block=4, kv_blocks=24, prefix_cache=True))
     # explicit capacity so the headroom gauge renders on the CPU host
     # (no allocator bytes_limit); generous enough to stay quiet
     ledger = engine.attach_memory_ledger(
@@ -247,7 +247,7 @@ def main(argv=None) -> int:
     # paired mem_pressure episode rows
     tiny = ServingEngine(model, ServingConfig(
         max_batch=2, prompt_cap=12, max_new_tokens=8, decode_chunk=4,
-        paged=True, kv_block=4, kv_blocks=6))
+        kv_block=4, kv_blocks=6))
     tiny.attach_memory_ledger()
     rows = []
     tiny.metrics.on_record = rows.append

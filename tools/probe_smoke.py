@@ -89,7 +89,7 @@ def main(argv=None) -> int:
         # churn would let the cache self-heal before detection
         return ServingEngine(model, ServingConfig(
             max_batch=2, prompt_cap=16, max_new_tokens=6, decode_chunk=3,
-            paged=True, prefix_cache=True, kv_block=KB, kv_blocks=48,
+            prefix_cache=True, kv_block=KB, kv_blocks=48,
             prefix_cache_bytes=64 * BPB, spill_host_bytes=1 << 22))
 
     traffic = shared_prefix_traffic(

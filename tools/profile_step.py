@@ -1,7 +1,7 @@
 """Trace one training-step executable on TPU and print device-time tables.
 
 Thin CLI over `paddle_tpu.profiler.trace_analysis` (where the
-.trace.json.gz parser now lives): mirrors bench.py's model configs
+.trace.json.gz parser now lives): builds one of the model configs
 (vit / bert / gpt / swin / resnet50), runs a few steps under
 jax.profiler.trace, then prints the KernelView / DistributedView tables —
 the only trustworthy per-component timing on remote-dispatch runtimes

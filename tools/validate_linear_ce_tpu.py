@@ -7,7 +7,7 @@ Checks (flagship shape T=6144 H=2048 V=50304 bf16):
   1. forward loss parity Pallas vs legacy chunked-XLA path
   2. dx/dW parity (bf16 tolerances)
   3. fwd+bwd wall time of both paths via a fused multi-step scan with a
-     host-read fence (bench.py protocol — per memory, naive timing lies)
+     host-read fence (naive timing lies)
 """
 import os
 import sys

@@ -182,7 +182,7 @@ def spec_engine_parity():
     # least one pool block past the prompt
     eng = ServingEngine(m, ServingConfig(
         max_batch=2, prompt_cap=CAP, max_new_tokens=NEW, decode_chunk=4,
-        paged=True, kv_block=8, kv_blocks=256, prefix_cache=True,
+        kv_block=8, kv_blocks=256, prefix_cache=True,
         spec_decode=True, spec_k=4))
     eng.warmup_prefix_cache(cfg.vocab_size, clear=False)
     traffic = repeated_traffic(8, n_prompts=2, prompt_len=CAP,
@@ -239,7 +239,7 @@ def engine_parity():
                                    max_new_tokens=NEW).numpy()[:, CAP:]
     eng = ServingEngine(m, ServingConfig(max_batch=2, prompt_cap=CAP,
                                          max_new_tokens=NEW,
-                                         decode_chunk=4, paged=True,
+                                         decode_chunk=4,
                                          kv_block=16))
     eng.submit(ids[0, :lens[0]])
     eng.drain()
@@ -289,7 +289,7 @@ def sharded_engine_parity(shards):
     def serve(s):
         eng = ServingEngine(m, ServingConfig(
             max_batch=2, prompt_cap=CAP, max_new_tokens=NEW,
-            decode_chunk=4, paged=True, kv_block=16, shards=s))
+            decode_chunk=4, kv_block=16, shards=s))
         for i, ln in enumerate(lens):
             eng.submit(ids[i, :ln])
         eng.drain()
