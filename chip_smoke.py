@@ -42,6 +42,8 @@ SERVE = dict(max_batch=8, prompt_cap=128, max_new_tokens=32, kv_block=16,
 PROMPT_LENS = (128, 17, 3, 64, 100, 1, 33, 128)
 KERNEL_SHAPES = dict(nh=16, hd=128, hidden=2048, vocab=50304,
                      flash=(3, 2048), ce_tokens=6144,
+                     # GPT-2.7B's heads: 80 wide, padded to the 128 lanes
+                     flash_padded=(32, 80),
                      pool_blocks=1024, kv_block=16, table_slots=64,
                      serve_batch=8, prefix_s=(128, 4),
                      # latent decode at the expert model's serving cell:
@@ -209,10 +211,9 @@ def kernels_phase(shapes: dict, seed: int, *, interpret: bool = False,
         if not ok:
             failures.append(name)
 
-    # flash attention, forward and gradients
+    # flash attention, forward and gradients; the gradients again at heads
+    # the kernel pads to the lanes
     b, s = shapes["flash"]
-    qkv = [rnd(b, s, nh, hd) for _ in range(3)]
-    cot = rnd(b, s, nh, hd)
 
     def flash_fwd(q, k, v):
         return fa.flash_attention(q, k, v, causal=True, interpret=interpret)
@@ -226,10 +227,23 @@ def kernels_phase(shapes: dict, seed: int, *, interpret: bool = False,
                            * cot.astype(jnp.float32))
         return jax.grad(loss, argnums=(0, 1, 2))
 
+    bq, bk = min(fa.DEFAULT_BQ, s), min(fa.DEFAULT_BK, s)
+    done, needed = fa.causal_work(s, s, bq, bk)
+    say("kernels", f"flash causal S={s} in blocks of {bq} x {bk}, crossed "
+        f"blocks in strips of {fa.sub_tile(bq, bk)} q rows: {done} score "
+        f"pairs multiplied a head for {needed} under the diagonal "
+        f"({done / needed:.3f} x)")
+    qkv = [rnd(b, s, nh, hd) for _ in range(3)]
+    cot = rnd(b, s, nh, hd)
     case(f"flash fwd q/k/v [{b},{s},{nh},{hd}] causal", flash_fwd, ref_fwd,
          qkv)
     case(f"flash grads [{b},{s},{nh},{hd}] causal", grads_of(flash_fwd),
          grads_of(ref_fwd), qkv + [cot])
+    nh_p, hd_p = shapes["flash_padded"]
+    qkv = [rnd(b, s, nh_p, hd_p) for _ in range(3)]
+    cot = rnd(b, s, nh_p, hd_p)
+    case(f"flash grads [{b},{s},{nh_p},{hd_p}] causal, padded lanes",
+         grads_of(flash_fwd), grads_of(ref_fwd), qkv + [cot])
     del qkv, cot
 
     # linear cross-entropy against the unfused head: logits in f32, then

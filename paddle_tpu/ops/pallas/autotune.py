@@ -27,7 +27,10 @@ loses 6 MFU points inside the full training step (smaller K/V tiles
 re-read HBM; the bandwidth they steal is invisible when the kernel runs
 alone). `tune_in_step` closes this trap: it times candidates inside a
 caller-supplied FULL step. The isolated `tune_flash_blocks` remains
-for quick exploration.
+for quick exploration. What block tuning could not reach, the arithmetic
+a large causal block spends above the diagonal, went with the sub-tile
+plan of flash_attention.py (PERF.md section 6, PR 32: the in-step readings
+its sub-tile side was chosen from).
 """
 from __future__ import annotations
 

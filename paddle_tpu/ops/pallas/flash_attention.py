@@ -16,6 +16,15 @@ Backward follows FlashAttention-2: forward stores per-row logsumexp L
 (replicated over 8 sublanes — TPU blocks tile (8,128)); backward recomputes
 P = exp(QKᵀ·scale − L) tile by tile with Δ = rowsum(dO ⊙ O) precomputed.
 
+Causal calls work under the diagonal only ("causal tile plan" below). The
+HBM blocks stay large (1024 x 1024 at S = 2048: smaller ones re-read K and
+V and lose in the step what they win alone, autotune.py), and a block the
+diagonal crosses is worked inside VMEM in strips of q rows, each against
+the k columns up to its own end, with only the square tile at that end
+masked; a block wholly under the diagonal runs with no mask, and a step
+above it, which is skipped, names a block already resident and starts no
+DMA. `causal_work` counts what a plan multiplies.
+
 Layout: [batch, seq, heads, head_dim] in, same out (paddle convention).
 head_dim pads to the 128-lane boundary in the wrapper (zero pads change no
 dot product), so 64-dim heads work. Matmuls run on bf16 inputs with f32
@@ -59,6 +68,119 @@ def _kv_mask(s, ki, bk, kv_len):
     return jnp.where(k_idx < kv_len, s, jnp.asarray(_NEG, s.dtype))
 
 
+# --------------------------------------------------------- causal tile plan
+# A causal block is classed from its grid position alone. One wholly above
+# the diagonal is skipped, one wholly under it is FULL and runs with no
+# mask, and one the diagonal CROSSES is worked in strips of `sub_tile` q
+# rows: strip i meets the k columns up to its own end only, and only its
+# last, square tile is masked. HBM blocks and grid are the same for every
+# plan; what shrinks is the arithmetic inside a crossed block, from the
+# whole block to (n + 1) / 2n of it at n strips. All three kernels take
+# the strips by q rows: their products then stream long operands (dkv's
+# `p.T @ do` runs over all the strip's columns), and the forward makes one
+# softmax update a row. Measured a head on the v5e at S = 2048 (PERF.md
+# section 6, PR 32): by k columns, or in square tiles, the same work is
+# slower than the whole block.
+
+def _block_class(qi, ki, bq, bk):
+    """(needed, full) of causal block (qi, ki); needed and not full is
+    crossed. Python ints or traced program ids."""
+    needed = ki * bk <= (qi + 1) * bq - 1
+    full = ki * bk + bk - 1 <= qi * bq
+    return needed, full
+
+
+def sub_tile(bq, bk, kv_len=None):
+    """Rows of a strip of a crossed block: a quarter of the block, lane
+    aligned. None where the block is worked whole under its mask: blocks
+    that are not square (the diagonal then enters a tile anywhere), too
+    small for two lane-aligned strips, or cut by a `kv_len` mask as well."""
+    if bq != bk or kv_len is not None:
+        return None
+    t = max(128, bq // 4)
+    return t if t % 128 == 0 and bq % t == 0 and bq > t else None
+
+
+def _crossed_strips(b, t):
+    """Strips of a b x b block on the diagonal, as (first row, rows,
+    columns): q rows [r0, r0 + rn) against k columns [0, cn), of which the
+    last rn are the tile on the diagonal."""
+    return [(r0, t, r0 + t) for r0 in range(0, b, t)]
+
+
+def causal_work(s_q, s_k, bq, bk, causal=True, kv_len=None):
+    """(score pairs the kernels multiply, score pairs the attention needs)
+    for one head under the plan the kernels take at these blocks: 1.5 x at
+    S = 2048 in blocks of 1024 worked whole, 1.125 x in strips of 256. The
+    same for the forward, dq and dkv."""
+    cols = s_k if kv_len is None else min(kv_len, s_k)
+    if not causal:
+        return s_q * s_k, s_q * cols
+    t = sub_tile(bq, bk, kv_len)
+    crossed = bq * bk if t is None else sum(
+        rn * cn for _, rn, cn in _crossed_strips(bq, t))
+    done = 0
+    for qi in range(s_q // bq):
+        for ki in range(s_k // bk):
+            needed, full = _block_class(qi, ki, bq, bk)
+            done += (bq * bk if full else crossed) if needed else 0
+    return done, sum(min(r + 1, cols) for r in range(s_q))
+
+
+def _diag_tail_mask(s):
+    """Causal mask of a strip [rn, cn] whose last rn columns are the square
+    tile on the diagonal; the columns before it lie under it."""
+    rn, cn = s.shape
+    tail = _causal_mask(s[:, cn - rn:], 0, 0, rn, rn)
+    return tail if cn == rn else jnp.concatenate([s[:, :cn - rn], tail], 1)
+
+
+def _block_steps(causal, qi, ki, bq, bk, kv_len):
+    """What a kernel does at grid step (qi, ki), as [(when, strips, mask)]:
+    under the decorator `when` it works each strip (the form of
+    `_crossed_strips`) and puts its scores through `mask`. A non-causal
+    block and a full one are one strip with no causal mask; a crossed one
+    takes its strips, or the whole block under `_causal_mask` where
+    `sub_tile` gives none."""
+    whole = [(0, bq, bk)]
+
+    def kv(s):
+        return s if kv_len is None else _kv_mask(s, ki, bk, kv_len)
+
+    if not causal:
+        return [(lambda body: body(), whole, kv)]
+    needed, full = _block_class(qi, ki, bq, bk)
+    crossed = jnp.logical_and(needed, jnp.logical_not(full))
+    t = sub_tile(bq, bk, kv_len)
+    if t is None:
+        return [(pl.when(full), whole, kv),
+                (pl.when(crossed), whole,
+                 lambda s: kv(_causal_mask(s, qi, ki, bq, bk)))]
+    return [(pl.when(full), whole, kv),
+            (pl.when(crossed), _crossed_strips(bq, t), _diag_tail_mask)]
+
+
+def _k_block(causal, bq, bk):
+    """Block of k / v at step (qi, ki) of the forward's and dq's grids. A
+    causal step above the diagonal is skipped: it names the row's last
+    needed block, which is resident, so it starts no DMA (at two blocks a
+    side k and v are fetched twice a head, not four times)."""
+    if not causal:
+        return lambda qi, ki: ki
+    # lax.div on int32: `//` promotes to i64 under x64, which Mosaic refuses
+    return lambda qi, ki: jnp.minimum(
+        ki, lax.div((qi + 1) * bq - 1, jnp.int32(bk)))
+
+
+def _q_block(causal, bq, bk, n_qb):
+    """Block of q / dO / lse / delta at step (ki, qi) of dkv's grid: the
+    skipped steps come first and name the column's first needed block."""
+    if not causal:
+        return lambda ki, qi: qi
+    return lambda ki, qi: jnp.minimum(
+        jnp.maximum(qi, lax.div(ki * bk, jnp.int32(bq))), n_qb - 1)
+
+
 # ------------------------------------------------------------------ forward
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc,
                 *, scale, causal, n_kb, kv_len=None):
@@ -73,26 +195,24 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc,
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
     # causal: blocks fully above the diagonal contribute nothing
-    needed = True if not causal else (ki * bk <= (qi + 1) * bq - 1)
-
-    @pl.when(needed)
-    def _step():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = _causal_mask(s, qi, ki, bq, bk)
-        if kv_len is not None:
-            s = _kv_mask(s, ki, bk, kv_len)
-        m_prev, l_prev = m_sc[...], l_sc[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        m_sc[...] = m_new
-        l_sc[...] = corr * l_prev + p.sum(axis=-1, keepdims=True)
-        acc_sc[...] = corr * acc_sc[...] + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    for when, strips, mask in _block_steps(causal, qi, ki, bq, bk, kv_len):
+        @when
+        def _step():
+            for r0, rn, cn in strips:
+                rows, cols = pl.ds(r0, rn), pl.ds(0, cn)
+                q = q_ref[0, rows, :]
+                k = k_ref[0, cols, :]
+                v = v_ref[0, cols, :]
+                s = mask(jnp.dot(q, k.T, preferred_element_type=jnp.float32)
+                         * scale)
+                m_prev, l_prev = m_sc[rows, :], l_sc[rows, :]
+                m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                corr = jnp.exp(m_prev - m_new)
+                m_sc[rows, :] = m_new
+                l_sc[rows, :] = corr * l_prev + p.sum(axis=-1, keepdims=True)
+                acc_sc[rows, :] = corr * acc_sc[rows, :] + jnp.dot(
+                    p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
     @pl.when(ki == n_kb - 1)
     def _finish():
@@ -105,6 +225,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc,
 FWD_NAME = "pallas_flash_fwd"
 
 
+# Traced once per signature and inlined at every call: a kernel body with
+# its strips unrolled takes ~0.1 s to trace, which a 24-layer step would
+# otherwise pay 24 times in its set-up. Inlined, the caller's jaxpr is the
+# one the plain call gives.
+_launcher = functools.partial(
+    jax.jit, inline=True,
+    static_argnames=("scale", "causal", "bq", "bk", "interpret", "kv_len"))
+
+
+@_launcher
 def _flash_fwd(q, k, v, *, scale, causal, bq, bk, interpret, kv_len=None):
     b, s_q, h, d = q.shape
     s_k = k.shape[1]
@@ -112,6 +242,7 @@ def _flash_fwd(q, k, v, *, scale, causal, bq, bk, interpret, kv_len=None):
     kt = jnp.moveaxis(k, 2, 1).reshape(b * h, s_k, d)
     vt = jnp.moveaxis(v, 2, 1).reshape(b * h, s_k, d)
     n_kb = s_k // bk
+    kb = _k_block(causal, bq, bk)
 
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal, n_kb=n_kb,
@@ -121,8 +252,8 @@ def _flash_fwd(q, k, v, *, scale, causal, bq, bk, interpret, kv_len=None):
         grid=(b * h, s_q // bq, n_kb),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, _i0())),
-            pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh, ki, _i0())),
-            pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh, ki, _i0())),
+            pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh, kb(qi, ki), _i0())),
+            pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh, kb(qi, ki), _i0())),
         ],
         out_specs=(pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, _i0())),
                    pl.BlockSpec((1, 8, bq), lambda bh, qi, ki: (bh, _i0(), qi))),
@@ -136,6 +267,24 @@ def _flash_fwd(q, k, v, *, scale, causal, bq, bk, interpret, kv_len=None):
 
 
 # ----------------------------------------------------------------- backward
+def _strip_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, strip,
+                scale, mask):
+    """One strip's operands and P = exp(QK^T scale - L), dS = P (dO V^T -
+    delta), as (q, k, do, p, ds)."""
+    r0, rn, cn = strip
+    rows, cols = pl.ds(r0, rn), pl.ds(0, cn)
+    q = q_ref[0, rows, :]
+    k = k_ref[0, cols, :]
+    v = v_ref[0, cols, :]
+    do = do_ref[0, rows, :]
+    lse = lse_ref[0, 0, rows][:, None]
+    delta = delta_ref[0, 0, rows][:, None]
+    s = mask(jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale)
+    p = jnp.exp(s - lse)
+    dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
+    return q, k, do, p, p * (dp - delta)
+
+
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    dq_sc, *, scale, causal, n_kb, kv_len=None):
     qi, ki = pl.program_id(1), pl.program_id(2)
@@ -146,25 +295,15 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         dq_sc[...] = jnp.zeros_like(dq_sc)
 
-    needed = True if not causal else (ki * bk <= (qi + 1) * bq - 1)
-
-    @pl.when(needed)
-    def _step():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0, 0][:, None]
-        delta = delta_ref[0, 0][:, None]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = _causal_mask(s, qi, ki, bq, bk)
-        if kv_len is not None:
-            s = _kv_mask(s, ki, bk, kv_len)
-        p = jnp.exp(s - lse)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta)).astype(k.dtype)
-        dq_sc[...] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
+    for when, strips, mask in _block_steps(causal, qi, ki, bq, bk, kv_len):
+        @when
+        def _step():
+            for strip in strips:
+                _, k, _, _, ds = _strip_p_ds(
+                    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, strip,
+                    scale, mask)
+                dq_sc[pl.ds(*strip[:2]), :] += jnp.dot(
+                    ds.astype(k.dtype), k, preferred_element_type=jnp.float32)
 
     @pl.when(ki == n_kb - 1)
     def _finish():
@@ -183,27 +322,18 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_sc[...] = jnp.zeros_like(dk_sc)
         dv_sc[...] = jnp.zeros_like(dv_sc)
 
-    needed = True if not causal else ((qi + 1) * bq - 1 >= ki * bk)
-
-    @pl.when(needed)
-    def _step():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0, 0][:, None]
-        delta = delta_ref[0, 0][:, None]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = _causal_mask(s, qi, ki, bq, bk)
-        if kv_len is not None:
-            s = _kv_mask(s, ki, bk, kv_len)
-        p = jnp.exp(s - lse)
-        pt = p.astype(do.dtype)
-        dv_sc[...] += jnp.dot(pt.T, do, preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta)).astype(q.dtype)
-        dk_sc[...] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
+    for when, strips, mask in _block_steps(causal, qi, ki, bq, bk, kv_len):
+        @when
+        def _step():
+            for strip in strips:
+                q, _, do, p, ds = _strip_p_ds(
+                    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, strip,
+                    scale, mask)
+                cols = pl.ds(0, strip[2])
+                dv_sc[cols, :] += jnp.dot(p.astype(do.dtype).T, do,
+                                          preferred_element_type=jnp.float32)
+                dk_sc[cols, :] += jnp.dot(ds.astype(q.dtype).T, q,
+                                          preferred_element_type=jnp.float32)
 
     @pl.when(qi == n_qb - 1)
     def _finish():
@@ -215,6 +345,7 @@ DQ_NAME = "pallas_flash_dq"
 DKV_NAME = "pallas_flash_dkv"
 
 
+@_launcher
 def _flash_bwd(res, g, *, scale, causal, bq, bk, interpret, kv_len=None):
     qt, kt, vt, out, lse = res
     bh, s_q, d = qt.shape
@@ -224,6 +355,8 @@ def _flash_bwd(res, g, *, scale, causal, bq, bk, interpret, kv_len=None):
     delta = jnp.broadcast_to(delta[:, None, :], (bh, 8, s_q))
     n_kb = s_k // bk
     n_qb = s_q // bq
+    kb = _k_block(causal, bq, bk)
+    qb = _q_block(causal, bq, bk, n_qb)
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
@@ -232,8 +365,8 @@ def _flash_bwd(res, g, *, scale, causal, bq, bk, interpret, kv_len=None):
         grid=(bh, n_qb, n_kb),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, qi, ki: (b, qi, _i0())),
-            pl.BlockSpec((1, bk, d), lambda b, qi, ki: (b, ki, _i0())),
-            pl.BlockSpec((1, bk, d), lambda b, qi, ki: (b, ki, _i0())),
+            pl.BlockSpec((1, bk, d), lambda b, qi, ki: (b, kb(qi, ki), _i0())),
+            pl.BlockSpec((1, bk, d), lambda b, qi, ki: (b, kb(qi, ki), _i0())),
             pl.BlockSpec((1, bq, d), lambda b, qi, ki: (b, qi, _i0())),
             pl.BlockSpec((1, 8, bq), lambda b, qi, ki: (b, _i0(), qi)),
             pl.BlockSpec((1, 8, bq), lambda b, qi, ki: (b, _i0(), qi)),
@@ -251,12 +384,12 @@ def _flash_bwd(res, g, *, scale, causal, bq, bk, interpret, kv_len=None):
                    jax.ShapeDtypeStruct((bh, s_k, d), vt.dtype)),
         grid=(bh, n_kb, n_qb),
         in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, ki, qi: (b, qi, _i0())),
+            pl.BlockSpec((1, bq, d), lambda b, ki, qi: (b, qb(ki, qi), _i0())),
             pl.BlockSpec((1, bk, d), lambda b, ki, qi: (b, ki, _i0())),
             pl.BlockSpec((1, bk, d), lambda b, ki, qi: (b, ki, _i0())),
-            pl.BlockSpec((1, bq, d), lambda b, ki, qi: (b, qi, _i0())),
-            pl.BlockSpec((1, 8, bq), lambda b, ki, qi: (b, _i0(), qi)),
-            pl.BlockSpec((1, 8, bq), lambda b, ki, qi: (b, _i0(), qi)),
+            pl.BlockSpec((1, bq, d), lambda b, ki, qi: (b, qb(ki, qi), _i0())),
+            pl.BlockSpec((1, 8, bq), lambda b, ki, qi: (b, _i0(), qb(ki, qi))),
+            pl.BlockSpec((1, 8, bq), lambda b, ki, qi: (b, _i0(), qb(ki, qi))),
         ],
         out_specs=(pl.BlockSpec((1, bk, d), lambda b, ki, qi: (b, ki, _i0())),
                    pl.BlockSpec((1, bk, d), lambda b, ki, qi: (b, ki, _i0()))),

@@ -19,7 +19,8 @@ from paddle_tpu.models import GPTConfig  # noqa: E402
 
 
 _TINY_KERNEL_SHAPES = dict(
-    nh=2, hd=64, hidden=128, vocab=1024, flash=(2, 128), ce_tokens=64,
+    nh=2, hd=64, hidden=128, vocab=1024, flash=(2, 128),
+    flash_padded=(2, 40), ce_tokens=64,
     pool_blocks=32, kv_block=8, table_slots=4, serve_batch=8,
     prefix_s=(8, 4), latent=(4, 24, 16, 8, 4, 32))
 

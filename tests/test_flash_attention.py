@@ -357,3 +357,158 @@ def test_flash_kv_len_nonpositive_rejected():
     with pytest.raises(ValueError):
         flash_attention_packed(qp, qp, qp, num_heads=1, kv_len=0,
                                interpret=True)
+
+
+# ------------------------------------------------- causal tile plan (PR 32)
+def _masked_reference(q, k, v, kv_len=None):
+    """Causal attention by the kernel's origin rule (query r sees key c
+    when r >= c, both counted from 0, whatever s_q and s_k), keys from
+    kv_len on masked, as an explicit mask over the plain reference."""
+    s_q, s_k = q.shape[1], k.shape[1]
+    r, c = np.arange(s_q)[:, None], np.arange(s_k)[None, :]
+    keep = (r >= c) & (c < (s_k if kv_len is None else kv_len))
+    return attention_reference(q, k, v, mask=jnp.asarray(keep)[None, None])
+
+
+def _assert_causal_parity(q, k, v, block_q, block_k, kv_len=None):
+    """Forward and q/k/v gradients of the causal kernel against the masked
+    reference, under a cotangent that weighs every output element."""
+    cot = jnp.asarray(np.random.RandomState(11).randn(*q.shape)
+                      .astype(np.float32))
+
+    def f_flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=block_q,
+                               block_k=block_k, interpret=True, kv_len=kv_len)
+
+    def f_ref(q, k, v):
+        return _masked_reference(q, k, v, kv_len)
+
+    np.testing.assert_allclose(np.asarray(f_flash(q, k, v)),
+                               np.asarray(f_ref(q, k, v)),
+                               rtol=2e-4, atol=2e-4)
+    g_flash = jax.grad(lambda *a: jnp.sum(f_flash(*a) * cot), (0, 1, 2))(
+        q, k, v)
+    g_ref = jax.grad(lambda *a: jnp.sum(f_ref(*a) * cot), (0, 1, 2))(q, k, v)
+    for gf, gr, name in zip(g_flash, g_ref, "qkv"):
+        np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
+                                   rtol=2e-3, atol=2e-3,
+                                   err_msg=f"d{name} mismatch")
+    return g_flash
+
+
+@pytest.mark.parametrize("d", [128, 80])
+@pytest.mark.parametrize("s,block", [(512, 256), (1024, 512)])
+def test_flash_causal_subtiled_matches_reference(s, block, d):
+    """Blocks the diagonal crosses are worked in strips of 128 rows (two a
+    block at 256, four at 512; two blocks a side); d = 80 is cell 3's
+    head, padded to the lanes. On these shapes the masked reference is the
+    plain causal one."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    assert fa.sub_tile(block, block) == 128
+    q, k, v = _rand(1, s, 2, d, seed=21)
+    _assert_causal_parity(q, k, v, block, block)
+    np.testing.assert_array_equal(
+        np.asarray(_masked_reference(q, k, v)),
+        np.asarray(attention_reference(q, k, v, is_causal=True)))
+
+
+@pytest.mark.parametrize("block_q,block_k", [(256, 128), (128, 256)])
+def test_flash_causal_unequal_blocks_keep_masked_form(block_q, block_k):
+    """bq != bk: the diagonal enters a block anywhere, so a crossed block
+    is worked whole under its mask, and the numbers are the same."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    assert fa.sub_tile(block_q, block_k) is None
+    q, k, v = _rand(1, 512, 2, 64, seed=22)
+    _assert_causal_parity(q, k, v, block_q, block_k)
+
+
+def test_flash_causal_with_kv_len_inside_last_block():
+    """causal and a kv_len that cuts the last block: both masks hold, and
+    the padded keys get exactly zero dk / dv."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    kv_len = 400
+    assert fa.sub_tile(256, 256, kv_len) is None
+    q, k, v = _rand(1, 512, 2, 64, seed=23)
+    _, dk, dv = _assert_causal_parity(q, k, v, 256, 256, kv_len=kv_len)
+    assert float(jnp.abs(dk[:, kv_len:]).max()) == 0.0
+    assert float(jnp.abs(dv[:, kv_len:]).max()) == 0.0
+
+
+@pytest.mark.parametrize("s_q,s_k", [(256, 512), (512, 256)])
+def test_flash_causal_unequal_lengths_keep_origin_rule(s_q, s_k):
+    """s_q != s_k means what it meant before the tile plan: rows and
+    columns both count from 0 (not the reference's bottom-right rule)."""
+    q, _, _ = _rand(1, s_q, 2, 64, seed=24)
+    _, k, v = _rand(1, s_k, 2, 64, seed=25)
+    _assert_causal_parity(q, k, v, 256, 256)
+
+
+def _plan_pairs(s_q, s_k, bq, bk):
+    """The (query, key) pairs the kernels multiply under the plan, as a
+    count per pair, and the pairs whose scores go through a causal mask."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    done = np.zeros((s_q, s_k), int)
+    masked = np.zeros((s_q, s_k), bool)
+    t = fa.sub_tile(bq, bk)
+    for qi in range(s_q // bq):
+        for ki in range(s_k // bk):
+            needed, full = fa._block_class(qi, ki, bq, bk)
+            if not needed:
+                continue
+            strips = [(0, bq, bk)] if full or t is None \
+                else fa._crossed_strips(bq, t)
+            for r0, rn, cn in strips:
+                rows = slice(qi * bq + r0, qi * bq + r0 + rn)
+                done[rows, ki * bk:ki * bk + cn] += 1
+                if not full:    # whole block, or the strip's square tail
+                    first = 0 if t is None else cn - rn
+                    masked[rows, ki * bk + first:ki * bk + cn] = True
+    return done, masked
+
+
+@pytest.mark.parametrize("s_q,s_k,bq,bk", [
+    (2048, 2048, 1024, 1024), (1024, 1024, 256, 256), (512, 512, 256, 128),
+    (512, 1024, 256, 256), (1024, 512, 512, 512), (768, 768, 384, 384)])
+def test_causal_plan_covers_the_diagonal_once(s_q, s_k, bq, bk):
+    """Every pair under the diagonal is multiplied exactly once, every pair
+    left unmasked lies under it, and `causal_work` counts this plan."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    done, masked = _plan_pairs(s_q, s_k, bq, bk)
+    under = np.arange(s_q)[:, None] >= np.arange(s_k)[None, :]
+    assert done.max() == 1
+    assert (done[under] == 1).all()
+    assert under[(done == 1) & ~masked].all()
+    assert fa.causal_work(s_q, s_k, bq, bk) == (done.sum(), under.sum())
+
+
+def test_causal_work_at_the_training_shape():
+    """S = 2048 at the default blocks: the kernels multiply at most 1.13
+    times the pairs under the diagonal (1.5 with whole crossed blocks,
+    which is what a kv_len inside the block still takes); a non-causal
+    call multiplies what it needs."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    done, needed = fa.causal_work(2048, 2048, fa.DEFAULT_BQ, fa.DEFAULT_BK)
+    assert needed == 2048 * 2049 // 2
+    assert 1.0 <= done / needed <= 1.13
+    whole, _ = fa.causal_work(2048, 2048, fa.DEFAULT_BQ, fa.DEFAULT_BK,
+                              kv_len=2047)
+    assert whole == 3 * 1024 * 1024
+    assert 1.49 < whole / needed < 1.51
+    assert fa.causal_work(2048, 2048, 1024, 1024, causal=False) == \
+        (2048 * 2048, 2048 * 2048)
+    assert fa.causal_work(256, 256, 128, 128, causal=False, kv_len=197) == \
+        (256 * 256, 256 * 197)
+
+
+@pytest.mark.parametrize("bq,bk", [(128, 128), (256, 128), (128, 256),
+                                   (384, 128)])
+def test_causal_block_classing_matches_a_brute_force_mask(bq, bk):
+    """skipped / full / crossed of every (qi, ki) of a 4 x 4 grid."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    under = np.arange(4 * bq)[:, None] >= np.arange(4 * bk)[None, :]
+    for qi in range(4):
+        for ki in range(4):
+            blk = under[qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk]
+            needed, full = fa._block_class(qi, ki, bq, bk)
+            assert needed == bool(blk.any()), (qi, ki)
+            assert full == bool(blk.all()), (qi, ki)
