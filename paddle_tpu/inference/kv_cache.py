@@ -71,11 +71,25 @@ class BlockPool:
         plane is a (codes int8, scale f32) pair with per-(row, head)
         factored scales (the static int8-KV trick ported to the paged
         pool); needs a head axis.
+    layer_block_shapes / state_shapes / state_rows / snapshot_rows : a
+        model whose layers do not all cache the same thing states its
+        planes layer by layer. `layer_block_shapes[i]` are the paged
+        planes of layer i (none for a layer that keeps no pages);
+        `state_shapes[i]` are its RECURRENT STATE arrays, float32, one
+        row a batch slot and not a page a block: the pool holds each as
+        ``[state_rows, *shape]`` for the engine's slots and
+        ``[snapshot_rows, *shape]`` for the snapshots the prefix trie
+        keeps where a cached prefix ends (`state_move` zeroes, saves and
+        restores a row). One manager, one memory account:
+        `bytes_per_block` is a block's over the layers that page,
+        `state_bytes` what the state planes pin.
     """
 
     def __init__(self, *, num_blocks: int, block_size: int,
-                 num_layers: int, block_shapes, head_axis: int = None,
-                 dtype="float32", cache_dtype=None):
+                 num_layers: int, block_shapes=None, head_axis: int = None,
+                 dtype="float32", cache_dtype=None, layer_block_shapes=None,
+                 state_shapes=None, state_rows: int = 0,
+                 snapshot_rows: int = 0):
         if num_blocks < 2:
             raise ValueError("num_blocks must be >= 2 (block 0 is the "
                              "reserved trash block)")
@@ -90,8 +104,29 @@ class BlockPool:
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.num_layers = int(num_layers)
-        self.block_shapes = tuple(tuple(int(d) for d in shp)
-                                  for shp in block_shapes)
+        # the planes each layer pages: the same for every layer
+        # (`block_shapes`), or stated layer by layer, some with none
+        if layer_block_shapes is None:
+            layer_block_shapes = [block_shapes] * self.num_layers
+        self.layer_block_shapes = tuple(
+            tuple(tuple(int(d) for d in shp) for shp in layer)
+            for layer in layer_block_shapes)
+        self.block_shapes = self.layer_block_shapes[0]
+        # the second kind of plane: a recurrent state a ROW, not a page.
+        # `state_shapes[i]` are layer i's state arrays (float32); each is
+        # held as [state_rows, *shape] for the live slots plus
+        # [snapshot_rows, *shape] for the prefix trie's snapshots
+        self.state_shapes = tuple(
+            tuple(tuple(int(d) for d in shp) for shp in layer)
+            for layer in (state_shapes or [()] * self.num_layers))
+        self.has_state = any(self.state_shapes)
+        self.state_rows = int(state_rows) if self.has_state else 0
+        self.snapshot_rows = int(snapshot_rows) if self.has_state else 0
+        if self.has_state and (cache_dtype is not None
+                               or self.state_rows < 1
+                               or self.snapshot_rows < 1):
+            raise ValueError("state planes need state_rows and "
+                             "snapshot_rows >= 1 and no cache_dtype")
         self.head_axis = head_axis
         # heads of a plane (None: the planes have no head axis)
         self.num_heads = None if head_axis is None \
@@ -110,11 +145,16 @@ class BlockPool:
 
     @classmethod
     def for_model(cls, model, *, num_blocks: int, block_size: int,
-                  cache_dtype=None):
+                  cache_dtype=None, state_rows: int = 0,
+                  snapshot_rows: int = 0):
         """Geometry from the model's `kv_pool_geometry(block_size)`:
-        num_layers, block_shapes, head_axis and dtype."""
+        num_layers, block_shapes (or layer_block_shapes and
+        state_shapes), head_axis and dtype. `state_rows` (the engine's
+        slots) and `snapshot_rows` size the state planes of a model that
+        has them."""
         return cls(num_blocks=num_blocks, block_size=block_size,
-                   cache_dtype=cache_dtype,
+                   cache_dtype=cache_dtype, state_rows=state_rows,
+                   snapshot_rows=snapshot_rows,
                    **model.kv_pool_geometry(block_size))
 
     def make_pools(self):
@@ -160,8 +200,14 @@ class BlockPool:
                 return (_zeros(shape, jnp.int8),
                         _zeros(shape[:-1], jnp.float32))
             return (_zeros(shape, self.dtype),)
-        return [sum((_plane(shp) for shp in self.block_shapes), ())
-                for _ in range(self.num_layers)]
+
+        def _state(shape):          # (the slots' rows, the snapshots')
+            return tuple(jnp.zeros((n,) + shape, jnp.float32)
+                         for n in (self.state_rows, self.snapshot_rows))
+        return [sum((_plane(shp) for shp in paged), ())
+                + sum((_state(shp) for shp in state), ())
+                for paged, state in zip(self.layer_block_shapes,
+                                        self.state_shapes)]
 
     # ------------------------------------------------------------- sizing
     def blocks_needed(self, tokens: int) -> int:
@@ -181,12 +227,56 @@ class BlockPool:
         """HBM bytes ONE block pins across every layer's planes — the
         unit the prefix cache's byte budget is charged in."""
         if self.cache_dtype == "int8":          # codes + f32 scale
-            per = sum(math.prod(shp) + 4 * math.prod(shp[:-1])
-                      for shp in self.block_shapes)
-        else:
-            per = sum(math.prod(shp) for shp in self.block_shapes) \
-                * np.dtype(self.dtype).itemsize
-        return per * self.num_layers
+            return sum(math.prod(shp) + 4 * math.prod(shp[:-1])
+                       for layer in self.layer_block_shapes for shp in layer)
+        return sum(math.prod(shp) for layer in self.layer_block_shapes
+                   for shp in layer) * np.dtype(self.dtype).itemsize
+
+    @property
+    def state_bytes_per_row(self) -> int:
+        """HBM bytes ONE row of the state planes pins across the layers
+        that have them: a live slot's, or a snapshot's."""
+        return 4 * sum(math.prod(shp) for layer in self.state_shapes
+                       for shp in layer)
+
+    @property
+    def state_bytes(self) -> int:
+        """What the state planes pin in all: slots and snapshots."""
+        return self.state_bytes_per_row * (self.state_rows
+                                           + self.snapshot_rows)
+
+    def state_move(self, pools, op: int, slot: int, snap: int):
+        """One row of every state plane zeroed (`STATE_ZERO`: the slot's),
+        saved (`STATE_SAVE`: slot -> snapshot) or restored (`STATE_LOAD`:
+        snapshot -> slot). The operation and both rows are DATA of one
+        small donated executable. Returns the replaced pools."""
+        import jax
+        import jax.numpy as jnp
+        n_paged = tuple(len(layer) for layer in self.layer_block_shapes)
+        sig = ("state_move", self.state_rows, self.snapshot_rows,
+               self.state_shapes)
+        fn = _SPILL_SCATTER_CACHE.get(sig)
+        if fn is None:
+            from ..jit.api import _note_cache_miss
+            _note_cache_miss()
+
+            def run(pools, op, slot, snap):
+                out = []
+                for layer, n in zip(pools, n_paged):
+                    planes = list(layer)
+                    for j in range(n, len(planes), 2):
+                        rows, snaps = planes[j], planes[j + 1]
+                        row, kept = rows[slot], snaps[snap]
+                        planes[j] = rows.at[slot].set(jnp.where(
+                            op == STATE_ZERO, 0.0,
+                            jnp.where(op == STATE_LOAD, kept, row)))
+                        planes[j + 1] = snaps.at[snap].set(
+                            jnp.where(op == STATE_SAVE, row, kept))
+                    out.append(tuple(planes))
+                return out
+            fn = _SPILL_SCATTER_CACHE[sig] = jax.jit(
+                run, donate_argnums=(0,))
+        return fn(pools, np.int32(op), np.int32(slot), np.int32(snap))
 
     @property
     def free_blocks(self) -> int:
@@ -436,8 +526,11 @@ class BlockPool:
 
 
 # one scatter executable per pool geometry, shared across engines (all
-# replicas of one model share shapes, so one compile serves the fleet)
+# replicas of one model share shapes, so one compile serves the fleet);
+# the state planes' row mover is kept here too
 _SPILL_SCATTER_CACHE: Dict[tuple, object] = {}
+
+STATE_ZERO, STATE_SAVE, STATE_LOAD = 0, 1, 2
 
 
 class HostSpillTier:

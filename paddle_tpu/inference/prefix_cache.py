@@ -82,7 +82,7 @@ class _Node:
     """One cached full block: token key, pool block id (or SPILLED),
     LRU stamp, and — while spilled — the host payload."""
     __slots__ = ("key", "block", "parent", "children", "last_used",
-                 "payload")
+                 "payload", "snap", "snap_used")
 
     def __init__(self, key, block, parent):
         self.key = key                       # tuple of block_size token ids
@@ -91,6 +91,10 @@ class _Node:
         self.children: Dict[tuple, "_Node"] = {}
         self.last_used = 0
         self.payload = None                  # host arrays while spilled
+        self.snap = None                     # row of the pool's state
+        #                                      snapshots holding the state
+        #                                      at this node's last token
+        self.snap_used = 0                   # when it was saved or restored
 
 
 class PrefixCache:
@@ -122,6 +126,13 @@ class PrefixCache:
         #                                     tier's own LRU must not
         #                                     drop it (its eviction path
         #                                     can run INSIDE _rehydrate)
+        # state snapshots (a pool with state planes): free rows of the
+        # snapshot planes, and the nodes that hold one
+        self._snap_free: List[int] = list(
+            range(getattr(pool, "snapshot_rows", 0) - 1, -1, -1))
+        self._snap_nodes: List[_Node] = []
+        self.snapshots_taken = 0
+        self.snapshot_evictions = 0
 
     def attach_spill(self, tier, *, reader, writer) -> "PrefixCache":
         """Wire the host-RAM spill tier: ``reader(block) -> payload``
@@ -164,20 +175,90 @@ class PrefixCache:
         block can be found even after evicting, the walk stops there —
         the request simply prefills the rest, and its insert upgrades
         the spilled node with the recomputed block."""
+        nodes = self._walk(tokens)
+        return [n.block for n in nodes], len(nodes) * self.pool.block_size
+
+    def _walk(self, tokens) -> List[_Node]:
+        """The nodes of the longest cached prefix of `tokens`, stamped,
+        spilled ones rehydrated (see `match`)."""
         self._tick += 1
         node = self._root
-        blocks: List[int] = []
+        nodes: List[_Node] = []
         for i in range(int(len(tokens)) // self.pool.block_size):
             child = node.children.get(self._key(tokens, i))
             if child is None:
                 break
-            if child.block == SPILLED and not self._rehydrate(child,
-                                                              blocks):
+            if child.block == SPILLED and not self._rehydrate(
+                    child, [n.block for n in nodes]):
                 break
             child.last_used = self._tick
-            blocks.append(child.block)
+            nodes.append(child)
             node = child
-        return blocks, len(blocks) * self.pool.block_size
+        return nodes
+
+    # -------------------------------------------------- state snapshots
+    # A model with recurrent layers (pool.has_state) cannot reuse a
+    # prefix's pages under a state that never saw them: a match is only
+    # as long as the deepest node on it that holds a SNAPSHOT of the
+    # state at its last token. The engine saves one where a prompt's last
+    # prefill window begins (`snapshot`), into one of the pool's
+    # `snapshot_rows`; the one longest unused (saved or restored) makes
+    # room for a new one, and a node takes its snapshot with it.
+
+    def match_state(self, tokens, limit: int) -> Tuple[List[int], int,
+                                                       Optional[int], int]:
+        """`match`, cut back to the deepest node that holds a snapshot and
+        ends at or before `limit` tokens. Returns (block_ids,
+        matched_tokens, the snapshot's row or None, tokens of the plain
+        match given up)."""
+        path = self._walk(tokens)
+        bs = self.pool.block_size
+        keep = 0
+        for i, n in enumerate(path):
+            if n.snap is not None and (i + 1) * bs <= limit:
+                keep = i + 1
+        cut = (len(path) - keep) * bs
+        if not keep:
+            return [], 0, None, cut
+        n = path[keep - 1]
+        n.snap_used = self._tick
+        return [m.block for m in path[:keep]], keep * bs, n.snap, cut
+
+    def snapshot(self, tokens, n_tokens: int) -> Optional[int]:
+        """A row of the snapshot planes for the state after `n_tokens`
+        (whole blocks, already inserted) of `tokens`, for the caller to
+        save into; None where that node holds one already (stamped) or
+        is not cached."""
+        bs = self.pool.block_size
+        node = self._root
+        for i in range(int(n_tokens) // bs):
+            node = node.children.get(self._key(tokens, i))
+            if node is None:
+                return None
+        if node is self._root:
+            return None
+        if node.snap is not None:
+            node.snap_used = self._tick
+            return None
+        if not self._snap_free:
+            self._drop_snapshot(min(self._snap_nodes,
+                                    key=lambda n: n.snap_used))
+        node.snap = self._snap_free.pop()
+        node.snap_used = self._tick
+        self._snap_nodes.append(node)
+        self.snapshots_taken += 1
+        return node.snap
+
+    @property
+    def snapshots_held(self) -> int:
+        return len(self._snap_nodes)
+
+    def _drop_snapshot(self, node: _Node) -> None:
+        if node.snap is not None:
+            self._snap_free.append(node.snap)
+            self._snap_nodes.remove(node)
+            node.snap = None
+            self.snapshot_evictions += 1
 
     def _rehydrate(self, node: _Node, protect) -> bool:
         """Bring one spilled node back on device: take an ownerless pool
@@ -334,6 +415,7 @@ class PrefixCache:
         tier's final-death accounting — its device eviction was already
         counted when it spilled)."""
         del node.parent.children[node.key]
+        self._drop_snapshot(node)
         if node.block == SPILLED:
             node.payload = None
             self._spilled -= 1
@@ -464,6 +546,8 @@ class PrefixCache:
         self._count = 0
         self._spilled = 0
         self.evicted_total += device_dropped
+        for n in list(self._snap_nodes):
+            self._drop_snapshot(n)
         return dropped
 
     def __repr__(self):

@@ -133,6 +133,7 @@ import jax
 import jax.numpy as jnp
 
 from ..profiler import StepMonitor
+from .kv_cache import STATE_LOAD, STATE_SAVE, STATE_ZERO
 from ..profiler.monitor import _jit_cache_misses
 from ..profiler._metrics import (LogHistogram, counter_lines, gauge_lines,
                                  histogram_lines)
@@ -148,6 +149,12 @@ _span = jax.profiler.TraceAnnotation
 # where a paged row's pending token and done flag are read from when a
 # decode chunk is launched (ServingEngine._stage_decode_inputs)
 _SRC_HOST, _SRC_CHUNK, _SRC_FIRST = 0, 1, 2
+
+
+# what an engine over a pool with state planes counts (ServingMetrics
+# gains them only then)
+_STATE_COUNTERS = ("state_snapshots_taken", "state_snapshots_restored",
+                   "state_snapshot_evictions", "prefix_match_cut_tokens")
 
 
 @dataclass
@@ -619,7 +626,36 @@ class ServingMetrics:
                  "expert_tokens_max": "tokens of the fullest held expert, "
                                       "summed over expert-layer calls",
                  "expert_layer_calls": "expert-layer calls (one a layer "
-                                       "and model step)"}
+                                       "and model step)",
+                 # block-sparse attention and recurrent state (models
+                 # that have them; absent otherwise)
+                 "sparse_rows": "decode row-steps of a sparse-attention "
+                                "layer that selected their pages",
+                 "dense_rows": "decode row-steps of a sparse-attention "
+                               "layer short enough to attend every page",
+                 "sparse_blocks_attended": "pages walked by selecting "
+                                           "decode row-steps (all KV "
+                                           "heads)",
+                 "dense_blocks_attended": "pages walked by dense decode "
+                                          "row-steps (all KV heads)",
+                 "sparse_keys_scored": "compressed keys scored by "
+                                       "selecting queries (prefill and "
+                                       "decode)",
+                 "state_rows_updated": "decode row-steps of a recurrent "
+                                       "layer that updated their state",
+                 "attn_pairs": "(query, token) pairs attended a "
+                               "sparse-attention layer (prefill and "
+                               "decode)",
+                 "state_snapshots_taken": "recurrent-state snapshots "
+                                          "saved beside a cached prefix",
+                 "state_snapshots_restored": "admissions that restored a "
+                                             "slot's state from a snapshot",
+                 "state_snapshot_evictions": "snapshots dropped for a "
+                                             "newer one or with their "
+                                             "trie node",
+                 "prefix_match_cut_tokens": "tokens of a prefix match "
+                                            "given up for want of a "
+                                            "state snapshot"}
         for name, value in self.counters.items():
             lines.extend(counter_lines(prefix, f"{name}_total", value,
                                        helps[name]))
@@ -634,7 +670,10 @@ class ServingMetrics:
                                        "/ pooled capacity",
                  "kv_shared_tokens": "logical KV rows served from "
                                      "shared prefix blocks (summed over "
-                                     "requests)"}
+                                     "requests)",
+                 "state_slots_occupancy": "rows of the recurrent-state "
+                                          "planes in use (live slots and "
+                                          "snapshots) / rows held"}
         for name, value in self.gauges.items():
             lines.extend(gauge_lines(prefix, name, value, ghelp[name]))
         for name, help_ in self.HISTS:
@@ -732,6 +771,11 @@ class ServingConfig:
     # length). None = the whole prompt (or uncached suffix) in one
     # window.
     prefill_chunk: Optional[int] = None
+    # --- recurrent state (a model whose geometry states `state_shapes`):
+    # rows of the state planes kept for the prefix trie's snapshots, each
+    # the state of every such layer where a cached prefix ends. Counted in
+    # the pool's memory (`BlockPool.state_bytes`); unused by other models.
+    state_snapshots: int = 8
     # --- static analysis (ISSUE 6): True / "error" / analysis.GraphLint —
     # the engine audits each of its {prefill, decode} executables with
     # the graph lint once, the first step it is built (findings
@@ -943,7 +987,15 @@ class ServingEngine:
         self._pool = BlockPool.for_model(model,
                                          num_blocks=config.kv_blocks,
                                          block_size=config.kv_block,
-                                         cache_dtype=config.cache_dtype)
+                                         cache_dtype=config.cache_dtype,
+                                         state_rows=B,
+                                         snapshot_rows=config.state_snapshots)
+        if self._pool.has_state:
+            # engine-side counters of the state planes (the model's own
+            # per-call counters arrive through step_counter_names)
+            for name in _STATE_COUNTERS:
+                self.metrics.counters.setdefault(name, 0)
+            self.metrics.gauges.setdefault("state_slots_occupancy", None)
         with self._mesh_scope():
             self._pools = self._pool.make_pools()
         self._slots: List[Optional[Request]] = [None] * B
@@ -1335,6 +1387,12 @@ class ServingEngine:
             raise
         with _span("serving/bookkeep"):
             self.metrics.gauges["inflight"] = len(self._live())
+            if self._pool.has_state:
+                held = 0 if self._prefix is None \
+                    else self._prefix.snapshots_held
+                self.metrics.gauges["state_slots_occupancy"] = \
+                    (len(self._live()) + held) / (
+                        self._pool.state_rows + self._pool.snapshot_rows)
             if ran:
                 # gauges describe the step's micro-batch: fill = rows of
                 # the chunk it launched (a step without one: the requests
@@ -1602,8 +1660,19 @@ class ServingEngine:
                     continue
                 plen = req.prompt_len
                 need_rows = plen + req.max_new_tokens - 1
-                matched, t = ([], 0) if self._prefix is None \
-                    else self._prefix.match(req.prompt)
+                snap = None
+                if self._prefix is None:
+                    matched, t = [], 0
+                elif self._pool.has_state:
+                    # pages are only reusable under a state that saw
+                    # them: the match ends at the deepest snapshot, and
+                    # before the prompt's last token (which is prefilled
+                    # or re-decoded), so it never needs a copy-on-write
+                    matched, t, snap, cut = self._prefix.match_state(
+                        req.prompt, plen - 1)
+                    self.metrics.counters["prefix_match_cut_tokens"] += cut
+                else:
+                    matched, t = self._prefix.match(req.prompt)
                 # COW: an aligned full hit (t == plen) shares all matched
                 # blocks EXCEPT the last, which is replaced by a private copy
                 # (the re-decode write lands in it); otherwise the shared run
@@ -1627,7 +1696,7 @@ class ServingEngine:
                         # (preflight's fits_ever) must not starve on its own
                         # protected cached prefix — drop the hit, reclaim
                         # freely, full-prefill
-                        matched, t, cow, shared = [], 0, False, []
+                        matched, t, cow, shared, snap = [], 0, False, [], None
                         if self._prefix.reclaim(
                                 self._pool.blocks_needed(need_rows)):
                             blocks = self._pool.alloc(req.id, need_rows)
@@ -1652,6 +1721,15 @@ class ServingEngine:
                 self._tables[slot] = self._pool.table_row(
                     req.id, self._tables.shape[1])
                 self._shared_tok[slot] = len(shared) * bs
+                if self._pool.has_state:
+                    # the slot's state: the snapshot's, or none
+                    self._pools = self._pool.state_move(
+                        self._pools, STATE_ZERO if snap is None
+                        else STATE_LOAD, slot, snap or 0)
+                    ran.add("state_move")
+                    if snap is not None:
+                        self.metrics.counters["state_snapshots_restored"] += 1
+                    req._state_from = t
                 # tokens the host has read / tokens launched: the budget
                 # is kept against the second, so a chunk is sized before
                 # the one before it is read
@@ -1732,6 +1810,11 @@ class ServingEngine:
             name = "prefill_chunk" if pc is not None else \
                 "prefill" if off == 0 else "suffix_prefill"
             t_pf0 = self.clock()
+            kw = {}
+            if self._pool.has_state:
+                kw["state_slots"] = np.asarray([slot], np.int32)  # lint: allow(tracer-asarray)
+                if final:
+                    self._snapshot_state(slot, req, off)
             with _span("serving/prefill"):
                 with _span("serving/prefill_launch"):
                     self._pools, first = self.model.prefill_paged(
@@ -1740,7 +1823,7 @@ class ServingEngine:
                         temperature=cfg.temperature, top_k=cfg.top_k,
                         top_p=cfg.top_p, seed=cfg.seed + self._calls,
                         weight_dtype=cfg.weight_dtype,
-                        cache_dtype=cfg.cache_dtype, start=start)
+                        cache_dtype=cfg.cache_dtype, start=start, **kw)
                     if final:
                         self._firsts = self._device_helper(
                             "paged_put_first",
@@ -1761,6 +1844,27 @@ class ServingEngine:
             req._launched = 1
             self._insert_prefix(req, self._pool.owned(req.id), plen)
             flight.firsts.append((slot, req, first, name, t_pf0))
+
+    def _snapshot_state(self, slot: int, req: Request, off: int):
+        """Before a prompt's LAST prefill window is launched: the slot's
+        state is the state after the `off` tokens the windows before it
+        covered. Where that is a block boundary past what the trie
+        matched, cache those blocks now and save the state beside them
+        (one snapshot a prompt, at the longest prefix a later prompt can
+        share whole windows of)."""
+        bs = self._pool.block_size
+        if self._prefix is None or off <= req._state_from or off % bs:
+            return
+        self._insert_prefix(req, self._pool.owned(req.id), off)
+        evicted = self._prefix.snapshot_evictions
+        row = self._prefix.snapshot(req.prompt, off)
+        if row is not None:
+            self._pools = self._pool.state_move(self._pools, STATE_SAVE,
+                                                slot, row)
+            mt = self.metrics.counters
+            mt["state_snapshots_taken"] += 1
+            mt["state_snapshot_evictions"] += \
+                self._prefix.snapshot_evictions - evicted
 
     def _launch_decode(self, flight: _Flight, live: List[int], staged):
         """Enqueue one fixed-shape decode chunk over the whole slot batch

@@ -106,7 +106,7 @@ def _page_map(ndim):
 
 
 def _kernel_slots(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-            m_sc, l_sc, acc_sc, *, scale, nh, bs, n_slots):
+            m_sc, l_sc, acc_sc, *, scale, nh, bs, n_slots, group=1):
     b, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
@@ -128,7 +128,7 @@ def _kernel_slots(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         # "matmul" is a broadcast multiply + lane reduction; nh unrolls
         # statically (serving configs keep nh <= 40)
         for h in range(nh):
-            s = jnp.sum(k[:, h, :] * q[h:h + 1, :], axis=-1,
+            s = jnp.sum(k[:, h // group, :] * q[h:h + 1, :], axis=-1,
                         keepdims=True) * scale      # [bs, 1]
             s = jnp.where(keep, s, jnp.asarray(_NEG, s.dtype))
             m_prev = m_sc[h:h + 1, :]               # [1, 1]
@@ -140,7 +140,7 @@ def _kernel_slots(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
             l_sc[h:h + 1, :] = corr * l_prev + jnp.sum(p, axis=0,
                                                        keepdims=True)
             acc_sc[h:h + 1, :] = corr * acc_sc[h:h + 1, :] + jnp.sum(
-                p * v[:, h, :], axis=0, keepdims=True)
+                p * v[:, h // group, :], axis=0, keepdims=True)
 
     @pl.when(j == n_slots - 1)
     def _finish():
@@ -262,7 +262,7 @@ def paged_attention_q8_kernel(q, kc_pool, ks_pool, vc_pool, vs_pool,
 # MXU while the fetch pattern stays the block-table walk.
 
 def _kernel_multi(tables_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_sc, l_sc, acc_sc, *, scale, nh, bs, s, n_slots):
+                  m_sc, l_sc, acc_sc, *, scale, nh, bs, s, n_slots, group=1):
     b, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
@@ -282,7 +282,7 @@ def _kernel_multi(tables_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
         row = lax.broadcasted_iota(jnp.int32, (s, bs), 0)
         keep = col <= st + row           # causal across prefix + window
         for h in range(nh):
-            sc = lax.dot_general(q[:, h, :], k[:, h, :],
+            sc = lax.dot_general(q[:, h, :], k[:, h // group, :],
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
             sc = sc * scale                          # [s, bs]
@@ -295,7 +295,7 @@ def _kernel_multi(tables_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
             m_sc[h] = m_new
             l_sc[h] = corr * l_prev + jnp.sum(p, axis=1, keepdims=True)
             acc_sc[h] = corr * acc_sc[h] + lax.dot_general(
-                p, v[:, h, :], (((1,), (0,)), ((), ())),
+                p, v[:, h // group, :], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)  # [s, hd]
 
     @pl.when(j == n_slots - 1)
@@ -363,18 +363,21 @@ PREFIX_NAME = "pallas_paged_prefix"
 def paged_prefix_attention_kernel(q, k_pool, v_pool, tables, start, *,
                                   scale=None, interpret=False):
     """Ragged multi-token paged attention: q [B, S, H, D] query tokens at
-    global positions start[b] + i; pools [NB, bs, H, D]; tables [B, MB]
-    i32; start [B] i32. Each query row attends every pool column <= its
-    own position — the kernel form of `paged_prefix_attention_reference`
-    (suffix prefill, chunked prefill, spec-decode verify; S = 1 with
-    start = lens is exactly the decode case). Returns q's layout."""
+    global positions start[b] + i; pools [NB, bs, Hkv, D], Hkv dividing H
+    (query head h reads KV head h // (H / Hkv); Hkv = H is the kernel it
+    always was); tables [B, MB] i32; start [B] i32. Each query row
+    attends every pool column <= its own position — the kernel form of
+    `paged_prefix_attention_reference` (suffix prefill, chunked prefill,
+    spec-decode verify; S = 1 with start = lens is exactly the decode
+    case). Returns q's layout."""
     b, s, nh, hd = q.shape
     nb, bs = k_pool.shape[0], k_pool.shape[1]
+    nkv = _kv_heads(nh, k_pool)
     mb = tables.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(hd)
 
-    pool_spec = pl.BlockSpec((1, bs, nh, hd), _page_map(4))
+    pool_spec = pl.BlockSpec((1, bs, nkv, hd), _page_map(4))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, mb),
@@ -389,7 +392,7 @@ def paged_prefix_attention_kernel(q, k_pool, v_pool, tables, start, *,
     )
     return pl.pallas_call(
         functools.partial(_kernel_multi, scale=scale, nh=nh, bs=bs, s=s,
-                          n_slots=mb),
+                          n_slots=mb, group=nh // nkv),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, s, nh, hd), q.dtype),
         interpret=interpret,
@@ -488,8 +491,9 @@ def _kernel_walk(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
     b, n_rows = pl.program_id(0), pl.num_programs(0)
     mb = tables_ref.shape[1]
     nh, hd = q_ref.shape[1], q_ref.shape[2]
+    nkv = k_buf.shape[3]                            # KV heads of a page
     t = pps * bs                                    # tokens a block holds
-    w = t * nh                                      # its (token, head) rows
+    w = t * nkv                                     # its (token, head) rows
 
     def cdiv(a, d):                   # i32 throughout (Mosaic x64 rule)
         return lax.div(a + (d - 1), jnp.int32(d))
@@ -529,7 +533,9 @@ def _kernel_walk(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
         # every length: one compare a block then masks head and length
         lane = lax.broadcasted_iota(jnp.int32, (nh, w), 1)
         head = lax.broadcasted_iota(jnp.int32, (nh, w), 0)
-        lane_ref[...] = jnp.where(lax.rem(lane, jnp.int32(nh)) == head,
+        if nh != nkv:       # grouped: row h keeps the lanes of KV head h // G
+            head = lax.div(head, jnp.int32(nh // nkv))
+        lane_ref[...] = jnp.where(lax.rem(lane, jnp.int32(nkv)) == head,
                                   lane, jnp.int32(jnp.iinfo(jnp.int32).max))
         # a block's pages past its row's last are not fetched, and what a
         # slot holds there meets a probability of exactly 0: so it has to
@@ -558,7 +564,7 @@ def _kernel_walk(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
         k = k_buf[slot].reshape(w, hd)
         v = v_buf[slot].reshape(w, hd)
         s = _sum_rows(_block_dot(q, k, ((1,), (1,))), nh) * scale  # [nh, w]
-        keep = lane_ref[...] < (ln - blk * t) * nh
+        keep = lane_ref[...] < (ln - blk * t) * nkv
         s = jnp.where(keep, s, jnp.asarray(_NEG, s.dtype))
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)                  # exactly 0 off `keep`
@@ -581,6 +587,15 @@ def _kernel_walk(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
     slot_ref[0] = lax.rem(slot0 + n_blocks, jnp.int32(2))
 
 
+def _kv_heads(nh, k_pool):
+    """KV heads of a [NB, bs, Hkv, D] pool under `nh` query heads."""
+    nkv = k_pool.shape[2]
+    if nh % nkv:
+        raise ValueError(f"{nh} query heads over {nkv} KV heads: the "
+                         f"groups must be whole")
+    return nkv
+
+
 def _pages_dma_sliceable(nh, hd):
     """Whether the chip's compiler lets a DMA slice one page out of a
     [NB, bs, nh, hd] pool: the pool is tiled over (nh, hd) in HBM, and a
@@ -591,9 +606,10 @@ def _pages_dma_sliceable(nh, hd):
 
 def paged_attention_kernel(q, k_pool, v_pool, tables, lens, *, scale=None,
                            interpret=False):
-    """q [B, 1, H, D] (or [B, H, D]); pools [NB, bs, H, D]; tables
-    [B, MB] i32; lens [B] = attendable rows per batch entry. Returns the
-    same layout as q."""
+    """q [B, 1, H, D] (or [B, H, D]); pools [NB, bs, Hkv, D], Hkv
+    dividing H (query head h reads KV head h // (H / Hkv); Hkv = H is the
+    kernel it always was); tables [B, MB] i32; lens [B] = attendable rows
+    per batch entry. Returns the same layout as q."""
     squeezed = q.ndim == 4
     if squeezed:
         if q.shape[1] != 1:
@@ -604,12 +620,13 @@ def paged_attention_kernel(q, k_pool, v_pool, tables, lens, *, scale=None,
         q3 = q
     b, nh, hd = q3.shape
     bs = k_pool.shape[1]
+    nkv = _kv_heads(nh, k_pool)
     mb = tables.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(hd)
 
-    if _pages_dma_sliceable(nh, hd):
-        pps = _pages_per_step(bs * nh * hd * k_pool.dtype.itemsize, mb)
+    if _pages_dma_sliceable(nkv, hd):
+        pps = _pages_per_step(bs * nkv * hd * k_pool.dtype.itemsize, mb)
         # q and the output whole, once: 4 KiB a row is not worth a DMA
         # and a wait in every program
         rows = pl.BlockSpec((b, nh, hd),
@@ -621,24 +638,24 @@ def paged_attention_kernel(q, k_pool, v_pool, tables, lens, *, scale=None,
             grid=(b,),
             in_specs=[rows, pool, pool],
             out_specs=rows,
-            scratch_shapes=[pltpu.VMEM((2, pps, bs, nh, hd), k_pool.dtype),
-                            pltpu.VMEM((2, pps, bs, nh, hd), v_pool.dtype),
+            scratch_shapes=[pltpu.VMEM((2, pps, bs, nkv, hd), k_pool.dtype),
+                            pltpu.VMEM((2, pps, bs, nkv, hd), v_pool.dtype),
                             pltpu.SemaphoreType.DMA((2, 2)),
                             pltpu.SMEM((1,), jnp.int32),
-                            pltpu.VMEM((nh, pps * bs * nh), jnp.int32)],
+                            pltpu.VMEM((nh, pps * bs * nkv), jnp.int32)],
         )
         # rows in order: each fetches the next one's first block
         semantics = ("arbitrary",)
     else:
         kernel = functools.partial(_kernel_slots, scale=scale, nh=nh, bs=bs,
-                                   n_slots=mb)
+                                   n_slots=mb, group=nh // nkv)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, mb),
             in_specs=[
                 pl.BlockSpec((1, nh, hd), _row_map(3)),
-                pl.BlockSpec((1, bs, nh, hd), _page_map(4)),
-                pl.BlockSpec((1, bs, nh, hd), _page_map(4)),
+                pl.BlockSpec((1, bs, nkv, hd), _page_map(4)),
+                pl.BlockSpec((1, bs, nkv, hd), _page_map(4)),
             ],
             out_specs=pl.BlockSpec((1, nh, hd), _row_map(3)),
             scratch_shapes=[pltpu.VMEM((nh, 1), jnp.float32),
@@ -655,3 +672,153 @@ def paged_attention_kernel(q, k_pool, v_pool, tables, lens, *, scale=None,
         name=DECODE_NAME,
     )(tables.astype(jnp.int32), lens.astype(jnp.int32), q3, k_pool, v_pool)
     return out[:, None] if squeezed else out
+
+
+# ------------------------------------------------ grouped KV heads, lists
+# Fewer KV heads than query heads, and a LIST of pages a (row, KV head)
+# instead of the row's table (ops/sparse_attention.py: the pages a
+# block-sparse layer selected, or a short row's whole prefix). The pools
+# are [NB, Hkv, bs, D]: a KV head's page is a [bs, D] tile of its own, a
+# DMA slices it whole, and the G query heads of the group are the rows of
+# ONE product against it (no lane of a score tile belongs to another
+# head, so nothing of `_kernel_walk`'s head mask is needed). A program is
+# a (row, KV head) pair, in order, each fetching the next one's first
+# block; only a list's last page may be partly filled.
+
+GROUPED_DECODE_NAME = "pallas_paged_grouped_decode"
+
+
+def grouped_pages_dma_sliceable(bs, hd, dtype):
+    """Whether a [bs, hd] page of a [NB, Hkv, bs, hd] pool fills whole
+    tiles of the pool's layout, so that a DMA can slice it out."""
+    return hd % 128 == 0 and bs % (32 // jnp.dtype(dtype).itemsize) == 0
+
+
+def _kernel_grouped_walk(ids_ref, toks_ref, q_ref, k_hbm, v_hbm, o_ref,
+                         k_buf, v_buf, sems, slot_ref, *, scale, bs, pps,
+                         hkv):
+    r, n_rows = pl.program_id(0), pl.num_programs(0)
+    w = ids_ref.shape[1]
+    g, hd = q_ref.shape[1], q_ref.shape[2]
+    t = pps * bs
+
+    def cdiv(a, d):
+        return lax.div(a + (d - 1), jnp.int32(d))
+
+    def pages_of(row):
+        return jnp.minimum(cdiv(toks_ref[row], bs), w)
+
+    def block_dmas(op, row, blk, slot, n_pages):
+        slot = jnp.asarray(slot, jnp.int32)
+        row = jnp.asarray(row, jnp.int32)
+        head = lax.rem(row, jnp.int32(hkv))
+        for i in range(pps):
+            @pl.when(blk * pps + i < n_pages)
+            def _():
+                page = ids_ref[row, blk * pps + i]
+                for j, (pool, buf) in enumerate(((k_hbm, k_buf),
+                                                 (v_hbm, v_buf))):
+                    getattr(pltpu.make_async_copy(
+                        pool.at[page, head], buf.at[slot, jnp.int32(i)],
+                        sems.at[jnp.int32(j), slot]), op)()
+
+    def fetch_first_of_next_row(slot):
+        nxt = jnp.minimum(r + 1, n_rows - 1)
+        @pl.when(r + 1 < n_rows)
+        def _():
+            block_dmas("start", nxt, 0, slot, pages_of(nxt))
+
+    n_pages = pages_of(r)
+    n_blocks = cdiv(n_pages, pps)
+    ln = jnp.minimum(toks_ref[r], w * bs)
+
+    @pl.when(r == 0)
+    def _():
+        v_buf[...] = jnp.zeros_like(v_buf)      # see `_kernel_walk`
+        slot_ref[0] = 0
+        block_dmas("start", 0, 0, 0, n_pages)
+
+    slot0 = slot_ref[0]
+    q = _mxu_rows(q_ref[r], k_buf.dtype)            # [g or 2 g, hd]
+    col = lax.broadcasted_iota(jnp.int32, (g, t), 1)
+
+    def body(blk, carry):
+        m_prev, l_prev, acc = carry
+        slot = lax.rem(slot0 + blk, jnp.int32(2))
+
+        @pl.when(blk + 1 < n_blocks)
+        def _():
+            block_dmas("start", r, blk + 1, 1 - slot, n_pages)
+
+        @pl.when(blk + 1 == n_blocks)
+        def _():
+            fetch_first_of_next_row(1 - slot)
+
+        block_dmas("wait", r, blk, slot, n_pages)
+
+        k = k_buf[slot].reshape(t, hd)
+        v = v_buf[slot].reshape(t, hd)
+        s = _sum_rows(_block_dot(q, k, ((1,), (1,))), g) * scale    # [g, t]
+        keep = col < ln - blk * t
+        s = jnp.where(keep, s, jnp.asarray(_NEG, s.dtype))
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        p = jnp.where(keep, p, 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = corr * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        pv = _block_dot(_mxu_rows(p, v.dtype), v, ((1,), (0,)))
+        return m_new, l_new, corr * acc + _sum_rows(pv, g)
+
+    _, l, acc = lax.fori_loop(
+        jnp.int32(0), n_blocks, body,
+        (jnp.full((g, 1), _NEG, jnp.float32),
+         jnp.zeros((g, 1), jnp.float32),
+         jnp.zeros((g, hd), jnp.float32)))
+    o_ref[r] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+    @pl.when(n_blocks == 0)
+    def _():
+        fetch_first_of_next_row(slot0)
+
+    slot_ref[0] = lax.rem(slot0 + n_blocks, jnp.int32(2))
+
+
+def grouped_paged_attention_kernel(q, k_pool, v_pool, ids, tokens, *,
+                                   scale=None, interpret=False):
+    """q [B, Hkv, G, D]; pools [NB, Hkv, bs, D]; ids [B, Hkv, W] i32 the
+    pages each (row, KV head) attends, in order; tokens [B, Hkv] i32 how
+    many tokens they hold (only the last page may be partly filled).
+    Returns q's layout and dtype."""
+    b, hkv, g, hd = q.shape
+    bs = k_pool.shape[2]
+    w = ids.shape[2]
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
+    rows = b * hkv
+    pps = _pages_per_step(bs * hd * k_pool.dtype.itemsize, w)
+    whole = pl.BlockSpec((rows, g, hd),
+                         lambda ri, ids, toks: (_i0(), _i0(), _i0()))
+    pool = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(rows,),
+        in_specs=[whole, pool, pool],
+        out_specs=whole,
+        scratch_shapes=[pltpu.VMEM((2, pps, bs, hd), k_pool.dtype),
+                        pltpu.VMEM((2, pps, bs, hd), v_pool.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.SMEM((1,), jnp.int32)],
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel_grouped_walk, scale=scale, bs=bs, pps=pps,
+                          hkv=hkv),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((rows, g, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name=GROUPED_DECODE_NAME,
+    )(ids.reshape(rows, w).astype(jnp.int32),
+      tokens.reshape(rows).astype(jnp.int32), q.reshape(rows, g, hd),
+      k_pool, v_pool)
+    return out.reshape(b, hkv, g, hd)

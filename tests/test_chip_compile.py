@@ -388,6 +388,44 @@ def test_latent_decode_at_the_serving_cells_geometry(one_chip):
     _assert_kernel(text, la.LATENT_DECODE_NAME)
 
 
+# ------------------------------------------------ grouped KV heads, page lists
+def test_page_list_decode_at_the_sparse_cells_geometry(one_chip):
+    """B 128 x 2 KV heads of 16 query heads, lists of 128 pages (a dense
+    row's 8,192 tokens; a selection fills 64), 6,144 pages [2, 64, 128]
+    (benchmarks/workloads/serve-minicpm-sala-docs32k-over.json): a KV
+    head's page is a [64, 128] tile a DMA slices whole, where a
+    [64, 2, 128] page would be padded eightfold in the pool."""
+    assert pa.grouped_pages_dma_sliceable(64, 128, jnp.bfloat16)
+    assert not pa.grouped_pages_dma_sliceable(8, 128, jnp.bfloat16)
+    pool = ((6144, 2, 64, 128), jnp.bfloat16)
+    text = _compile(
+        lambda q, k, v, i, t: pa.grouped_paged_attention_kernel(
+            q, k, v, i, t, scale=128 ** -0.5),
+        one_chip, ((128, 2, 16, 128), jnp.bfloat16), pool, pool,
+        ((128, 2, 128), jnp.int32), ((128, 2), jnp.int32))
+    _assert_kernel(text, pa.GROUPED_DECODE_NAME)
+    assert "bf16[6144,2,64,128]{3,2,1,0" in text        # the pool as it lies
+
+
+@pytest.mark.parametrize("nkv", [8, 4], ids=["walk", "slots"])
+def test_paged_decode_with_grouped_kv_heads(one_chip, nkv):
+    """The GPT-layout kernels under fewer KV heads than query heads
+    (pools [NB, bs, Hkv, D]): 8 KV heads still fill the tiles a DMA
+    slices (the walk), 4 keep the grid over the table's slots."""
+    assert pa._pages_dma_sliceable(nkv, HD) == (nkv == 8)
+    pool = ((POOL_BLOCKS, KV_BLOCK, nkv, HD), jnp.bfloat16)
+    text = _compile(pa.paged_attention_kernel, one_chip, _q(1), pool, pool,
+                    _TABLES, _ROWS)
+    _assert_kernel(text, pa.DECODE_NAME)
+
+
+def test_paged_prefix_with_grouped_kv_heads(one_chip):
+    pool = ((POOL_BLOCKS, KV_BLOCK, 4, HD), jnp.bfloat16)
+    text = _compile(pa.paged_prefix_attention_kernel, one_chip, _q(4), pool,
+                    pool, _TABLES, _ROWS)
+    _assert_kernel(text, pa.PREFIX_NAME)
+
+
 # -------------------------------------------- the engine's own device programs
 @pytest.mark.parametrize("b,chunk", [(32, 8), (128, 8)],
                          ids=["gpt-cells", "pangu-cell"])
