@@ -394,11 +394,14 @@ class ServingMetrics:
                          "mem_pressure_episodes": 0,
                          # the paged step's overlap: decode chunks
                          # launched, those launched while an earlier one
-                         # was still unread, and chunk rows spent on a
-                         # request whose EOS the host had not seen yet
+                         # was still unread, chunk rows spent on a
+                         # request whose EOS the host had not seen yet,
+                         # and chunk rows that rode neutral (no request,
+                         # or one not decodable yet: they attend nothing)
                          "decode_chunks": 0,
                          "decode_chunks_overlapped": 0,
-                         "eos_late_rows": 0}
+                         "eos_late_rows": 0,
+                         "decode_rows_idle": 0}
         self.gauges = {"queue_depth": 0, "inflight": 0,
                        "batch_fill_ratio": None, "kv_occupancy": None,
                        "kv_slots_occupancy": None,
@@ -613,6 +616,9 @@ class ServingMetrics:
                  "eos_late_rows": "rows of a decode chunk launched after "
                                   "their request's EOS and before the "
                                   "host read it (tokens discarded)",
+                 "decode_rows_idle": "rows of launched decode chunks that "
+                                     "rode neutral (max_batch less the "
+                                     "rows decoding): they attend nothing",
                  # expert layers (models that hold a share of a sparse
                  # layer's experts; absent otherwise)
                  "expert_assignments_here": "(token, expert) assignments "
@@ -1877,6 +1883,7 @@ class ServingEngine:
         tables, lens, pending, done = staged
         mt = self.metrics.counters
         mt["decode_chunks"] += 1
+        mt["decode_rows_idle"] += cfg.max_batch - len(live)
         if self._flight is not None and self._flight.toks is not None:
             mt["decode_chunks_overlapped"] += 1
         flight.t0 = self.clock()

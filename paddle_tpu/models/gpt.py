@@ -110,6 +110,12 @@ def gpt_config(preset: str, **overrides) -> GPTConfig:
 from contextlib import contextmanager
 
 
+def _attended(lens, done=None):
+    """Rows a paged decode step attends: the `lens` cached and the token
+    just written; none for a `done` row, whose output nobody reads."""
+    return lens + 1 if done is None else jnp.where(done, 0, lens + 1)
+
+
 def _is_q8_cache(cache):
     """True iff a static-cache tuple is the int8 form (k_codes, k_scale,
     v_codes, v_scale, pos[, ragged]). The length check alone is not a safe
@@ -200,9 +206,10 @@ class GPTSelfAttention(Layer):
         new_cache = None
         if paged:
             # PAGED KV-cache serving (ISSUE 5/10): ("paged", k_pool,
-            # v_pool, block_tables, lens[, start]) — or, int8 pools,
-            # ("paged8", k_codes, k_scale, v_codes, v_scale, tables,
-            # lens[, start]). KV lives in a fixed [NB, bs, nh, hd] block
+            # v_pool, block_tables, lens[, start[, done]]) — or, int8
+            # pools, ("paged8", k_codes, k_scale, v_codes, v_scale,
+            # tables, lens[, start[, done]]). KV lives in a fixed
+            # [NB, bs, nh, hd] block
             # pool shared by every request; each row owns blocks named by
             # its table row. One executable serves ANY mix of request
             # lengths — the table/lens/start vectors are data, never
@@ -211,7 +218,10 @@ class GPTSelfAttention(Layer):
             # A trailing `start` (prefix cache) marks SUFFIX prefill:
             # the s > 1 tokens sit at global positions start[b] + i, and
             # attention runs over the pool (cached prefix + suffix)
-            # instead of the prompt alone.
+            # instead of the prompt alone. A decode step (start None) may
+            # name its `done` rows [B] bool — idle slots, rows past their
+            # EOS — whose output nobody reads: they attend nothing, and
+            # a row of length 0 costs the kernel no fetch and no product.
             if cache[0] not in ("paged", "paged8"):
                 raise ValueError(f"unknown tagged KV-cache kind "
                                  f"{cache[0]!r} (expected 'paged' or "
@@ -240,7 +250,7 @@ class GPTSelfAttention(Layer):
                                          attention_reference)
             if q8c:
                 kc, ks, vc, vs, tables, lens = cache[1:7]
-                start = cache[7] if len(cache) > 7 else None
+                start, done = (cache[7:9] + (None, None))[:2]
                 # dispatch on start-presence BEFORE width: a [B, 1]
                 # window WITH a start offset is a 1-token suffix-prefill
                 # chunk (write at start[b], attend the pool), not a
@@ -258,12 +268,14 @@ class GPTSelfAttention(Layer):
                         "paged_cache_v_q8", paged_cache_write_q8,
                         [vc, vs, qkv[:, :, 2], tables, lens])
 
-                    def _attend_paged_q8(qa, kca, ksa, vca, vsa, t, l):
+                    def _attend_paged_q8(qa, kca, ksa, vca, vsa, t, l,
+                                         *dn):
                         return paged_attention_q8(qa, kca, ksa, vca, vsa,
-                                                  t, l + 1)
+                                                  t, _attended(l, *dn))
 
                     ctx = apply_op("paged_attend_q8", _attend_paged_q8,
-                                   [q, kc2, ks2, vc2, vs2, tables, lens])
+                                   [q, kc2, ks2, vc2, vs2, tables, lens]
+                                   + ([] if done is None else [done]))
                 elif start is not None:
                     # suffix prefill: quantized writes at start[b] + i,
                     # attention over the pool (cached prefix + suffix)
@@ -309,25 +321,26 @@ class GPTSelfAttention(Layer):
                              vc2.detach(), vs2.detach(), tables, lens) + \
                     (() if start is None else (start,))
             else:
-                kp, vp, tables, lens = cache[1], cache[2], cache[3], \
-                    cache[4]
-                start = cache[5] if len(cache) > 5 else None
+                kp, vp, tables, lens = cache[1:5]
+                start, done = (cache[5:7] + (None, None))[:2]
                 # same start-before-width dispatch as the q8 branch
                 if s == 1 and start is None:
                     # decode step: the token lands at row position
                     # lens[b] and attends to cols <= itself (lens + 1
-                    # attendable rows)
+                    # attendable rows; none where the row is done)
                     kp2 = apply_op("paged_cache_k", paged_cache_write,
                                    [kp, qkv[:, :, 1], tables, lens])
                     vp2 = apply_op("paged_cache_v", paged_cache_write,
                                    [vp, qkv[:, :, 2], tables, lens])
 
-                    def _attend_paged(qa, kpa, vpa, t, l):
-                        return paged_attention(qa, kpa, vpa, t, l + 1,
+                    def _attend_paged(qa, kpa, vpa, t, l, *dn):
+                        return paged_attention(qa, kpa, vpa, t,
+                                               _attended(l, *dn),
                                                score_dtype=qa.dtype)
 
                     ctx = apply_op("paged_attend", _attend_paged,
-                                   [q, kp2, vp2, tables, lens])
+                                   [q, kp2, vp2, tables, lens]
+                                   + ([] if done is None else [done]))
                 elif start is not None:
                     # suffix prefill (prefix cache): write at
                     # start[b] + i, attend over the pool — causal across
@@ -1141,12 +1154,12 @@ class GPTForCausalLM(Layer):
         def run(pa, pools, tbl, lens_, pending_, done_, key0):
             pa = _replicate_tree(pa)
 
-            def model_step(tokens, pools, ln):
+            def model_step(tokens, pools, ln, dn):
                 ex, pays = expand(pa)
                 with _trace_guard(), _swap_params(params, ex), \
                         _q8_bind(params, pays), autograd.no_grad():
                     caches = [(tag,) + tuple(Tensor(p) for p in layer) +
-                              (Tensor(tbl), Tensor(ln))
+                              (Tensor(tbl), Tensor(ln), None, Tensor(dn))
                               for layer in pools]
                     logits, nc = self.forward(
                         Tensor(tokens), position_ids=Tensor(ln[:, None]),
@@ -1157,7 +1170,7 @@ class GPTForCausalLM(Layer):
 
             def body(carry, _):
                 pools, ln, cur, key, dn = carry
-                logits, pools = model_step(cur[:, None], pools, ln)
+                logits, pools = model_step(cur[:, None], pools, ln, dn)
                 ln = ln + 1
                 key, kk = jax.random.split(key)
                 new = pick(logits[:, -1].astype(jnp.float32),

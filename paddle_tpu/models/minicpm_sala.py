@@ -200,6 +200,8 @@ class MiniCPMSALAForCausalLM(Layer):
             seen = call.pos[:, 0] + 1
             ids, toks = decode_lists(call.tables, seen, chosen[:, 0],
                                      sparse[:, 0], sz)
+            # a row that is not live attends nothing: nobody reads it
+            toks = jnp.where(live, toks, 0)
             o = grouped_paged_decode(q[:, 0], k_pool, v_pool, ids, toks,
                                      scale)[:, None]
             sp, lv = sparse[:, 0], live[:, 0]

@@ -349,8 +349,10 @@ class PanguMoEForCausalLM(Layer):
                     def attend(q_lat, lat, pool=pool):
                         pool = latent_cache_write(pool, lat, tables, ln)
                         new_pools.append((pool,))
+                        # a done row attends nothing: nobody reads it
                         return latent_paged_decode(
-                            q_lat[:, 0], pool, tables, ln + 1, rank=rank,
+                            q_lat[:, 0], pool, tables,
+                            jnp.where(dn, 0, ln + 1), rank=rank,
                             scale=scale)[:, None]
                     x, s_i = self._block(p, i, x, pos, attend, ~dn[:, None])
                     stats = stats + s_i

@@ -12,24 +12,30 @@ CPU/tier-1 path). The kernels here walk the block table instead.
 The decode kernel (`paged_attention_kernel`, one query token a row) walks
 each row's own pages, several a step (`_kernel_walk`; ISSUE 27):
 
-  grid (B,)      one program per batch row, in order. The pools stay in
-                 HBM (`pl.ANY`); the block table and `lens` are scalar-
-                 prefetched.
+  one program    the whole batch: q and the output lie whole in VMEM
+                 (4 KiB a row), the pools stay in HBM (`pl.ANY`), the
+                 block table and `lens` are scalar-prefetched. A loop
+                 walks the rows that attend anything, in order
+                 (`_PageWalk`); a row of lens 0 (a slot without a
+                 request, a row past its EOS: the models hand a done row
+                 0) is stepped over by a scalar compare: no DMA, no
+                 product, zeros out (0.03 us a row on the chip, PERF.md
+                 section 6, PR 35).
   blocks         a row of `lens` tokens costs cdiv(lens, pages_per_step
                  * bs) trips of a `fori_loop`, whatever the table's
-                 width; a dummy row (lens 1) costs one, a row of lens 0
-                 none. `_pages_per_step` takes the step from the shapes:
+                 width. `_pages_per_step` takes the step from the shapes:
                  what `_WALK_VMEM_BUDGET` holds of (K, V) x two slots (8
                  pages = 128 tokens = 1 MiB a step for bf16 pages of
                  16 x 16 x 128).
   fetch          one `make_async_copy` per LIVE page of the block into a
                  double-buffered VMEM slot; the row's next block, or the
-                 next row's first, is in flight while this one is
-                 computed. Table slots past a row's last page are never
-                 read: nothing past `lens` reaches the output (padding
-                 slots may point anywhere, page 0 may hold NaN). What a
-                 slot holds past the block's live pages is zeros or an
-                 earlier row's own page, and meets a zero probability.
+                 first of the next row that attends anything, is in
+                 flight while this one is computed. Table slots past a
+                 row's last page are never read: nothing past `lens`
+                 reaches the output (padding slots may point anywhere,
+                 page 0 may hold NaN). What a slot holds past the block's
+                 live pages is zeros or an earlier row's own page, and
+                 meets a zero probability.
   compute        the block as a [T*nh, hd] matrix (row = (token, head)),
                  straight from the slot in the pools' dtype: scores are
                  q [nh, hd] x block^T -> [nh, T*nh] on the MXU, of which
@@ -486,105 +492,166 @@ def _sum_rows(y, r):
     return sum(y[i:i + r] for i in range(0, y.shape[0], r))
 
 
+class _PageWalk:
+    """The fetch side of a decode walk, shared by the kernels that walk a
+    list of pages a row (`_kernel_walk`, `_kernel_grouped_walk`, the
+    latent kernel): blocks of `pps` pages into one of two VMEM slots, the
+    row's next block, or the next LIVE row's first, in flight while this
+    one is computed. A row of no tokens is not part of the walk: it costs
+    no DMA and no product, and the row before it looks past it.
+
+    `toks_ref` [rows] the tokens a row attends (scalar-prefetched),
+    `width` the slots of a row's list, `copies(row, j, slot, i)` the
+    async copies that bring list entry `j` of `row` to place `i` of
+    `slot`."""
+
+    def __init__(self, toks_ref, width, *, bs, pps, copies):
+        self.toks_ref, self.n_rows = toks_ref, toks_ref.shape[0]
+        self.width, self.bs, self.pps, self.copies = width, bs, pps, copies
+
+    @staticmethod
+    def cdiv(a, d):                   # i32 throughout (Mosaic x64 rule)
+        return lax.div(a + (d - 1), jnp.int32(d))
+
+    def _row(self, row):              # a row number that can be read
+        return jnp.minimum(jnp.asarray(row, jnp.int32), self.n_rows - 1)
+
+    def tokens_of(self, row):
+        return jnp.minimum(self.toks_ref[self._row(row)],
+                           self.width * self.bs)
+
+    def pages_of(self, row):
+        return self.cdiv(self.tokens_of(row), self.bs)
+
+    def next_live(self, row):
+        """The first row from `row` on that attends anything; `n_rows`
+        where none does. A handful of scalar operations a row passed."""
+        return lax.while_loop(
+            lambda r: (r < self.n_rows) & (self.toks_ref[self._row(r)] <= 0),
+            lambda r: r + 1, jnp.asarray(row, jnp.int32))
+
+    def _dmas(self, op, row, blk, slot, n_pages):
+        """"start" or "wait" the copies of block `blk` of `row`: its live
+        pages only."""
+        slot = jnp.asarray(slot, jnp.int32)         # (Mosaic x64 rule)
+        for i in range(self.pps):
+            @pl.when(blk * self.pps + i < n_pages)
+            def _():
+                for copy in self.copies(row, blk * self.pps + i, slot,
+                                        jnp.int32(i)):
+                    getattr(copy, op)()
+
+    def start_first(self, row, slot):
+        """Start the first block of `row` (no row: nothing) into `slot`."""
+        @pl.when(row < self.n_rows)
+        def _():
+            self._dmas("start", self._row(row), 0, slot, self.pages_of(row))
+
+    def blocks(self, row, nxt, slot0, step, init):
+        """Walk live `row`, whose first block is in flight into `slot0`:
+        `step(blk, slot, carry)` sees block `blk` landed in `slot`. Its
+        last block starts the first of `nxt`, the next live row. Returns
+        (the last carry, the slot `nxt` finds its first block in)."""
+        n_pages = self.pages_of(row)
+        n_blocks = self.cdiv(n_pages, self.pps)
+
+        def body(blk, carry):
+            slot = lax.rem(slot0 + blk, jnp.int32(2))
+
+            @pl.when(blk + 1 < n_blocks)
+            def _():
+                self._dmas("start", row, blk + 1, 1 - slot, n_pages)
+
+            @pl.when(blk + 1 == n_blocks)
+            def _():
+                self.start_first(nxt, 1 - slot)
+
+            self._dmas("wait", row, blk, slot, n_pages)
+            return step(blk, slot, carry)
+
+        carry = lax.fori_loop(jnp.int32(0), n_blocks, body, init)
+        return carry, lax.rem(slot0 + n_blocks, jnp.int32(2))
+
+    def live_rows(self, attend):
+        """One program over the whole batch: `attend(row, nxt, slot0)`
+        walks a live row (through `blocks`) and returns the next one's
+        slot. Rows without tokens are stepped over."""
+        first = self.next_live(0)
+        self.start_first(first, 0)
+
+        def body(carry):
+            row, slot0 = carry
+            nxt = self.next_live(row + 1)
+            return nxt, attend(row, nxt, slot0)
+
+        lax.while_loop(lambda c: c[0] < self.n_rows, body,
+                       (first, jnp.int32(0)))
+
+
+def _kv_copies(pools, bufs, sems, page, slot, i):
+    """The copies of one page (`page` indexes the pools' leading axes) of
+    K and of V to place `i` of `slot`: a semaphore each a slot."""
+    return [pltpu.make_async_copy(pool.at[page], buf.at[slot, i],
+                                  sems.at[jnp.int32(n), slot])
+            for n, (pool, buf) in enumerate(zip(pools, bufs))]
+
+
 def _kernel_walk(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
-                 k_buf, v_buf, sems, slot_ref, lane_ref, *, scale, bs, pps):
-    b, n_rows = pl.program_id(0), pl.num_programs(0)
-    mb = tables_ref.shape[1]
+                 k_buf, v_buf, sems, lane_ref, *, scale, bs, pps):
     nh, hd = q_ref.shape[1], q_ref.shape[2]
     nkv = k_buf.shape[3]                            # KV heads of a page
     t = pps * bs                                    # tokens a block holds
     w = t * nkv                                     # its (token, head) rows
 
-    def cdiv(a, d):                   # i32 throughout (Mosaic x64 rule)
-        return lax.div(a + (d - 1), jnp.int32(d))
+    def copies(row, j, slot, i):
+        return _kv_copies((k_hbm, v_hbm), (k_buf, v_buf), sems,
+                          tables_ref[row, j], slot, i)
 
-    def pages_of(row):
-        return jnp.minimum(cdiv(lens_ref[row], bs), mb)
+    walk = _PageWalk(lens_ref, tables_ref.shape[1], bs=bs, pps=pps,
+                     copies=copies)
+    # row h of a score tile keeps the lanes of its own head: lane j is
+    # (token j // nh, head j % nh) of the block. Kept for every row as the
+    # lane's number where the head is the row's, else past every length:
+    # one compare a block then masks head and length
+    lane = lax.broadcasted_iota(jnp.int32, (nh, w), 1)
+    head = lax.broadcasted_iota(jnp.int32, (nh, w), 0)
+    if nh != nkv:           # grouped: row h keeps the lanes of KV head h // G
+        head = lax.div(head, jnp.int32(nh // nkv))
+    lane_ref[...] = jnp.where(lax.rem(lane, jnp.int32(nkv)) == head,
+                              lane, jnp.int32(jnp.iinfo(jnp.int32).max))
+    # a block's pages past its row's last are not fetched, and what a slot
+    # holds there meets a probability of exactly 0: so it has to be
+    # finite. Zero once; after that it is some row's own page
+    v_buf[...] = jnp.zeros_like(v_buf)
+    o_ref[...] = jnp.zeros_like(o_ref)              # rows of no tokens
 
-    def block_dmas(op, row, blk, slot, n_pages):
-        """"start" or "wait" the copies of block `blk` of `row`: its live
-        pages only, K and V on one semaphore each per slot."""
-        slot = jnp.asarray(slot, jnp.int32)         # (Mosaic x64 rule)
-        for i in range(pps):
-            @pl.when(blk * pps + i < n_pages)
-            def _():
-                page = tables_ref[row, blk * pps + i]
-                for j, (pool, buf) in enumerate(((k_hbm, k_buf),
-                                                 (v_hbm, v_buf))):
-                    getattr(pltpu.make_async_copy(
-                        pool.at[page], buf.at[slot, jnp.int32(i)],
-                        sems.at[jnp.int32(j), slot]), op)()
+    def attend(b, nxt, slot0):
+        ln = walk.tokens_of(b)
+        q = _mxu_rows(q_ref[b], k_buf.dtype)        # [nh or 2 nh, hd]
 
-    def fetch_first_of_next_row(slot):
-        nxt = jnp.minimum(b + 1, n_rows - 1)
-        @pl.when(b + 1 < n_rows)
-        def _():
-            block_dmas("start", nxt, 0, slot, pages_of(nxt))
+        def step(blk, slot, carry):
+            m_prev, l_prev, acc = carry
+            k = k_buf[slot].reshape(w, hd)
+            v = v_buf[slot].reshape(w, hd)
+            s = _sum_rows(_block_dot(q, k, ((1,), (1,))), nh) * scale
+            keep = lane_ref[...] < (ln - blk * t) * nkv         # [nh, w]
+            s = jnp.where(keep, s, jnp.asarray(_NEG, s.dtype))
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)              # exactly 0 off `keep`
+            corr = jnp.exp(m_prev - m_new)
+            l_new = corr * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            pv = _block_dot(_mxu_rows(p, v.dtype), v, ((1,), (0,)))
+            return m_new, l_new, corr * acc + _sum_rows(pv, nh)
 
-    n_pages = pages_of(b)
-    n_blocks = cdiv(n_pages, pps)
-    ln = jnp.minimum(lens_ref[b], mb * bs)
+        (_, l, acc), slot = walk.blocks(
+            b, nxt, slot0, step,
+            (jnp.full((nh, 1), _NEG, jnp.float32),
+             jnp.zeros((nh, 1), jnp.float32),
+             jnp.zeros((nh, hd), jnp.float32)))
+        o_ref[b] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        return slot
 
-    @pl.when(b == 0)
-    def _():
-        # row h of a score tile keeps the lanes of its own head: lane j is
-        # (token j // nh, head j % nh) of the block. Kept for every row
-        # as the lane's number where the head is the row's, else past
-        # every length: one compare a block then masks head and length
-        lane = lax.broadcasted_iota(jnp.int32, (nh, w), 1)
-        head = lax.broadcasted_iota(jnp.int32, (nh, w), 0)
-        if nh != nkv:       # grouped: row h keeps the lanes of KV head h // G
-            head = lax.div(head, jnp.int32(nh // nkv))
-        lane_ref[...] = jnp.where(lax.rem(lane, jnp.int32(nkv)) == head,
-                                  lane, jnp.int32(jnp.iinfo(jnp.int32).max))
-        # a block's pages past its row's last are not fetched, and what a
-        # slot holds there meets a probability of exactly 0: so it has to
-        # be finite. Zero once; after that it is some row's own page
-        v_buf[...] = jnp.zeros_like(v_buf)
-        slot_ref[0] = 0
-        block_dmas("start", 0, 0, 0, n_pages)   # nobody fetched ahead
-
-    slot0 = slot_ref[0]
-    q = _mxu_rows(q_ref[b], k_buf.dtype)            # [nh or 2 nh, hd]
-
-    def body(blk, carry):
-        m_prev, l_prev, acc = carry
-        slot = lax.rem(slot0 + blk, jnp.int32(2))
-
-        @pl.when(blk + 1 < n_blocks)
-        def _():
-            block_dmas("start", b, blk + 1, 1 - slot, n_pages)
-
-        @pl.when(blk + 1 == n_blocks)
-        def _():
-            fetch_first_of_next_row(1 - slot)
-
-        block_dmas("wait", b, blk, slot, n_pages)
-
-        k = k_buf[slot].reshape(w, hd)
-        v = v_buf[slot].reshape(w, hd)
-        s = _sum_rows(_block_dot(q, k, ((1,), (1,))), nh) * scale  # [nh, w]
-        keep = lane_ref[...] < (ln - blk * t) * nkv
-        s = jnp.where(keep, s, jnp.asarray(_NEG, s.dtype))
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)                  # exactly 0 off `keep`
-        corr = jnp.exp(m_prev - m_new)
-        l_new = corr * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        pv = _block_dot(_mxu_rows(p, v.dtype), v, ((1,), (0,)))
-        return m_new, l_new, corr * acc + _sum_rows(pv, nh)
-
-    _, l, acc = lax.fori_loop(
-        jnp.int32(0), n_blocks, body,
-        (jnp.full((nh, 1), _NEG, jnp.float32),
-         jnp.zeros((nh, 1), jnp.float32),
-         jnp.zeros((nh, hd), jnp.float32)))
-    o_ref[b] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-
-    @pl.when(n_blocks == 0)
-    def _():                          # an empty row fetches ahead too
-        fetch_first_of_next_row(slot0)
-
-    slot_ref[0] = lax.rem(slot0 + n_blocks, jnp.int32(2))
+    walk.live_rows(attend)
 
 
 def _kv_heads(nh, k_pool):
@@ -627,24 +694,22 @@ def paged_attention_kernel(q, k_pool, v_pool, tables, lens, *, scale=None,
 
     if _pages_dma_sliceable(nkv, hd):
         pps = _pages_per_step(bs * nkv * hd * k_pool.dtype.itemsize, mb)
-        # q and the output whole, once: 4 KiB a row is not worth a DMA
-        # and a wait in every program
+        # one program: q and the output whole (4 KiB a row), the rows
+        # that attend anything walked in order inside it
         rows = pl.BlockSpec((b, nh, hd),
-                            lambda bi, tables, lens: (_i0(), _i0(), _i0()))
+                            lambda i, tables, lens: (_i0(), _i0(), _i0()))
         pool = pl.BlockSpec(memory_space=pl.ANY)
         kernel = functools.partial(_kernel_walk, scale=scale, bs=bs, pps=pps)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b,),
+            grid=(1,),
             in_specs=[rows, pool, pool],
             out_specs=rows,
             scratch_shapes=[pltpu.VMEM((2, pps, bs, nkv, hd), k_pool.dtype),
                             pltpu.VMEM((2, pps, bs, nkv, hd), v_pool.dtype),
                             pltpu.SemaphoreType.DMA((2, 2)),
-                            pltpu.SMEM((1,), jnp.int32),
                             pltpu.VMEM((nh, pps * bs * nkv), jnp.int32)],
         )
-        # rows in order: each fetches the next one's first block
         semantics = ("arbitrary",)
     else:
         kernel = functools.partial(_kernel_slots, scale=scale, nh=nh, bs=bs,
@@ -681,9 +746,10 @@ def paged_attention_kernel(q, k_pool, v_pool, tables, lens, *, scale=None,
 # are [NB, Hkv, bs, D]: a KV head's page is a [bs, D] tile of its own, a
 # DMA slices it whole, and the G query heads of the group are the rows of
 # ONE product against it (no lane of a score tile belongs to another
-# head, so nothing of `_kernel_walk`'s head mask is needed). A program is
-# a (row, KV head) pair, in order, each fetching the next one's first
-# block; only a list's last page may be partly filled.
+# head, so nothing of `_kernel_walk`'s head mask is needed). A row of the
+# walk is a (row, KV head) pair; one program walks those that attend
+# anything, in order, each fetching the next one's first block; only a
+# list's last page may be partly filled.
 
 GROUPED_DECODE_NAME = "pallas_paged_grouped_decode"
 
@@ -695,92 +761,49 @@ def grouped_pages_dma_sliceable(bs, hd, dtype):
 
 
 def _kernel_grouped_walk(ids_ref, toks_ref, q_ref, k_hbm, v_hbm, o_ref,
-                         k_buf, v_buf, sems, slot_ref, *, scale, bs, pps,
-                         hkv):
-    r, n_rows = pl.program_id(0), pl.num_programs(0)
-    w = ids_ref.shape[1]
+                         k_buf, v_buf, sems, *, scale, bs, pps, hkv):
     g, hd = q_ref.shape[1], q_ref.shape[2]
     t = pps * bs
 
-    def cdiv(a, d):
-        return lax.div(a + (d - 1), jnp.int32(d))
+    def copies(row, j, slot, i):
+        return _kv_copies((k_hbm, v_hbm), (k_buf, v_buf), sems,
+                          (ids_ref[row, j], lax.rem(row, jnp.int32(hkv))),
+                          slot, i)
 
-    def pages_of(row):
-        return jnp.minimum(cdiv(toks_ref[row], bs), w)
-
-    def block_dmas(op, row, blk, slot, n_pages):
-        slot = jnp.asarray(slot, jnp.int32)
-        row = jnp.asarray(row, jnp.int32)
-        head = lax.rem(row, jnp.int32(hkv))
-        for i in range(pps):
-            @pl.when(blk * pps + i < n_pages)
-            def _():
-                page = ids_ref[row, blk * pps + i]
-                for j, (pool, buf) in enumerate(((k_hbm, k_buf),
-                                                 (v_hbm, v_buf))):
-                    getattr(pltpu.make_async_copy(
-                        pool.at[page, head], buf.at[slot, jnp.int32(i)],
-                        sems.at[jnp.int32(j), slot]), op)()
-
-    def fetch_first_of_next_row(slot):
-        nxt = jnp.minimum(r + 1, n_rows - 1)
-        @pl.when(r + 1 < n_rows)
-        def _():
-            block_dmas("start", nxt, 0, slot, pages_of(nxt))
-
-    n_pages = pages_of(r)
-    n_blocks = cdiv(n_pages, pps)
-    ln = jnp.minimum(toks_ref[r], w * bs)
-
-    @pl.when(r == 0)
-    def _():
-        v_buf[...] = jnp.zeros_like(v_buf)      # see `_kernel_walk`
-        slot_ref[0] = 0
-        block_dmas("start", 0, 0, 0, n_pages)
-
-    slot0 = slot_ref[0]
-    q = _mxu_rows(q_ref[r], k_buf.dtype)            # [g or 2 g, hd]
+    walk = _PageWalk(toks_ref, ids_ref.shape[1], bs=bs, pps=pps,
+                     copies=copies)
+    v_buf[...] = jnp.zeros_like(v_buf)          # see `_kernel_walk`
+    o_ref[...] = jnp.zeros_like(o_ref)
     col = lax.broadcasted_iota(jnp.int32, (g, t), 1)
 
-    def body(blk, carry):
-        m_prev, l_prev, acc = carry
-        slot = lax.rem(slot0 + blk, jnp.int32(2))
+    def attend(r, nxt, slot0):
+        ln = walk.tokens_of(r)
+        q = _mxu_rows(q_ref[r], k_buf.dtype)        # [g or 2 g, hd]
 
-        @pl.when(blk + 1 < n_blocks)
-        def _():
-            block_dmas("start", r, blk + 1, 1 - slot, n_pages)
+        def step(blk, slot, carry):
+            m_prev, l_prev, acc = carry
+            k = k_buf[slot].reshape(t, hd)
+            v = v_buf[slot].reshape(t, hd)
+            s = _sum_rows(_block_dot(q, k, ((1,), (1,))), g) * scale
+            keep = col < ln - blk * t                           # [g, t]
+            s = jnp.where(keep, s, jnp.asarray(_NEG, s.dtype))
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            p = jnp.where(keep, p, 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_new = corr * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            pv = _block_dot(_mxu_rows(p, v.dtype), v, ((1,), (0,)))
+            return m_new, l_new, corr * acc + _sum_rows(pv, g)
 
-        @pl.when(blk + 1 == n_blocks)
-        def _():
-            fetch_first_of_next_row(1 - slot)
+        (_, l, acc), slot = walk.blocks(
+            r, nxt, slot0, step,
+            (jnp.full((g, 1), _NEG, jnp.float32),
+             jnp.zeros((g, 1), jnp.float32),
+             jnp.zeros((g, hd), jnp.float32)))
+        o_ref[r] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        return slot
 
-        block_dmas("wait", r, blk, slot, n_pages)
-
-        k = k_buf[slot].reshape(t, hd)
-        v = v_buf[slot].reshape(t, hd)
-        s = _sum_rows(_block_dot(q, k, ((1,), (1,))), g) * scale    # [g, t]
-        keep = col < ln - blk * t
-        s = jnp.where(keep, s, jnp.asarray(_NEG, s.dtype))
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(keep, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = corr * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        pv = _block_dot(_mxu_rows(p, v.dtype), v, ((1,), (0,)))
-        return m_new, l_new, corr * acc + _sum_rows(pv, g)
-
-    _, l, acc = lax.fori_loop(
-        jnp.int32(0), n_blocks, body,
-        (jnp.full((g, 1), _NEG, jnp.float32),
-         jnp.zeros((g, 1), jnp.float32),
-         jnp.zeros((g, hd), jnp.float32)))
-    o_ref[r] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-
-    @pl.when(n_blocks == 0)
-    def _():
-        fetch_first_of_next_row(slot0)
-
-    slot_ref[0] = lax.rem(slot0 + n_blocks, jnp.int32(2))
+    walk.live_rows(attend)
 
 
 def grouped_paged_attention_kernel(q, k_pool, v_pool, ids, tokens, *,
@@ -797,17 +820,16 @@ def grouped_paged_attention_kernel(q, k_pool, v_pool, ids, tokens, *,
     rows = b * hkv
     pps = _pages_per_step(bs * hd * k_pool.dtype.itemsize, w)
     whole = pl.BlockSpec((rows, g, hd),
-                         lambda ri, ids, toks: (_i0(), _i0(), _i0()))
+                         lambda i, ids, toks: (_i0(), _i0(), _i0()))
     pool = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(rows,),
+        grid=(1,),
         in_specs=[whole, pool, pool],
         out_specs=whole,
         scratch_shapes=[pltpu.VMEM((2, pps, bs, hd), k_pool.dtype),
                         pltpu.VMEM((2, pps, bs, hd), v_pool.dtype),
-                        pltpu.SemaphoreType.DMA((2, 2)),
-                        pltpu.SMEM((1,), jnp.int32)],
+                        pltpu.SemaphoreType.DMA((2, 2))],
     )
     out = pl.pallas_call(
         functools.partial(_kernel_grouped_walk, scale=scale, bs=bs, pps=pps,

@@ -426,6 +426,119 @@ def test_paged_prefix_with_grouped_kv_heads(one_chip):
     _assert_kernel(text, pa.PREFIX_NAME)
 
 
+# ------------------------------------- rows that attend nothing (PR 35)
+def _attended(lens, done):
+    return jnp.where(done, 0, lens + 1)
+
+
+_IDLE_CALLS = {
+    # cell 2 / 4: B 32, a table of 128, 3,072 pages of 16 tokens, 16 x 128
+    "paged": (lambda q, k, v, t, l, d: pa.paged_attention_kernel(
+        q, k, v, t, _attended(l, d)),
+        [((32, 1, NH, HD), jnp.bfloat16)]
+        + [((3072, KV_BLOCK, NH, HD), jnp.bfloat16)] * 2
+        + [((32, 128), jnp.int32), ((32,), jnp.int32), ((32,), jnp.bool_)],
+        pa.DECODE_NAME),
+    # cell 5: B 128, 128 heads on a latent of 576, 40 pages of 128 tokens
+    "latent": (lambda q, p, t, l, d: la.latent_decode_kernel(
+        q, p, t, _attended(l, d), rank=512, scale=192 ** -0.5),
+        [((128, 128, 576), jnp.bfloat16), ((6144, 576, 128), jnp.bfloat16),
+         ((128, 40), jnp.int32), ((128,), jnp.int32), ((128,), jnp.bool_)],
+        la.LATENT_DECODE_NAME),
+    # cell 6: B 128 x 2 KV heads of 16 query heads, lists of 128 pages
+    "page_list": (lambda q, k, v, i, t, live: (
+        pa.grouped_paged_attention_kernel(
+            q, k, v, i, jnp.where(live[:, None], t, 0),
+            scale=128 ** -0.5)),
+        [((128, 2, 16, 128), jnp.bfloat16)]
+        + [((6144, 2, 64, 128), jnp.bfloat16)] * 2
+        + [((128, 2, 128), jnp.int32), ((128, 2), jnp.int32),
+           ((128,), jnp.bool_)],
+        pa.GROUPED_DECODE_NAME),
+}
+
+
+@pytest.mark.parametrize("kernel", list(_IDLE_CALLS))
+def test_decode_kernels_take_rows_of_length_zero(one_chip, kernel):
+    """The three walks at their cells' geometry, called as the models
+    call them: a done row's attended length is 0, computed in the
+    program from the chunk's own `done`. A row of no tokens is data: the
+    kernel steps over it in a loop the chip's compiler has to take."""
+    fn, shapes, name = _IDLE_CALLS[kernel]
+    _assert_kernel(_compile(fn, one_chip, *shapes), name)
+
+
+def test_decode_chunk_of_cell_2_holds_one_named_kernel_a_layer(
+        one_chip, monkeypatch):
+    """The compiled decode chunk at cell 2's engine geometry (two layers
+    of GPT-1.3B's widths; benchmarks/workloads/serve-gpt3-1.3b-chat.json)
+    holds one `pallas_paged_decode` a layer, and each is the instruction
+    `paged_attention_roofline.chat` looks for by its shapes
+    (benchmarks/metrics/, read here and never edited): done rows reach
+    the kernel through its `lens` operand, nothing was added to it."""
+    import json
+    import paddle_tpu as paddle
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+    from paddle_tpu.nn import initializer
+    from benchmarks import trace
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmarks/metrics/paged_attention_roofline.chat.json")) \
+            as f:
+        spec = json.load(f)
+    with open(os.path.join(
+            root, "benchmarks/workloads/serve-gpt3-1.3b-chat.json")) as f:
+        eng = json.load(f)["engine"]
+    b, nb, bs, chunk = (eng["max_batch"], eng["kv_blocks"], eng["kv_block"],
+                        eng["decode_chunk"])
+    mb = -(-(eng["prompt_cap"] + eng["max_new_tokens"]) // bs)
+    layers = 2
+    paddle.seed(0)
+    with initializer.fast_init():
+        model = GPTForCausalLM(GPTConfig(
+            vocab_size=512, hidden_size=HIDDEN, num_layers=layers,
+            num_heads=NH, max_position_embeddings=2048,
+            intermediate_size=4 * HIDDEN))
+    model.to(dtype="bfloat16")
+    model.eval()
+
+    class Captured(Exception):
+        pass
+
+    def grab(sig, build):               # the chunk's program, not run
+        def take(*args):
+            raise Captured(build(), args)
+        return take
+
+    monkeypatch.setattr(model, "_gen_cache_get", grab)
+    monkeypatch.setenv("PADDLE_TPU_PAGED", "1")     # the gate sees the CPU
+    pool = jax.ShapeDtypeStruct((nb, bs, NH, HD), jnp.bfloat16)
+    with pytest.raises(Captured) as got:
+        model.decode_paged([(pool, pool)] * layers,
+                           jnp.zeros((b, mb), jnp.int32),
+                           jnp.zeros((b,), jnp.int32),
+                           jnp.zeros((b,), jnp.int32),
+                           jnp.ones((b,), bool), chunk)
+    fn, args = got.value.args
+    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), args)
+    text = fn.lower(*args).compile().as_text()
+    shapes = dict(re.findall(r"(%\S+) = (\w+\[[\d,]*\])", text))
+    calls = [l for l in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l
+             and re.search(r"[%_]" + pa.DECODE_NAME + r"_*\.",
+                           l.split(" = ", 1)[0])]
+    assert len(calls) == layers, calls
+    patterns = [[part.format(MB=b, KB=nb, BS=bs, NH=NH, D=HD) for part in p]
+                for p in spec["patterns"]]
+    for call in calls:
+        # as the device names the event: operands with their types
+        typed = re.sub(r"%[\w.\-]+", lambda m: shapes.get(m.group(0), "")
+                       + " " + m.group(0), call.split(" = ", 1)[1])
+        assert trace.matches("%k = " + typed, patterns), typed
+
+
 # -------------------------------------------- the engine's own device programs
 @pytest.mark.parametrize("b,chunk", [(32, 8), (128, 8)],
                          ids=["gpt-cells", "pangu-cell"])

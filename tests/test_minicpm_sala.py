@@ -25,6 +25,7 @@ from paddle_tpu.ops.pallas import paged_attention as pa
 from benchmarks import reference_minicpm_sala as R
 from benchmarks import weights_minicpm_sala as W
 from benchmarks.runners import serve_minicpm_sala as runner
+from tools.validate_paged_tpu import idle_mixes
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SEED = 3
@@ -390,6 +391,39 @@ def test_the_page_list_kernel_against_the_gather(tokens):
     live = np.asarray(toks) > 0
     assert float(jnp.abs(got - want)[live].max()) < 1e-5
     assert float(jnp.abs(got)[~live].sum()) == 0.0
+
+
+@pytest.mark.parametrize("mix", list(idle_mixes(8)))
+def test_page_lists_of_no_tokens_among_live_rows(mix):
+    """Rows that attend nothing (`decode_lists`' tokens zeroed where a row
+    is not live) among live rows, both KV heads of a row alike: live rows
+    bit-equal to the same call without them, the others zeros, nothing
+    read of the NaN page 0."""
+    rng = np.random.default_rng(2)
+    b, hkv, g, d, bs, nb, w = 8, 2, 4, 16, 8, 60, 40
+    live = list(idle_mixes(b)[mix])
+    toks = np.zeros(b, np.int32)
+    toks[live] = (300, 1, 128, 5, 129, 17, 320)[:len(live)]  # blocks of 16
+    q = jnp.asarray(rng.normal(size=(b, hkv, g, d)), jnp.float32)
+    kp, vp = (jnp.asarray(rng.normal(size=(nb, hkv, bs, d)),
+                          jnp.float32).at[0].set(jnp.nan) for _ in range(2))
+    ids = rng.integers(1, nb, (b, hkv, w))
+    ids[toks == 0] = 0
+    ids = jnp.asarray(ids, jnp.int32)
+    toks = jnp.broadcast_to(jnp.asarray(toks)[:, None], (b, hkv))
+    call = lambda q, i, t: np.asarray(  # noqa: E731
+        pa.grouped_paged_attention_kernel(q, kp, vp, i, t, scale=0.25,
+                                          interpret=True))
+    got = call(q, ids, toks)
+    idle = np.setdiff1d(np.arange(b), live)
+    assert (got[idle] == 0).all() and np.isfinite(got).all()
+    if live:
+        rows = jnp.asarray(live)
+        assert (got[live] == call(q[rows], ids[rows], toks[rows])).all()
+        want = SA.grouped_paged_decode_reference(
+            q[rows], jnp.nan_to_num(kp), jnp.nan_to_num(vp), ids[rows],
+            toks[rows], 0.25)
+        assert float(jnp.abs(got[live] - want).max()) < 1e-5
 
 
 def _gqa_case(seed=0, b=3, nh=4, nkv=2, hd=16, bs=4, nb=24, mb=5, s=1):

@@ -25,10 +25,11 @@ from paddle_tpu.models import GPTConfig, GPTForCausalLM
 from paddle_tpu.ops.attention import (attention_reference,
                                       paged_attention_reference,
                                       paged_cache_write,
-                                      paged_prefill_write)
+                                      paged_prefill_write, quantize_kv)
 from paddle_tpu.ops.pallas import paged_attention as pa
 from paddle_tpu.ops.pallas.paged_attention import paged_attention_kernel
-from tools.validate_paged_tpu import ragged_case, ragged_cases
+from tools.validate_paged_tpu import (idle_mixes, ragged_case,
+                                      ragged_cases)
 
 
 # ------------------------------------------------------ block allocator
@@ -205,6 +206,51 @@ def test_paged_walk_interpret_matches_reference(monkeypatch, table, name,
     tol = 2e-5 if dtype == "float32" else 1e-2      # one bf16 rounding
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want), rtol=tol, atol=tol / 4)
+
+
+# Rows that attend nothing (length 0: a slot without a request, a slot in
+# prefill, a row past its EOS) among rows that do. The walk steps over
+# them inside its one program; the slot-grid kernels mask by length.
+_IDLE_LIVE_LENS = (70, 1, 64, 5, 65, 17, 84)    # blocks of 64 in a table of 84
+
+
+@pytest.mark.parametrize("mix", list(idle_mixes(8)))
+@pytest.mark.parametrize("kernel", ["walk", "slots", "q8"])
+def test_rows_of_length_zero_among_live_rows(monkeypatch, kernel, mix):
+    """Live rows are bit-equal to the same call without the empty rows,
+    whose output is zeros; page 0, where an empty row's table points, and
+    every page no row owns hold NaN."""
+    if kernel == "walk":
+        monkeypatch.setattr(pa, "_pages_dma_sliceable", lambda nh, hd: True)
+    live = list(idle_mixes(8)[mix])
+    lens = np.zeros(8, np.int64)
+    lens[live] = _IDLE_LIVE_LENS[:len(live)]
+    q, kp, vp, tables, la = ragged_case(tuple(lens), bs=4, nh=2, hd=8, mb=21,
+                                        dtype="float32")
+    if kernel == "q8":
+        (kc, ks), (vc, vs) = (quantize_kv(jnp.nan_to_num(p))
+                              for p in (kp, vp))
+        owned = np.unique(np.asarray(tables))
+        nobody = np.setdiff1d(np.arange(kp.shape[0]), owned[owned > 0])
+        ks, vs = (s.at[nobody].set(jnp.nan) for s in (ks, vs))
+        call = lambda q, t, l: pa.paged_attention_q8_kernel(  # noqa: E731
+            q, kc, ks, vc, vs, t, l, interpret=True)
+    else:
+        call = lambda q, t, l: paged_attention_kernel(  # noqa: E731
+            q, kp, vp, t, l, interpret=True)
+    got = np.asarray(call(q, tables, la))
+    idle = np.setdiff1d(np.arange(8), live)
+    assert (got[idle] == 0).all()
+    assert np.isfinite(got).all()
+    if live:
+        rows = jnp.asarray(live)
+        alone = np.asarray(call(q[rows], tables[rows], la[rows]))
+        assert (got[live] == alone).all()
+        want = paged_attention_reference(
+            q[rows], jnp.nan_to_num(kp), jnp.nan_to_num(vp), tables[rows],
+            la[rows])
+        np.testing.assert_allclose(alone, np.asarray(want),
+                                   atol=2e-2 if kernel == "q8" else 5e-6)
 
 
 def test_paged_walk_only_where_a_dma_can_slice_a_page():

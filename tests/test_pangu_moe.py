@@ -21,6 +21,7 @@ from paddle_tpu.ops.pallas import latent_attention as LK
 from benchmarks import reference_pangu_moe as R
 from benchmarks import weights_pangu_moe as W
 from benchmarks.runners import serve_pangu_moe as runner
+from tools.validate_paged_tpu import idle_mixes
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SEED = 3
@@ -346,6 +347,38 @@ def test_latent_decode_kernel_in_interpret_mode(monkeypatch, lens,
     xla = LA.latent_paged_attention(q[:, None], pool, tables, lens - 1,
                                     rank=rank, scale=0.3)[:, 0]
     assert float(jnp.abs(xla - want).max()) < 1e-6
+
+
+@pytest.mark.parametrize("mix", list(idle_mixes(8)))
+def test_latent_rows_of_length_zero_among_live_rows(monkeypatch, mix):
+    """Rows that attend nothing (a slot without a request, a row past its
+    EOS) among live rows: the live rows are bit-equal to the same call
+    without them, the empty rows are zeros, and nothing is read of the
+    NaN page their tables point at."""
+    monkeypatch.setattr(LK, "_TOKENS_PER_STEP", 16)     # blocks of 2 pages
+    rng = np.random.default_rng(1)
+    b, nh, w, rank, bs, mb, nb = 8, 4, 24, 16, 8, 6, 50
+    live = list(idle_mixes(b)[mix])
+    lens = np.zeros(b, np.int32)
+    lens[live] = (41, 1, 16, 5, 17, 33, 48)[:len(live)]
+    pool = jnp.asarray(rng.normal(size=(nb, w, bs)), jnp.float32)
+    pool = pool.at[0].set(jnp.nan)
+    q = jnp.asarray(rng.normal(size=(b, nh, w)), jnp.float32)
+    tables = rng.permutation(nb - 1)[:b * mb].reshape(b, mb) + 1
+    tables[lens == 0] = 0
+    tables, lens = jnp.asarray(tables, jnp.int32), jnp.asarray(lens)
+    call = lambda q, t, l: np.asarray(LK.latent_decode_kernel(  # noqa: E731
+        q, pool, t, l, rank=rank, scale=0.3, interpret=True))
+    got = call(q, tables, lens)
+    idle = np.setdiff1d(np.arange(b), live)
+    assert (got[idle] == 0).all() and np.isfinite(got).all()
+    if live:
+        rows = jnp.asarray(live)
+        assert (got[live] == call(q[rows], tables[rows], lens[rows])).all()
+        xla = LA.latent_paged_attention(
+            q[rows][:, None], jnp.nan_to_num(pool), tables[rows],
+            lens[rows] - 1, rank=rank, scale=0.3)[:, 0]
+        assert float(jnp.abs(got[live] - xla).max()) < 1e-6
 
 
 def test_prefill_attention_over_a_cached_prefix_and_the_window():

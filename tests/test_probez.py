@@ -126,7 +126,8 @@ def _user_slice(met):
     chunk counts and the occupancy gauges — those describe MACHINE
     state, which probe rows genuinely occupy."""
     from paddle_tpu.profiler._metrics import histogram_lines
-    machine = ("batches", "decode_chunks", "decode_chunks_overlapped")
+    machine = ("batches", "decode_chunks", "decode_chunks_overlapped",
+               "decode_rows_idle")
     counters = {k: v for k, v in met.counters.items() if k not in machine}
     hists = "\n".join(
         "\n".join(histogram_lines("u", name, met.hists[name], help_))

@@ -341,3 +341,146 @@ def test_other_engine_shapes_keep_their_tokens(served_model, mix, kw):
         np.testing.assert_array_equal(h.tokens[:n], want[:n])
     c = eng.metrics.counters
     assert c["decode_chunks_overlapped"] <= c["decode_chunks"]
+
+
+# ----------------------------------------------- rows that ride neutral
+# A slot without a request, a slot in prefill and a row past its EOS are
+# `done` rows of the chunk: they attend nothing (length 0), and what they
+# compute nobody reads. So a request's tokens cannot depend on how full
+# the batch around it is.
+
+def _toy_gpt():
+    paddle.seed(0)
+    m = GPTForCausalLM(GPTConfig(
+        vocab_size=96, hidden_size=32, num_layers=2, num_heads=4,
+        max_position_embeddings=64, intermediate_size=64))
+    m.eval()
+    kw = dict(prompt_cap=CAP, max_new_tokens=NEW, decode_chunk=CHUNK,
+              kv_block=KB, prefill_chunk=WINDOW)
+
+    def check(handles):
+        ids = np.zeros((len(handles), CAP), np.int64)
+        for i, h in enumerate(handles):
+            ids[i, :len(h.prompt)] = h.prompt
+        ref = m.generate_static_ragged(
+            paddle.to_tensor(ids), [len(h.prompt) for h in handles],
+            max_new_tokens=NEW).numpy()[:, CAP:]
+        for h, want in zip(handles, ref):
+            np.testing.assert_array_equal(h.tokens[:h.n_out],
+                                          want[:h.n_out])
+    return m, kw, 96, check
+
+
+def _toy_family(name):
+    """(model, engine settings, vocabulary, check of served handles
+    against the family's plain reference) at the toy size of the
+    family's own tests."""
+    import importlib
+    import json
+    import os
+    if name == "gpt":
+        return _toy_gpt()
+    runner = importlib.import_module(f"benchmarks.runners.serve_{name}")
+    ref = importlib.import_module(f"benchmarks.reference_{name}")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    toy = name.replace("_", "-")
+    with open(os.path.join(root, f"benchmarks/configs/toy-{toy}.json")) as f:
+        config = json.load(f)
+    cls = {"pangu_moe": "PanguMoEForCausalLM",
+           "minicpm_sala": "MiniCPMSALAForCausalLM"}[name]
+    m = getattr(importlib.import_module(f"paddle_tpu.models.{name}"), cls)(
+        runner.model_config(config))
+    runner.install_weights(m, config, 3)
+    m.eval()
+    kw = dict(prompt_cap=40, max_new_tokens=NEW, decode_chunk=CHUNK,
+              kv_block=8, kv_blocks=64, prefill_chunk=16)
+    if name == "minicpm_sala":
+        kw["state_snapshots"] = 4
+    logits = ref.logits if name == "pangu_moe" else ref.forward
+
+    def check(handles):
+        for h in handles:
+            toks = np.asarray(h.tokens)[:h.n_out]
+            seq = np.concatenate([np.asarray(h.prompt), toks])
+            lg = np.asarray(logits(config, 3, jax.numpy.asarray(
+                seq, jax.numpy.int32)))
+            at = len(h.prompt) - 1 + np.arange(len(toks))
+            assert float((lg[at].max(-1) - lg[at, toks]).max()) <= 1e-4
+    return m, kw, 256, check
+
+
+@pytest.mark.parametrize("family,executables", [
+    ("gpt", 4), ("pangu_moe", 4), ("minicpm_sala", 5)])
+def test_a_request_alone_in_a_wide_batch_serves_its_own_tokens(family,
+                                                               executables):
+    """One request in an engine of 8 slots, then the same request among
+    seven others: the same tokens, and the family's plain reference's.
+    Every chunk of the lone request carries 7 neutral rows
+    (`decode_rows_idle`); the engine builds the executables it always
+    built (a prefill window, a decode chunk, two helpers, and the move
+    of a state row where the model has state), a second engine none."""
+    m, kw, vocab, check = _toy_family(family)
+    rng = np.random.RandomState(11)
+    prompts = [rng.randint(1, vocab, (n,)).astype(np.int64)
+               for n in (13, 5, 16, 9, 3, 12, 8, 15)]
+    miss0 = compile_cache_misses()
+    alone = ServingEngine(m, ServingConfig(max_batch=8, prefix_cache=True,
+                                           **kw))
+    first = alone.submit(prompts[0], max_new_tokens=NEW)
+    alone.drain()
+    built = compile_cache_misses() - miss0
+    assert built == executables
+    c = alone.metrics.counters
+    assert first.status == "done" and first.n_out == NEW
+    assert c["decode_chunks"] == -(-(NEW - 1) // CHUNK)
+    assert c["decode_rows_idle"] == 7 * c["decode_chunks"]
+    assert alone.summary()["decode_rows_idle_total"] == c["decode_rows_idle"]
+    assert "paddle_tpu_serving_decode_rows_idle_total" in alone.metrics_text()
+
+    full = ServingEngine(m, ServingConfig(max_batch=8, prefix_cache=False,
+                                          **kw))
+    handles = [full.submit(p, max_new_tokens=NEW) for p in prompts]
+    full.drain()
+    assert compile_cache_misses() - miss0 == built
+    np.testing.assert_array_equal(handles[0].tokens[:NEW],
+                                  first.tokens[:NEW])
+    check([first] + handles)
+    c = full.metrics.counters
+    assert c["decode_rows_idle"] < 7 * c["decode_chunks"]
+
+
+def test_decode_rows_idle_counts_the_neutral_rows_of_every_chunk(
+        served_model, mix):
+    """A scripted order of arrivals, an EOS inside a chunk among them:
+    the counter is the sum over launched chunks of the rows handed to the
+    model as done before the chunk starts, whatever made them neutral
+    (no request, a prefill under way, the last tokens launched)."""
+    m, _ = served_model
+    reqs, eos = mix
+    eng = _engine(m, eos, max_batch=4)
+    neutral = []
+    real = m.decode_paged
+
+    def watching(pools, tables, lens, pending, done, *a, **kw):
+        neutral.append(int(np.asarray(lens == 0).sum()))
+        assert np.asarray(done)[np.asarray(lens) == 0].all()
+        return real(pools, tables, lens, pending, done, *a, **kw)
+
+    m.decode_paged = watching
+    try:
+        handles = []
+        for i, (p, b) in enumerate(reqs):       # two arrive every 2 steps
+            handles.append(eng.submit(p, max_new_tokens=b))
+            if i % 2:
+                eng.step()
+                eng.step()
+        eng.drain()
+    finally:
+        m.decode_paged = real
+    c = eng.metrics.counters
+    assert c["decode_chunks"] == len(neutral)
+    assert c["decode_rows_idle"] == sum(neutral) > 0
+    assert {0 < n < 4 for n in neutral} == {True}
+    for h, (p, b) in zip(handles, reqs):
+        np.testing.assert_array_equal(h.tokens[:h.n_out],
+                                      serial_reference(m, p, b, eos))

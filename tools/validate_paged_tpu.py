@@ -72,13 +72,23 @@ def kernel_parity(dtype, nh, hd, bs, tol):
 def ragged_cases(bs, pps, mb):
     """name -> lens: the edges of a walk that takes `pps` pages of `bs`
     tokens a step through a table of `mb` slots, and a batch mixing them
-    (dummy rows as the engine ships them: lens 1)."""
+    ("dummy": a row of one token; rows of none are `idle_mixes`)."""
     blk = pps * bs
     edges = {"dummy": 1, "one_page": bs, "one_block": blk,
              "block_plus_one": blk + 1, "whole_table": mb * bs}
     cases = {name: (ln, 1) for name, ln in edges.items()}
     cases["ragged"] = tuple(edges.values()) + (bs + 1, 1, blk - 1)
     return cases
+
+
+def idle_mixes(b=8):
+    """name -> the rows of a batch of `b` that hold a request; the others
+    attend nothing (length 0), as the engine ships a slot without a
+    request, a slot in prefill and a row past its EOS: the first row
+    empty, the last, runs of several, all but one, every row."""
+    return {"first": tuple(range(1, b)), "last": tuple(range(b - 1)),
+            "runs": (0, b // 2, b - 1), "all_but_one": (b - 3,),
+            "every": ()}
 
 
 def ragged_case(lens, *, bs, nh, hd, mb, dtype, nb=None, seed=0):
