@@ -650,8 +650,20 @@ class ServingMetrics:
                  "state_rows_updated": "decode row-steps of a recurrent "
                                        "layer that updated their state",
                  "attn_pairs": "(query, token) pairs attended a "
-                               "sparse-attention layer (prefill and "
-                               "decode)",
+                               "sparse or grouped attention layer over "
+                               "page lists (prefill and decode)",
+                 # state-space layers beside paged attention layers
+                 "ssm_rows_updated": "decode row-steps of a state-space "
+                                     "layer that moved their state",
+                 "ssm_tokens_scanned": "live prefill tokens a state-space "
+                                       "layer scanned",
+                 "ssm_windows_scanned": "prefill windows a state-space "
+                                        "layer scanned (a window and "
+                                        "layer)",
+                 "attn_pages_walked": "pages walked by the decode "
+                                      "row-steps of a paged attention "
+                                      "layer over page lists (all KV "
+                                      "heads)",
                  "state_snapshots_taken": "recurrent-state snapshots "
                                           "saved beside a cached prefix",
                  "state_snapshots_restored": "admissions that restored a "

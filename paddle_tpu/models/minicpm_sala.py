@@ -34,9 +34,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
-from ..core.tensor import Tensor, apply_op
+from ..core.tensor import apply_op
 from ..nn import initializer as I
 from ..nn.layer import Layer
 from ..nn.layers.experts import gated_mlp
@@ -45,8 +44,8 @@ from ..ops.sparse_attention import (SparseSizes, compressed_write,
                                     decode_lists, grouped_paged_decode,
                                     kv_cache_write, select_blocks,
                                     sparse_window_attention)
-from .decoder_parts import GatedMLP, _arr, _mm, _rms, _rope
-from .gpt import GPTForCausalLM, sample_logits
+from .decoder_parts import (GatedMLP, PagedStateDecoder, StepCall, _arr, _mm,
+                            _rms, _rope)
 
 SPARSE = "minicpm4"
 LIGHTNING = "lightning-attn"
@@ -129,26 +128,7 @@ class MiniCPMSALABlock(Layer):
         self.mlp = GatedMLP(h, c.intermediate_size, init, c.dtype)
 
 
-class _Call:
-    """What one traced call (a prefill window or a decode step) hands its
-    layers: where the tokens sit, the planes of each layer as they are
-    consumed and replaced, and the counters."""
-
-    def __init__(self, pools, tables, pos, lens, live, slots, width):
-        self.pools = list(pools)
-        self.tables, self.pos, self.lens = tables, pos, lens
-        self.live, self.slots, self.width = live, slots, width
-        self.stats = dict.fromkeys(STATS, jnp.float32(0))
-        self.chosen = []
-
-    def count(self, name, x):
-        self.stats[name] = self.stats[name] + jnp.sum(x).astype(jnp.float32)
-
-    def stats_array(self):
-        return jnp.stack([self.stats[k] for k in STATS])
-
-
-class MiniCPMSALAForCausalLM(Layer):
+class MiniCPMSALAForCausalLM(PagedStateDecoder):
     def __init__(self, config: MiniCPMSALAConfig):
         super().__init__()
         c = self.config = config
@@ -161,14 +141,12 @@ class MiniCPMSALAForCausalLM(Layer):
             self.add_sublayer(f"layers.{i}", blk)
         self.n_final = mk((c.hidden_size,), I.Constant(1.0))
         self.head = mk((c.vocab_size, c.hidden_size))
-        self._names = [n for n, _ in self.named_parameters()]
-        self._stats = np.zeros((len(STATS),), np.float32)
+        self._init_serving()
+
+    STATS = STATS
 
     # ------------------------------------------------ the pure functions
-    def _tree(self, arrays):
-        return dict(zip(self._names, arrays))
-
-    def _sparse_mixer(self, p, pre, h, call: _Call, i: int):
+    def _sparse_mixer(self, p, pre, h, call: StepCall, i: int):
         """h [B, S, H] (normed) -> the mixer's output [B, S, H]; writes the
         window's (or the step's) keys, values and compressed keys into the
         layer's pages first. S = 1 with no `lens` is a decode step."""
@@ -220,7 +198,7 @@ class MiniCPMSALAForCausalLM(Layer):
         return _mm(jax.nn.sigmoid(gate) * o.reshape(b, s, -1),
                    p[pre + "w_o"])
 
-    def _lightning_mixer(self, p, pre, h, call: _Call, i: int):
+    def _lightning_mixer(self, p, pre, h, call: StepCall, i: int):
         c = self.config
         b, s, _ = h.shape
         nh, d = c.lightning_heads, c.lightning_head_dim
@@ -242,7 +220,7 @@ class MiniCPMSALAForCausalLM(Layer):
         o = _rms((o * d ** -0.5).reshape(b, s, nh * d), p[pre + "n_out"], eps)
         return _mm(jax.nn.sigmoid(gate) * o, p[pre + "w_o"])
 
-    def _stream(self, p, ids, call: _Call):
+    def _stream(self, p, ids, call: StepCall):
         """The final residual stream [B, S, H] of the call's tokens."""
         c = self.config
         eps = c.rms_norm_eps
@@ -291,8 +269,8 @@ class MiniCPMSALAForCausalLM(Layer):
                              for shp in state for n in (b, 1))
                      for paged, state in zip(geo["layer_block_shapes"],
                                              geo["state_shapes"])]
-            call = _Call(pools, tables, pos, lens, pos < s,
-                         jnp.arange(b), mb * bs)
+            call = StepCall(STATS, pools, tables, pos, lens, pos < s,
+                            jnp.arange(b), mb * bs)
             x = self._stream(p, ids, call)
             return self._logits(p, x[:, :s]), call.chosen
         return fn
@@ -313,31 +291,11 @@ class MiniCPMSALAForCausalLM(Layer):
         return [(np.asarray(a), np.asarray(b)) for a, b in out]  # lint: allow(tracer-asarray)
 
     # ------------------------------------------------ the paged engine
-    _gen_cache_get = GPTForCausalLM._gen_cache_get
-
+    # (`prefill_paged`, `decode_paged`, the counters: PagedStateDecoder)
     def check_serving_config(self, cfg) -> None:
         """Refuses what this model does not implement, at engine build."""
-        bad = [why for cond, why in (
-            (cfg.spec_decode, "spec_decode=True (no verify_paged; a "
-                              "rejected draft would have to roll the "
-                              "recurrent state back)"),
-            ((cfg.shards or 1) > 1, "shards > 1 (neither the state planes "
-                                    "nor 2 KV heads are sharded)"),
-            (cfg.cache_dtype is not None, f"cache_dtype={cfg.cache_dtype!r} "
-                                          f"(pages are pooled in the model "
-                                          f"dtype, the state in float32)"),
-            (cfg.weight_dtype is not None,
-             f"weight_dtype={cfg.weight_dtype!r}"),
-            (cfg.spill_host_bytes is not None,
-             "spill_host_bytes (a spilled block's state snapshot is not "
-             "carried)"),
-            (cfg.prefill_chunk is not None
-             and cfg.prefill_chunk % cfg.kv_block != 0,
-             f"prefill_chunk={cfg.prefill_chunk} (a window must be whole "
-             f"pages of {cfg.kv_block})"),
-            (cfg.prefill_chunk is None and cfg.prompt_cap % cfg.kv_block != 0,
-             f"prompt_cap={cfg.prompt_cap} without prefill_chunk (a window "
-             f"must be whole pages of {cfg.kv_block})")) if cond]
+        bad = self._refusals(cfg, "neither the state planes nor 2 KV heads "
+                                  "are sharded")
         if bad:
             raise ValueError("MiniCPMSALAForCausalLM does not serve under "
                              + "; ".join(bad))
@@ -359,112 +317,3 @@ class MiniCPMSALAForCausalLM(Layer):
                                        for s in sparse],
                 "state_shapes": [() if s else (st,) for s in sparse],
                 "dtype": self.emb._data.dtype}
-
-    step_counter_names = STATS
-
-    def detach_step_counters(self):
-        """The counters of the prefill and decode calls made since the
-        last detach, as the device array the last of them returned."""
-        stats, self._stats = self._stats, \
-            np.zeros((len(STATS),), np.float32)
-        return stats
-
-    def prefill_paged(self, input_ids, prompt_lens, pools, block_tables,
-                      temperature: float = 0.0, top_k: int = 0,
-                      top_p: float = 1.0, seed: int = 0,
-                      weight_dtype: str = None, cache_dtype: str = None,
-                      start=None, state_slots=None):
-        """As GPTForCausalLM.prefill_paged: writes the window into the
-        rows' pages and returns (pools', first token [n]). `state_slots`
-        [n] names the row of the state planes each prompt advances: the
-        window starts from the state found there (zeroed or restored by
-        the caller) and leaves the state at its last live token."""
-        ids = _arr(input_ids)
-        b, p_cap = ids.shape
-        lens = _arr(prompt_lens, jnp.int32).reshape(b)
-        tables = _arr(block_tables, jnp.int32)
-        st = jnp.zeros((b,), jnp.int32) if start is None \
-            else _arr(start, jnp.int32)
-        if state_slots is None:
-            raise ValueError("prefill_paged needs state_slots: the rows of "
-                             "the state planes the prompts advance")
-        slots = _arr(state_slots, jnp.int32).reshape(b)
-
-        def run(arrays, pools, ids, lens, tables, st, slots, key, stats):
-            p = self._tree(arrays)
-            pos = st[:, None] + jnp.arange(p_cap, dtype=jnp.int32)[None]
-            live = jnp.arange(p_cap)[None] < lens[:, None]
-            call = _Call(pools, tables, pos, lens, live, slots, p_cap)
-            x = self._stream(p, ids, call)
-            last = self._logits(p, x[jnp.arange(b), lens - 1])
-            nxt = sample_logits(last, key, temperature=temperature,
-                                top_k=top_k, top_p=top_p).astype(jnp.int32)
-            return call.pools, nxt, stats + call.stats_array()
-
-        sig = ("sala_prefill", b, p_cap, _shapes(pools),
-               int(tables.shape[1]), float(temperature), int(top_k),
-               float(top_p))
-        fn = self._gen_cache_get(
-            sig, lambda: jax.jit(run, donate_argnums=(1,)))
-        pools2, nxt, self._stats = fn(
-            tuple(q._data for q in self.parameters()), pools, ids, lens,
-            tables, st, slots, jax.random.PRNGKey(seed), self._stats)
-        return pools2, Tensor(nxt)
-
-    def decode_paged(self, pools, block_tables, lens, pending, done,
-                     max_new_tokens: int, temperature: float = 0.0,
-                     top_k: int = 0, top_p: float = 1.0, seed: int = 0,
-                     eos_token_id: int = None, weight_dtype: str = None,
-                     cache_dtype: str = None):
-        """As GPTForCausalLM.decode_paged: one compiled chunk of
-        `max_new_tokens` steps over the whole slot batch (row b is row b
-        of the state planes); returns (tokens [B, n] int64, pools', lens',
-        done'). A done row neither selects nor moves its state."""
-        if max_new_tokens <= 0:
-            raise ValueError("decode_paged needs max_new_tokens >= 1")
-        tables, lens_a, pend = (_arr(block_tables, jnp.int32),
-                                _arr(lens, jnp.int32),
-                                _arr(pending, jnp.int32))
-        done_a = _arr(done, bool)
-
-        def run(arrays, pools, tables, lens_, pending_, done_, key0, stats0):
-            p = self._tree(arrays)
-
-            def body(carry, _):
-                pools, ln, cur, key, dn, stats = carry
-                call = _Call(pools, tables, ln[:, None], None,
-                             ~dn[:, None], None, 1)
-                x = self._stream(p, cur[:, None], call)
-                key, kk = jax.random.split(key)
-                new = sample_logits(self._logits(p, x[:, 0]), kk,
-                                    temperature=temperature, top_k=top_k,
-                                    top_p=top_p).astype(jnp.int32)
-                if eos_token_id is not None:
-                    new = jnp.where(dn, jnp.asarray(eos_token_id, new.dtype),
-                                    new)
-                    dn = dn | (new == eos_token_id)
-                return (call.pools, ln + 1, new, key, dn,
-                        stats + call.stats_array()), new
-
-            (pools, lens_, _, _, done_, stats), toks = lax.scan(
-                body, (list(pools), lens_, pending_, key0, done_, stats0),
-                None, length=max_new_tokens)
-            return (jnp.moveaxis(toks, 0, 1).astype(jnp.int64), pools,
-                    lens_, done_, stats)
-
-        sig = ("sala_decode", tables.shape, _shapes(pools),
-               int(max_new_tokens), float(temperature), int(top_k),
-               float(top_p),
-               None if eos_token_id is None else int(eos_token_id))
-        fn = self._gen_cache_get(
-            sig, lambda: jax.jit(run, donate_argnums=(1,)))
-        toks, pools2, lens2, done2, self._stats = fn(
-            tuple(q._data for q in self.parameters()), pools, tables,
-            lens_a, pend, done_a, jax.random.PRNGKey(seed), self._stats)
-        return Tensor(toks), pools2, lens2, done2
-
-
-def _shapes(pools) -> tuple:
-    """The planes' shapes and dtypes, for an executable's signature."""
-    return tuple(tuple((tuple(a.shape), str(a.dtype)) for a in layer)
-                 for layer in pools)

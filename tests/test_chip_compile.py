@@ -38,6 +38,7 @@ from paddle_tpu.ops.pallas import layer_norm as ln
 from paddle_tpu.ops.pallas import linear_ce as lce
 from paddle_tpu.ops.pallas import latent_attention as la
 from paddle_tpu.ops.pallas import paged_attention as pa
+from paddle_tpu.ops.pallas import selective_scan as pss
 
 # GPT-1.3B geometry (models/gpt.py gpt3-1.3b) and chip_smoke.py's shapes
 NH, HD, HIDDEN, VOCAB = 16, 128, 2048, 50304
@@ -575,6 +576,135 @@ def test_engine_step_helpers_at_the_serving_cells_geometry(one_chip, b,
         assert fn.lower(*args, **kw).compile().as_text()
 
 
+# ------------------------------------------- the selective scan (Jamba, PR 36)
+def test_selective_scan_at_the_jamba_cells_window(one_chip):
+    """One row's prefill window of 256 tokens at d_inner 5,120 and 16
+    states (benchmarks/workloads/serve-jamba2-3b-reason-over.json): ten
+    programs of 512 channels, each its [16, 512] state in registers across
+    the window. Nothing of [256, 16, 5120] is an operand or a result: the
+    call's bytes are its tokens' (x, dt, y: 3 x 5 MB; B, C as columns) and
+    one row's state."""
+    b, t, din, n = 1, 256, 5120, 16
+    f32 = jnp.float32
+    compiled = jax.jit(pss.selective_scan_kernel).lower(*[
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+            ((b, t, din), f32), ((b, t, din), f32), ((b, t, n), f32),
+            ((b, t, n), f32), ((n, din), f32), ((din,), f32),
+            ((b, n, din), f32), ((b,), jnp.int32))]).compile()
+    _assert_kernel(compiled.as_text(), pss.SELECTIVE_SCAN_NAME)
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes + m.output_size_in_bytes \
+        + m.temp_size_in_bytes < 40 * 2 ** 20 < t * n * din * 4
+
+
+def test_the_jamba_cell_compiles_for_the_chip_and_fits(one_chip, monkeypatch):
+    """The decode chunk and the prefill window of cell 7 at the published
+    widths and the cell's engine: the memory plan (weights, the paged pool,
+    both state planes of 26 layers for 256 slots and 32 snapshots,
+    temporaries) is under the chip's 15.75 GiB and is what the cell's
+    `engine_note` states; a prefill window holds 26 selective-scan kernels
+    and no array of [T, 16, 5120] but the state planes themselves, updated
+    in place; a decode chunk walks one KV head's pages in 2 kernels; the
+    program's leaves count ISSUE 36's 3,029,337,472 parameters."""
+    import json
+    import math
+    import numpy as np
+    from paddle_tpu.inference.kv_cache import BlockPool
+    from paddle_tpu.models.jamba import JambaForCausalLM
+    from paddle_tpu.nn import initializer
+    from paddle_tpu.ops import selective_scan as ss
+    from benchmarks.runners import serve_jamba
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks/configs/jamba2-3b.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(
+            root, "benchmarks/workloads/serve-jamba2-3b-reason-over.json")) \
+            as f:
+        settings = json.load(f)
+    eng = settings["engine"]
+    b, nb, bs, chunk, snaps, window = (
+        eng["max_batch"], eng["kv_blocks"], eng["kv_block"],
+        eng["decode_chunk"], eng["state_snapshots"], eng["prefill_chunk"])
+    mb = -(-(eng["prompt_cap"] + eng["max_new_tokens"]) // bs)
+    with initializer.fast_init():
+        model = JambaForCausalLM(serve_jamba.model_config(config))
+    model.eval()
+    assert sum(math.prod(p.shape) for p in model.parameters()) \
+        == 3_029_337_472
+    pool = BlockPool.for_model(model, num_blocks=nb, block_size=bs,
+                               state_rows=b, snapshot_rows=snaps)
+    sds = jax.ShapeDtypeStruct
+    pools = [tuple(sds((nb,) + shp, jnp.bfloat16) for shp in paged)
+             + tuple(sds((rows,) + shp, jnp.float32) for shp in state
+                     for rows in (b, snaps))
+             for paged, state in zip(pool.layer_block_shapes,
+                                     pool.state_shapes)]
+
+    class Captured(Exception):
+        pass
+
+    def grab(sig, build):               # the program, not run
+        def take(*args):
+            raise Captured(build(), args)
+        return take
+
+    monkeypatch.setattr(model, "_gen_cache_get", grab)
+    monkeypatch.setenv("PADDLE_TPU_PAGED", "1")     # the gates see the CPU
+    monkeypatch.setattr(ss, "on_tpu", lambda: True)
+
+    def compiled(call):
+        with pytest.raises(Captured) as got:
+            call()
+        fn, args = got.value.args
+        args = jax.tree.map(lambda a: sds(a.shape, a.dtype,
+                                          sharding=one_chip), args)
+        c = fn.lower(*args).compile()
+        return c.as_text(), c.memory_analysis()
+
+    def kernels(text, name):
+        return [l for l in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in l
+                and re.search(r"[%_]" + name + r"_*\.", l.split(" = ", 1)[0])]
+
+    zeros = lambda *s: jnp.zeros(s, jnp.int32)  # noqa: E731
+    text, mem = compiled(lambda: model.decode_paged(
+        pools, zeros(b, mb), zeros(b), zeros(b), jnp.ones((b,), bool),
+        chunk))
+    assert len(kernels(text, pa.GROUPED_DECODE_NAME)) == 2
+    gib = lambda x: x / 2 ** 30  # noqa: E731
+    plan = gib(mem.argument_size_in_bytes + mem.temp_size_in_bytes)
+    assert plan < 15.75
+    # every plane is updated in place: what comes out beside them is the
+    # chunk's tokens, lengths, flags and counters
+    assert mem.alias_size_in_bytes >= pool.state_bytes \
+        + nb * pool.bytes_per_block
+    assert mem.output_size_in_bytes - mem.alias_size_in_bytes < 2 ** 20
+    note = settings["engine_note"]
+    assert f"{gib(mem.argument_size_in_bytes):.2f} GiB of arguments" in note
+    assert f"{gib(mem.temp_size_in_bytes):.2f} GiB of temporaries" in note
+    assert f"{gib(pool.state_bytes):.2f} GiB" in note
+
+    text, mem = compiled(lambda: model.prefill_paged(
+        np.zeros((1, window), np.int64), np.asarray([window], np.int32),
+        pools, zeros(1, mb), start=np.asarray([0], np.int32),
+        state_slots=np.asarray([0], np.int32)))
+    assert len(kernels(text, pss.SELECTIVE_SCAN_NAME)) == 26
+    assert gib(mem.argument_size_in_bytes + mem.temp_size_in_bytes) < plan
+    assert f"{gib(mem.temp_size_in_bytes):.2f} GiB a prefill window" in note
+    # [T, 16, 5120] with T = 256 is also the slots' plane: whatever gives
+    # that shape is the plane itself, handed on or written in place
+    din, n = config["mamba_expand"] * config["hidden_size"], \
+        config["mamba_d_state"]
+    made = set(re.findall(
+        rf"= f32\[{window},{n},{din}\](?:{{[^}}]*}})? ([a-z\-]+)\(", text))
+    assert made and made <= {"parameter", "get-tuple-element", "fusion",
+                             "dynamic-update-slice", "bitcast"}, made
+    for other in (f"f32[{window},{din},{n}]", f"f32[1,{window},{n},{din}]",
+                  f"f32[1,{window},{din},{n}]"):
+        assert other not in text
+
+
 # ------------------------------------------------------- the kernels' names
 def test_every_kernel_has_a_name_and_none_holds_another():
     """A metric finds a kernel's events by looking for its name inside the
@@ -584,7 +714,7 @@ def test_every_kernel_has_a_name_and_none_holds_another():
     int8 kernel's time under the bf16 one's)."""
     import inspect
     names, calls = [], 0
-    for mod in (fa, fm, fmb, i8, ln, lce, pa, la):
+    for mod in (fa, fm, fmb, i8, ln, lce, pa, la, pss):
         src = inspect.getsource(mod)
         consts = re.findall(r"^([A-Z0-9_]*NAME) = ", src, re.M)
         used = re.findall(r"^\s+name=(\w+),$", src, re.M)

@@ -387,14 +387,15 @@ def _toy_family(name):
     with open(os.path.join(root, f"benchmarks/configs/toy-{toy}.json")) as f:
         config = json.load(f)
     cls = {"pangu_moe": "PanguMoEForCausalLM",
-           "minicpm_sala": "MiniCPMSALAForCausalLM"}[name]
+           "minicpm_sala": "MiniCPMSALAForCausalLM",
+           "jamba": "JambaForCausalLM"}[name]
     m = getattr(importlib.import_module(f"paddle_tpu.models.{name}"), cls)(
         runner.model_config(config))
     runner.install_weights(m, config, 3)
     m.eval()
     kw = dict(prompt_cap=40, max_new_tokens=NEW, decode_chunk=CHUNK,
               kv_block=8, kv_blocks=64, prefill_chunk=16)
-    if name == "minicpm_sala":
+    if name in ("minicpm_sala", "jamba"):
         kw["state_snapshots"] = 4
     logits = ref.logits if name == "pangu_moe" else ref.forward
 
@@ -410,7 +411,7 @@ def _toy_family(name):
 
 
 @pytest.mark.parametrize("family,executables", [
-    ("gpt", 4), ("pangu_moe", 4), ("minicpm_sala", 5)])
+    ("gpt", 4), ("pangu_moe", 4), ("minicpm_sala", 5), ("jamba", 5)])
 def test_a_request_alone_in_a_wide_batch_serves_its_own_tokens(family,
                                                                executables):
     """One request in an engine of 8 slots, then the same request among
