@@ -239,6 +239,25 @@ def kernels_phase(shapes: dict, seed: int, *, interpret: bool = False,
          qkv)
     case(f"flash grads [{b},{s},{nh},{hd}] causal", grads_of(flash_fwd),
          grads_of(ref_fwd), qkv + [cot])
+    # the packed projection, q, k, v read as views of it: heads of 128
+    # lanes only, which is what GPT's training attention hands over
+    qkv_packed = rnd(b, s, 3 * nh * 128)
+    cot = rnd(b, s, nh * 128)
+
+    def out_and_grad(attend):
+        def both(x, c):
+            out, pull = jax.vjp(lambda x: attend(x).reshape(c.shape), x)
+            return out, pull(c)[0]
+        return both
+
+    case(f"flash fwd and grad, packed qkv [{b},{s},{3 * nh * 128}] causal",
+         out_and_grad(lambda x: fa.flash_attention_qkv(
+             x, nh, causal=True, interpret=interpret)),
+         out_and_grad(lambda x: A.attention_reference(
+             *(t.reshape(b, s, nh, 128) for t in jnp.split(x, 3, axis=-1)),
+             is_causal=True)),
+         [qkv_packed, cot])
+    del qkv_packed
     nh_p, hd_p = shapes["flash_padded"]
     qkv = [rnd(b, s, nh_p, hd_p) for _ in range(3)]
     cot = rnd(b, s, nh_p, hd_p)

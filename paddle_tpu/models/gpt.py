@@ -38,7 +38,8 @@ from ..distributed.mpu import (ColumnParallelLinear, RowParallelLinear,
                                VocabParallelEmbedding, ParallelCrossEntropy)
 from ..distributed import mesh as _mesh
 from ..distributed.recompute import recompute
-from ..ops.attention import functional_attention
+from ..ops.attention import (functional_attention,
+                             functional_qkv_attention)
 
 
 @dataclass
@@ -481,51 +482,24 @@ def _qkv_attention(qkv3h, nh, hd, sequence_parallel="ring"):
     import jax.numpy as jnp
     qkv3h = checkpoint_name(qkv3h, "qkv_proj")   # save-list hook (recompute.py)
     b, s = qkv3h.shape[0], qkv3h.shape[1]
-    H = nh * hd
     sp_active = sequence_parallel and _mesh.mesh_axis_size("sp") > 1
-    if (not sp_active and hd == 128 and s % 128 == 0
-            and _use_packed_flash()):
-        # packed-layout flash (opt-in): q/k/v stay [B,S,H] lane slices
-        # of the projection output; dq/dk/dv return in the same layout for
-        # the projection weight grad. Removes ~11 head-major layout passes
-        # per layer, but measured BREAK-EVEN on the v5e bench chip — the
-        # passes overlap with MXU work (see flash_attention_packed).
-        q, k, v = qkv3h[:, :, :H], qkv3h[:, :, H:2 * H], qkv3h[:, :, 2 * H:]
-        q = _mesh.shard_constraint(q, "dp", "sp", "mp")
-        k = _mesh.shard_constraint(k, "dp", "sp", "mp")
-        v = _mesh.shard_constraint(v, "dp", "sp", "mp")
-        from ..ops.pallas.flash_attention import flash_attention_packed
-        out = flash_attention_packed(q, k, v, nh, causal=True)
-        out = _mesh.shard_constraint(out, "dp", "sp", "mp")
-        out = jnp.reshape(out, (b, s, nh, hd))
-        return checkpoint_name(out, "attn_ctx")
-    qkv = jnp.reshape(qkv3h, (b, s, 3, nh, hd))
-    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-    q = _mesh.shard_constraint(q, "dp", "sp", "mp", None)
-    k = _mesh.shard_constraint(k, "dp", "sp", "mp", None)
-    v = _mesh.shard_constraint(v, "dp", "sp", "mp", None)
+
+    def heads(x):
+        return _mesh.shard_constraint(x, "dp", "sp", "mp", None)
+
     if sp_active:
         # sp>1: keep S sharded end-to-end — ring/ulysses schedule instead of
         # letting XLA all-gather K/V for the dense product (SURVEY §5.7).
         from ..ops.ring_attention import sequence_parallel_attention
-        out = sequence_parallel_attention(q, k, v, is_causal=True,
-                                          schedule=sequence_parallel)
+        qkv = jnp.reshape(qkv3h, (b, s, 3, nh, hd))
+        out = sequence_parallel_attention(
+            *(heads(qkv[:, :, i]) for i in range(3)), is_causal=True,
+            schedule=sequence_parallel)
     else:
-        out = functional_attention(q, k, v, is_causal=True)
+        out = functional_qkv_attention(qkv3h, nh, hd, is_causal=True,
+                                       constrain=heads)
     out = _mesh.shard_constraint(out, "dp", "sp", "mp", None)
     return checkpoint_name(out, "attn_ctx")
-
-
-def _use_packed_flash():
-    # opt-in: measured break-even on the v5e bench chip (see
-    # flash_attention_packed docstring) — default stays the proven
-    # head-major kernel. The platform gate keeps the env opt-in from
-    # routing a CPU/compile-incapable host into Mosaic.
-    import os
-    if os.environ.get("PADDLE_TPU_FLASH_PACKED") != "1":
-        return False
-    from ..device import on_tpu
-    return on_tpu()
 
 
 def _attend(q, k, v, causal):

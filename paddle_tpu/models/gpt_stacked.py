@@ -34,7 +34,7 @@ from ..nn.layer import Layer
 from ..nn import initializer as I
 from ..distributed import mesh as _mesh
 from ..incubate.nn.functional import fused_linear_cross_entropy_array
-from ..ops.attention import functional_attention
+from ..ops.attention import functional_qkv_attention
 from .gpt import GPTConfig
 
 # (param name, per-layer shape fn, pspec over the stacked [L, ...] tensor,
@@ -76,12 +76,10 @@ def _block_batch(p, x, cfg: GPTConfig):
     qkv = jnp.einsum("smth,shk->smtk", h, p["qkv_w"]) \
         + p["qkv_b"][:, None, None]
     qkv = _mesh.shard_constraint(qkv, "pp", "dp", None, "mp")
-    qkv = qkv.reshape(Sdim * mb, s, 3, nh, hd)
-    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-    q = _mesh.shard_constraint(q, ("pp", "dp"), None, "mp", None)
-    k = _mesh.shard_constraint(k, ("pp", "dp"), None, "mp", None)
-    v = _mesh.shard_constraint(v, ("pp", "dp"), None, "mp", None)
-    ctx = functional_attention(q, k, v, is_causal=True)
+    ctx = functional_qkv_attention(
+        qkv.reshape(Sdim * mb, s, 3 * nh * hd), nh, hd, is_causal=True,
+        constrain=lambda a: _mesh.shard_constraint(
+            a, ("pp", "dp"), None, "mp", None))
     ctx = ctx.reshape(Sdim, mb, s, nh * hd)
     a = jnp.einsum("smtk,skh->smth", ctx, p["out_w"]) \
         + p["out_b"][:, None, None]
@@ -111,12 +109,9 @@ def _block_single(p, x, cfg: GPTConfig):
     h = _ln(x, p["ln1_w"], p["ln1_b"], eps)
     qkv = jnp.einsum("mth,hk->mtk", h, p["qkv_w"]) + p["qkv_b"]
     qkv = _mesh.shard_constraint(qkv, "dp", None, "mp")
-    qkv = qkv.reshape(mb, s, 3, nh, hd)
-    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-    q = _mesh.shard_constraint(q, "dp", None, "mp", None)
-    k = _mesh.shard_constraint(k, "dp", None, "mp", None)
-    v = _mesh.shard_constraint(v, "dp", None, "mp", None)
-    ctx = functional_attention(q, k, v, is_causal=True)
+    ctx = functional_qkv_attention(
+        qkv, nh, hd, is_causal=True,
+        constrain=lambda a: _mesh.shard_constraint(a, "dp", None, "mp", None))
     a = jnp.einsum("mtk,kh->mth", ctx.reshape(mb, s, nh * hd), p["out_w"]) \
         + p["out_b"]
     a = _mesh.shard_constraint(a, "dp", None, None)

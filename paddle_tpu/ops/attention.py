@@ -80,6 +80,21 @@ def _flash(q, k, v, *, causal, scale, kv_len=None):
     return _mesh.shard_kernel(kernel, (q, k, v), (_BSHD,) * 3, _BSHD)
 
 
+def _flash_qkv(qkv, num_heads, *, causal, scale):
+    """The flash kernel on the PACKED projection [B, S, 3 * heads * 128]:
+    q, k, v are read as three views of the one array, o comes back
+    [B, S, heads * 128] and the gradient as one packed array. The packed
+    lanes are q, k and v heads in turn, no axis a mesh could split, so only
+    the batch rows go over dp, each shard running its own as in `_flash`."""
+    from .pallas.flash_attention import flash_attention_qkv
+
+    def kernel(qkv):
+        return flash_attention_qkv(qkv, num_heads, causal=causal, scale=scale)
+
+    rows = ("dp", None, None)
+    return _mesh.shard_kernel(kernel, (qkv,), (rows,), rows)
+
+
 def attention_reference(q, k, v, mask=None, is_causal=False, scale=None,
                         dropout_p=0.0, dropout_key=None, score_dtype=None):
     """Reference jnp attention on [B, S, H, D]; fp32 softmax accumulation.
@@ -186,6 +201,28 @@ def functional_attention(q, k, v, *, is_causal=False, scale=None, mask=None,
         return out[:, :s]
     return attention_reference(q, k, v, mask=mask, is_causal=is_causal,
                                scale=scale, score_dtype=score_dtype)
+
+
+def functional_qkv_attention(qkv, num_heads, head_dim, *, is_causal=False,
+                             scale=None, constrain=lambda x: x):
+    """Self-attention on the PACKED projection [B, S, 3 * heads * head_dim]
+    (q heads, then k heads, then v heads), for jitted model code; returns
+    [B, S, heads, head_dim]. Where the flash kernel runs, heads fill the
+    128 lanes and no mp axis splits them, the projection goes to the
+    kernels WHOLE and its gradient comes back as one array: no slice, pad
+    or head-major copy is made either way (such passes overlap nothing, a
+    TensorCore runs one fusion at a time; they were 7% of the GPT-3 1.3B
+    step, PERF.md section 6, PR 37). Elsewhere q, k, v are split, each put
+    through `constrain` (the caller's sharding constraint for a
+    [B, S, heads, head_dim] array), and go to `functional_attention`."""
+    b, s = qkv.shape[:2]
+    if (head_dim == 128 and _mesh.mesh_axis_size("mp") <= 1
+            and _use_pallas((b, s), head_dim)):
+        out = _flash_qkv(qkv, num_heads, causal=is_causal, scale=scale)
+        return out.reshape(b, s, num_heads, head_dim)
+    qkv = qkv.reshape(b, s, 3, num_heads, head_dim)
+    q, k, v = (constrain(qkv[:, :, i]) for i in range(3))
+    return functional_attention(q, k, v, is_causal=is_causal, scale=scale)
 
 
 # ----------------------------------------------------- static KV-cache ops

@@ -25,10 +25,22 @@ masked; a block wholly under the diagonal runs with no mask, and a step
 above it, which is skipped, names a block already resident and starts no
 DMA. `causal_work` counts what a plan multiplies.
 
-Layout: [batch, seq, heads, head_dim] in, same out (paddle convention).
-head_dim pads to the 128-lane boundary in the wrapper (zero pads change no
-dot product), so 64-dim heads work. Matmuls run on bf16 inputs with f32
-accumulation (preferred_element_type) — full MXU rate.
+Layout: q, k, v, o and their gradients reach the kernels token-major,
+[batch, seq, heads * w], as the projections make and take them, a head w
+lanes wide (128, or any multiple of it); a grid step finds its head as a
+block of w lanes through the index map ("token-major operands" below), so
+heads that are whole lanes have no head-major copy of an operand or a
+result, forward or backward. Such copies do not hide under the matmuls: a
+TensorCore runs one fusion at a time, and a traced GPT-3 1.3B step showed
+them as 7% of its own time; without them the step is 11% faster (PERF.md
+section 6, PR 37). `flash_attention_qkv` takes the packed
+[batch, seq, 3 * heads * 128] projection whole. `flash_attention` takes
+[batch, seq, heads, head_dim] (paddle convention): heads of whole lanes as
+a view of the token-major array, other heads zero-padded up to whole lanes
+(zero pads change no dot product, so 64-, 80- and 192-dim heads work) and
+laid head-major, which is the token-major form of one head a row (see
+`_flash`). Matmuls run on bf16 inputs with f32 accumulation
+(preferred_element_type) — full MXU rate.
 """
 from __future__ import annotations
 
@@ -43,6 +55,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BQ = 1024
 DEFAULT_BK = 1024
+LANES = 128     # a head's width in the kernels' operands is whole lanes
 _NEG = -1e30
 
 
@@ -225,45 +238,73 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc,
 FWD_NAME = "pallas_flash_fwd"
 
 
+# ------------------------------------------------- token-major operands
+# q, k, v, o and their gradients reach the kernels as the projections make
+# and take them: token-major [B, S, n * w], a head a block of w lanes (w a
+# multiple of 128). Step g of a grid's first axis (B * nh long) works batch
+# g // nh and the lane block off + g % nh, so a head is found by an index
+# map and never by a copy. `off` is 0 for an array of nh heads and 0, nh,
+# 2 nh for q, k, v as the three groups of ONE packed [B, S, 3 nh w]
+# projection. On the chip's HBM tiling a (rows, 128) block of such an
+# array is whole 4 KB tiles: the DMA moves what a head-major block's would.
+
+def _head_block(nh, w, off, rows, row):
+    """BlockSpec of head g % nh's (1, rows, w) block at grid step
+    (g, i, j): batch g // nh, row block `row(i, j)`, lane block off + g % nh
+    in units of the head's width w."""
+    def index(g, i, j):
+        n = jnp.int32(nh)   # i32: a bare python int traces as i64 under x64
+        return lax.div(g, n), row(i, j), jnp.int32(off) + lax.rem(g, n)
+    return pl.BlockSpec((1, rows, w), index)
+
+
+def _row_stat(rows, row):
+    """BlockSpec of lse / delta, [B * nh, 8, S] float32: one row of
+    numbers a head, replicated over 8 sublanes."""
+    return pl.BlockSpec((1, 8, rows), lambda g, i, j: (g, _i0(), row(i, j)))
+
+
+def _first(i, j):
+    return i
+
+
 # Traced once per signature and inlined at every call: a kernel body with
 # its strips unrolled takes ~0.1 s to trace, which a 24-layer step would
 # otherwise pay 24 times in its set-up. Inlined, the caller's jaxpr is the
 # one the plain call gives.
 _launcher = functools.partial(
     jax.jit, inline=True,
-    static_argnames=("scale", "causal", "bq", "bk", "interpret", "kv_len"))
+    static_argnames=("nh", "w", "offs", "scale", "causal", "bq", "bk",
+                     "interpret", "kv_len"))
 
 
 @_launcher
-def _flash_fwd(q, k, v, *, scale, causal, bq, bk, interpret, kv_len=None):
-    b, s_q, h, d = q.shape
+def _flash_fwd(q, k, v, *, nh, w, offs, scale, causal, bq, bk, interpret,
+               kv_len=None):
+    """o [B, S_q, nh * w] and lse [B * nh, 8, S_q] of token-major q, k, v
+    whose heads, w lanes wide, start at lane blocks `offs`."""
+    b, s_q = q.shape[:2]
     s_k = k.shape[1]
-    qt = jnp.moveaxis(q, 2, 1).reshape(b * h, s_q, d)
-    kt = jnp.moveaxis(k, 2, 1).reshape(b * h, s_k, d)
-    vt = jnp.moveaxis(v, 2, 1).reshape(b * h, s_k, d)
     n_kb = s_k // bk
     kb = _k_block(causal, bq, bk)
+    oq, ok, ov = offs
 
-    out, lse = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal, n_kb=n_kb,
                           kv_len=kv_len),
-        out_shape=(jax.ShapeDtypeStruct((b * h, s_q, d), q.dtype),
-                   jax.ShapeDtypeStruct((b * h, 8, s_q), jnp.float32)),
-        grid=(b * h, s_q // bq, n_kb),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, _i0())),
-            pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh, kb(qi, ki), _i0())),
-            pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh, kb(qi, ki), _i0())),
-        ],
-        out_specs=(pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, _i0())),
-                   pl.BlockSpec((1, 8, bq), lambda bh, qi, ki: (bh, _i0(), qi))),
+        out_shape=(jax.ShapeDtypeStruct((b, s_q, nh * w), q.dtype),
+                   jax.ShapeDtypeStruct((b * nh, 8, s_q), jnp.float32)),
+        grid=(b * nh, s_q // bq, n_kb),
+        in_specs=[_head_block(nh, w, oq, bq, _first),
+                  _head_block(nh, w, ok, bk, kb),
+                  _head_block(nh, w, ov, bk, kb)],
+        out_specs=(_head_block(nh, w, 0, bq, _first), _row_stat(bq, _first)),
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
-                        pltpu.VMEM((bq, d), jnp.float32)],
+                        pltpu.VMEM((bq, w), jnp.float32)],
         interpret=interpret,
         name=FWD_NAME,
-    )(qt, kt, vt)
-    return out, lse, (qt, kt, vt)
+    )(q, k, v)
 
 
 # ----------------------------------------------------------------- backward
@@ -345,138 +386,161 @@ DQ_NAME = "pallas_flash_dq"
 DKV_NAME = "pallas_flash_dkv"
 
 
+def _head_rowsum(a, b, nh):
+    """delta = rowsum(dO * O) a head, float32 [B * nh, S], from token-major
+    [B, S, nh * w] operands: the lanes are summed a head by a product
+    with the heads' 0 / 1 indicator. XLA makes that one fusion that reads
+    both operands once and writes the sums with the tokens along the lanes,
+    as the kernels take them. (Summed over a [B, S, nh, 128] view instead,
+    the float32 products are written out and copied into another tiling
+    first: 100 MB a layer at the training shape.) `highest` keeps the
+    float32 products whole; the indicator is exact in any precision."""
+    if nh == 1:     # head-major rows: the lanes are the one head's
+        return jnp.sum(a.astype(jnp.float32) * b.astype(jnp.float32), -1)
+    lanes = a.shape[-1]
+    heads = (lax.broadcasted_iota(jnp.int32, (nh, lanes), 1) // (lanes // nh)
+             == lax.broadcasted_iota(jnp.int32, (nh, lanes), 0))
+    sums = jnp.einsum("hl,bsl->bhs", heads.astype(jnp.float32),
+                      a.astype(jnp.float32) * b.astype(jnp.float32),
+                      precision=lax.Precision.HIGHEST)
+    return sums.reshape(a.shape[0] * nh, a.shape[1])
+
+
 @_launcher
-def _flash_bwd(res, g, *, scale, causal, bq, bk, interpret, kv_len=None):
-    qt, kt, vt, out, lse = res
-    bh, s_q, d = qt.shape
-    s_k = kt.shape[1]
-    dot = jnp.moveaxis(g, 2, 1).reshape(bh, s_q, d)
-    delta = jnp.sum(dot.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-    delta = jnp.broadcast_to(delta[:, None, :], (bh, 8, s_q))
+def _flash_bwd(q, k, v, o, lse, do, *, nh, w, offs, scale, causal, bq, bk,
+               interpret, kv_len=None):
+    """dq [B, S_q, nh * w], dk and dv [B, S_k, nh * w], token-major like
+    the operands."""
+    b, s_q = q.shape[:2]
+    s_k = k.shape[1]
+    delta = jnp.broadcast_to(_head_rowsum(do, o, nh)[:, None, :],
+                             (b * nh, 8, s_q))
     n_kb = s_k // bk
     n_qb = s_q // bq
     kb = _k_block(causal, bq, bk)
     qb = _q_block(causal, bq, bk, n_qb)
+    oq, ok, ov = offs
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           n_kb=n_kb, kv_len=kv_len),
-        out_shape=jax.ShapeDtypeStruct((bh, s_q, d), qt.dtype),
-        grid=(bh, n_qb, n_kb),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, qi, ki: (b, qi, _i0())),
-            pl.BlockSpec((1, bk, d), lambda b, qi, ki: (b, kb(qi, ki), _i0())),
-            pl.BlockSpec((1, bk, d), lambda b, qi, ki: (b, kb(qi, ki), _i0())),
-            pl.BlockSpec((1, bq, d), lambda b, qi, ki: (b, qi, _i0())),
-            pl.BlockSpec((1, 8, bq), lambda b, qi, ki: (b, _i0(), qi)),
-            pl.BlockSpec((1, 8, bq), lambda b, qi, ki: (b, _i0(), qi)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda b, qi, ki: (b, qi, _i0())),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        out_shape=jax.ShapeDtypeStruct((b, s_q, nh * w), q.dtype),
+        grid=(b * nh, n_qb, n_kb),
+        in_specs=[_head_block(nh, w, oq, bq, _first),
+                  _head_block(nh, w, ok, bk, kb),
+                  _head_block(nh, w, ov, bk, kb),
+                  _head_block(nh, w, 0, bq, _first),
+                  _row_stat(bq, _first), _row_stat(bq, _first)],
+        out_specs=_head_block(nh, w, 0, bq, _first),
+        scratch_shapes=[pltpu.VMEM((bq, w), jnp.float32)],
         interpret=interpret,
         name=DQ_NAME,
-    )(qt, kt, vt, dot, lse, delta)
+    )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           n_qb=n_qb, kv_len=kv_len),
-        out_shape=(jax.ShapeDtypeStruct((bh, s_k, d), kt.dtype),
-                   jax.ShapeDtypeStruct((bh, s_k, d), vt.dtype)),
-        grid=(bh, n_kb, n_qb),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, ki, qi: (b, qb(ki, qi), _i0())),
-            pl.BlockSpec((1, bk, d), lambda b, ki, qi: (b, ki, _i0())),
-            pl.BlockSpec((1, bk, d), lambda b, ki, qi: (b, ki, _i0())),
-            pl.BlockSpec((1, bq, d), lambda b, ki, qi: (b, qb(ki, qi), _i0())),
-            pl.BlockSpec((1, 8, bq), lambda b, ki, qi: (b, _i0(), qb(ki, qi))),
-            pl.BlockSpec((1, 8, bq), lambda b, ki, qi: (b, _i0(), qb(ki, qi))),
-        ],
-        out_specs=(pl.BlockSpec((1, bk, d), lambda b, ki, qi: (b, ki, _i0())),
-                   pl.BlockSpec((1, bk, d), lambda b, ki, qi: (b, ki, _i0()))),
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
+        out_shape=(jax.ShapeDtypeStruct((b, s_k, nh * w), k.dtype),
+                   jax.ShapeDtypeStruct((b, s_k, nh * w), v.dtype)),
+        grid=(b * nh, n_kb, n_qb),
+        in_specs=[_head_block(nh, w, oq, bq, qb),
+                  _head_block(nh, w, ok, bk, _first),
+                  _head_block(nh, w, ov, bk, _first),
+                  _head_block(nh, w, 0, bq, qb),
+                  _row_stat(bq, qb), _row_stat(bq, qb)],
+        out_specs=(_head_block(nh, w, 0, bk, _first),
+                   _head_block(nh, w, 0, bk, _first)),
+        scratch_shapes=[pltpu.VMEM((bk, w), jnp.float32),
+                        pltpu.VMEM((bk, w), jnp.float32)],
         interpret=interpret,
         name=DKV_NAME,
-    )(qt, kt, vt, dot, lse, delta)
+    )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
 
 # ------------------------------------------------------------- custom_vjp
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _flash(q, k, v, scale, causal, bq, bk, interpret, kv_len=None,
-           save_transposed=False):
-    out, _, _ = _flash_fwd(q, k, v, scale=scale, causal=causal, bq=bq, bk=bk,
-                           interpret=interpret, kv_len=kv_len)
-    b, s_q, h, d = q.shape
-    return jnp.moveaxis(out.reshape(b, h, s_q, d), 1, 2)
+# `_flash` takes its operands in one of three forms and hands results and
+# gradients back in the same one. What tells them apart is what the caller
+# has: (packed,) one [B, S, 3 nh w] projection, q, k, v its three groups
+# of lane blocks, the gradient one array again; (q, k, v) token-major
+# [B, S, nh w]; (q, k, v) as [B, S, nh, w], which is heads PADDED to whole
+# lanes. A head's width w is read off the operand: any multiple of 128.
+# A padded array is a pass of its own already, and on the chip its reshape
+# to [B, S, nh w] is a second one (the 4-D array is tiled 8 heads x 128
+# lanes, the token-major one 8 tokens x 128 lanes); laid head-major,
+# [B nh, S, w], it costs the same two passes and the kernels run 3-6%
+# faster on contiguous blocks (heads of 80 at B 3, S 2048, a layer forward
+# and backward on the v5e: 6.62 ms head-major, 6.87 token-major; GPT-3
+# 2.7B's step -0.49% token-major). So padded heads go head-major, which to
+# the kernels is a token-major array of ONE head a row: nh = 1, B nh batch
+# rows. Heads that are whole lanes as they come go token-major: the
+# reshape cancels against the projection that made them (three
+# projections of one input, a layer: 5.12 ms against 5.27 head-major), and
+# where it cannot (slices of a packed array, a [B, S, nh, w] array that
+# exists) the two forms tie within 1.3% (PERF.md section 6, PR 37).
+
+def _head_major(x):
+    b, s, h, d = x.shape
+    return jnp.moveaxis(x, 2, 1).reshape(b * h, s, d)
 
 
-def _flash_vjp_fwd(q, k, v, scale, causal, bq, bk, interpret, kv_len=None,
-                   save_transposed=False):
-    out, lse, (qt, kt, vt) = _flash_fwd(q, k, v, scale=scale, causal=causal,
-                                        bq=bq, bk=bk, interpret=interpret,
-                                        kv_len=kv_len)
-    b, s_q, h, d = q.shape
-    o = jnp.moveaxis(out.reshape(b, h, s_q, d), 1, 2)
-    if save_transposed:
-        # residuals: the HEAD-MAJOR [b*h, s, d] copies the forward already
-        # built — backward reuses them instead of re-transposing, saving 3
-        # layout passes per layer (~20 ms/step on the 1.3B flagship at the
-        # measured ~180 GB/s effective HBM bw) at +3·B·S·H·2B residual
-        # memory. Right when HBM has headroom; wrong near the remat knee.
-        return o, (qt, kt, vt, out, lse, (b, h))
-    # default residuals: the ORIGINAL layouts (alias the layer's live
-    # tensors) — the transposes are recomputed in bwd, saving 3 head-major
-    # copies of q/k/v in HBM across the whole backward (~100MB at 1.3B
-    # S=8192; the difference between fitting bf16 moments and OOM)
-    return o, (q, k, v, out, lse, (b, h))
+def _operands(qkv, nh):
+    """The kernels' view of a call: (q, k, v) as they take them, and as
+    keywords the heads a batch row of theirs holds, a head's width in
+    lanes, and the lane blocks q's, k's and v's heads start at."""
+    if len(qkv) == 1:
+        return qkv * 3, dict(nh=nh, w=qkv[0].shape[-1] // (3 * nh),
+                             offs=(0, nh, 2 * nh))
+    if qkv[0].ndim == 4:
+        return (tuple(map(_head_major, qkv)),
+                dict(nh=1, w=qkv[0].shape[-1], offs=(0, 0, 0)))
+    return qkv, dict(nh=nh, w=qkv[0].shape[-1] // nh, offs=(0, 0, 0))
 
 
-def _flash_vjp_bwd(scale, causal, bq, bk, interpret, kv_len, save_transposed,
-                   res, g):
-    q, k, v, out, lse, (b, h) = res
-    d = q.shape[-1]
-    if save_transposed:
-        qt, kt, vt = q, k, v
-    else:
-        qt = jnp.moveaxis(q, 2, 1).reshape(b * h, q.shape[1], d)
-        kt = jnp.moveaxis(k, 2, 1).reshape(b * h, k.shape[1], d)
-        vt = jnp.moveaxis(v, 2, 1).reshape(b * h, v.shape[1], d)
-    dq, dk, dv = _flash_bwd((qt, kt, vt, out, lse), g, scale=scale,
-                            causal=causal, bq=bq, bk=bk, interpret=interpret,
-                            kv_len=kv_len)
-    s_q, s_k, d = dq.shape[1], dk.shape[1], dq.shape[2]
-    dq = jnp.moveaxis(dq.reshape(b, h, s_q, d), 1, 2)
-    dk = jnp.moveaxis(dk.reshape(b, h, s_k, d), 1, 2)
-    dv = jnp.moveaxis(dv.reshape(b, h, s_k, d), 1, 2)
-    return dq, dk, dv
+def _like(x, operand, nh):
+    """A result or gradient of the kernels in its operand's form."""
+    if operand.ndim == 4:
+        return jnp.moveaxis(x.reshape(-1, nh, *x.shape[1:]), 1, 2)
+    return x
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6, 7))
+def _flash(qkv, nh, scale, causal, bq, bk, interpret, kv_len=None):
+    return _flash_vjp_fwd(qkv, nh, scale, causal, bq, bk, interpret,
+                          kv_len)[0]
+
+
+def _flash_vjp_fwd(qkv, nh, scale, causal, bq, bk, interpret, kv_len=None):
+    (q, k, v), heads = _operands(qkv, nh)
+    o, lse = _flash_fwd(q, k, v, **heads, scale=scale, causal=causal, bq=bq,
+                        bk=bk, interpret=interpret, kv_len=kv_len)
+    # residuals: the caller's own arrays, o as the kernel wrote it, lse
+    return _like(o, qkv[0], nh), (qkv, o, lse)
+
+
+def _flash_vjp_bwd(nh, scale, causal, bq, bk, interpret, kv_len, res, g):
+    qkv, o, lse = res
+    (q, k, v), heads = _operands(qkv, nh)
+    do = _head_major(g) if g.ndim == 4 else g
+    grads = _flash_bwd(q, k, v, o, lse, do, **heads, scale=scale,
+                       causal=causal, bq=bq, bk=bk, interpret=interpret,
+                       kv_len=kv_len)
+    if len(qkv) == 1:
+        # the packed projection's gradient: in a training step XLA reads
+        # dq, dk, dv through this concatenate as operands of the weight-
+        # and input-gradient products, and no pass of its own is left
+        return (jnp.concatenate(grads, axis=-1),),
+    return tuple(_like(x, qkv[0], nh) for x in grads),
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
-def _reference(q, k, v, *, scale, causal):
-    from ..attention import attention_reference
-    return attention_reference(q, k, v, is_causal=causal, scale=scale)
-
-
-def flash_attention(q, k, v, causal: bool = False, scale=None,
-                    block_q: int = None, block_k: int = None,
-                    interpret: bool = False, kv_len: int = None,
-                    save_transposed: bool = None):
-    """Differentiable flash attention on [B, S, H, D] arrays.
-
-    kv_len: static number of VALID key/value rows; rows >= kv_len (zero
-    padding up to the block boundary) receive -inf scores in forward and
-    backward, so their probability and dk/dv are exactly zero.
-
-    save_transposed: keep the forward's head-major q/k/v copies as
-    backward residuals (saves 3 re-transpose passes per layer) at the cost
-    of 3·B·S·H·2 bytes of residual memory. Default: env
-    PADDLE_TPU_FLASH_SAVE_T ("1"/"0"), else False (memory-lean)."""
-    if scale is None:
-        scale = 1.0 / math.sqrt(q.shape[-1])
-    s_q, s_k = q.shape[1], k.shape[1]
+def _blocks(x, dims, causal, block_q, block_k, interpret, kv_len):
+    """(bq, bk, kv_len) a call runs at. `x` is an operand, `dims` its
+    call's (B, S_q, S_k, heads, head_dim); a kv_len that masks nothing
+    comes back None."""
+    b, s_q, s_k, h, d = dims
     if kv_len is not None and kv_len <= 0:
         # every key column masked would make exp(s - m) == 1 uniformly and
         # return an average of V rather than erroring — reject up front
@@ -485,10 +549,10 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
     if kv_len is not None and kv_len >= s_k:
         kv_len = None
     import os
-    from . import autotune as _at0
-    if block_q is None and block_k is None and _at0._OVERRIDE is not None:
+    from . import autotune as _at
+    if block_q is None and block_k is None and _at._OVERRIDE is not None:
         # in-context tuner (autotune.tune_in_step) forcing this candidate
-        block_q, block_k = _at0._OVERRIDE
+        block_q, block_k = _at._OVERRIDE
     env_bq = os.environ.get("PADDLE_TPU_FLASH_BQ")  # tuning knobs
     env_bk = os.environ.get("PADDLE_TPU_FLASH_BK")
     if block_q is None and block_k is None and not env_bq and not env_bk \
@@ -502,16 +566,13 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
             # staged into the caller's trace, so during tracing we consult
             # the cache and fall back to defaults on a miss.
             import jax.core as _core
-            from . import autotune as _at
-            sig = (q.shape[0], s_q, s_k, q.shape[2], q.shape[3],
-                   int(causal), str(q.dtype))
+            sig = (b, s_q, s_k, h, d, int(causal), str(x.dtype))
             cached = _at.cached_blocks("flash_attention", sig)
             if cached is not None:
                 block_q, block_k = cached
-            elif not isinstance(q, _core.Tracer):
+            elif not isinstance(x, _core.Tracer):
                 block_q, block_k = _at.tune_flash_blocks(
-                    q.shape[0], s_q, s_k, q.shape[2], q.shape[3], causal,
-                    q.dtype)
+                    b, s_q, s_k, h, d, causal, x.dtype)
     bq = block_q or int(env_bq) if (block_q or env_bq) else min(DEFAULT_BQ, s_q)
     bk = block_k or int(env_bk) if (block_k or env_bk) else min(DEFAULT_BK, s_k)
     bq = min(bq, s_q)
@@ -520,365 +581,61 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
         bq //= 2
     while s_k % bk:
         bk //= 2
-    if bq < 8 or bk < 8:
-        if kv_len is not None:
-            from ..attention import attention_reference
-            kmask = (jnp.arange(s_k) < kv_len)[None, None, None, :]
-            return attention_reference(q, k, v, mask=kmask, is_causal=causal,
-                                       scale=scale)
-        return _reference(q, k, v, scale=scale, causal=causal)
-    d = q.shape[-1]
-    pad = (-d) % 128
-    if pad:
-        cfg = [(0, 0)] * 3 + [(0, pad)]
-        q = jnp.pad(q, cfg)
-        k = jnp.pad(k, cfg)
-        v = jnp.pad(v, cfg)
-    if save_transposed is None:
-        save_transposed = os.environ.get("PADDLE_TPU_FLASH_SAVE_T") == "1"
-    out = _flash(q, k, v, float(scale), bool(causal), int(bq), int(bk),
-                 bool(interpret), None if kv_len is None else int(kv_len),
-                 bool(save_transposed))
-    return out[..., :d] if pad else out
+    return int(bq), int(bk), None if kv_len is None else int(kv_len)
 
 
-# ----------------------------------------------------- packed-layout kernel
-# The [B, S, H, D] kernel above needs head-major [B*H, S, D] copies of
-# q/k/v (and of dq/dk/dv/out on the way back) — ~11 layout passes per layer
-# that cost ~85 ms/step on the GPT-1.3B flagship at the measured ~180 GB/s
-# effective HBM bandwidth (r3 profile). This variant consumes the
-# projection output DIRECTLY: q/k/v stay [B, S, H·D] (lane-contiguous),
-# the grid is (B, q_block, k_block), and heads are a compile-time loop of
-# 128-lane slices inside the kernel — zero transposes in fwd OR bwd.
-# Requires head_dim == 128 (lane-tile-aligned slices): true for GPT-1.3B
-# and GPT-6.7B (2048/16, 4096/32).
+def flash_attention(q, k, v, causal: bool = False, scale=None,
+                    block_q: int = None, block_k: int = None,
+                    interpret: bool = False, kv_len: int = None):
+    """Differentiable flash attention on [B, S, H, D] arrays.
 
-def _p_slice(ref0, h, hd):
-    return ref0[:, h * hd:(h + 1) * hd]
-
-
-def _packed_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc,
-                       acc_sc, *, scale, causal, n_kb, nh, hd, kv_len=None):
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    bq = q_ref.shape[1]
-    bk = k_ref.shape[1]
-
-    @pl.when(ki == 0)
-    def _init():
-        m_sc[...] = jnp.full_like(m_sc, _NEG)
-        l_sc[...] = jnp.zeros_like(l_sc)
-        acc_sc[...] = jnp.zeros_like(acc_sc)
-
-    needed = True if not causal else (ki * bk <= (qi + 1) * bq - 1)
-
-    @pl.when(needed)
-    def _step():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        for h in range(nh):
-            s = jnp.dot(_p_slice(q, h, hd), _p_slice(k, h, hd).T,
-                        preferred_element_type=jnp.float32) * scale
-            if causal:
-                s = _causal_mask(s, qi, ki, bq, bk)
-            if kv_len is not None:
-                s = _kv_mask(s, ki, bk, kv_len)
-            m_prev = m_sc[:, h:h + 1]
-            l_prev = l_sc[:, h:h + 1]
-            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            corr = jnp.exp(m_prev - m_new)
-            m_sc[:, h:h + 1] = m_new
-            l_sc[:, h:h + 1] = corr * l_prev + p.sum(axis=-1, keepdims=True)
-            acc_sc[:, h * hd:(h + 1) * hd] = (
-                corr * acc_sc[:, h * hd:(h + 1) * hd]
-                + jnp.dot(p.astype(v.dtype), _p_slice(v, h, hd),
-                          preferred_element_type=jnp.float32))
-
-    @pl.when(ki == n_kb - 1)
-    def _finish():
-        l = jnp.maximum(l_sc[...], 1e-30)                    # (bq, nh)
-        lhd = jnp.repeat(l, hd, axis=1)                      # (bq, nh*hd)
-        o_ref[0] = (acc_sc[...] / lhd).astype(o_ref.dtype)
-        lse = m_sc[...] + jnp.log(l)                         # (bq, nh)
-        lse_ref[0] = jnp.broadcast_to(
-            lse.T[:, None, :], (nh, 8, bq)).reshape(nh * 8, bq)
-
-
-PACKED_FWD_NAME = "pallas_packed_flash_fwd"
-
-
-def _packed_flash_fwd(q, k, v, *, scale, causal, bq, bk, interpret, nh,
-                      kv_len=None):
-    b, s_q, H = q.shape
+    kv_len: static number of VALID key/value rows; rows >= kv_len (zero
+    padding up to the block boundary) receive -inf scores in forward and
+    backward, so their probability and dk/dv are exactly zero."""
+    b, s_q, h, d = q.shape
     s_k = k.shape[1]
-    hd = H // nh
-    n_kb = s_k // bk
-
-    out, lse = pl.pallas_call(
-        functools.partial(_packed_fwd_kernel, scale=scale, causal=causal,
-                          n_kb=n_kb, nh=nh, hd=hd, kv_len=kv_len),
-        out_shape=(jax.ShapeDtypeStruct((b, s_q, H), q.dtype),
-                   jax.ShapeDtypeStruct((b, nh * 8, s_q), jnp.float32)),
-        grid=(b, s_q // bq, n_kb),
-        in_specs=[
-            pl.BlockSpec((1, bq, H), lambda bi, qi, ki: (bi, qi, _i0())),
-            pl.BlockSpec((1, bk, H), lambda bi, qi, ki: (bi, ki, _i0())),
-            pl.BlockSpec((1, bk, H), lambda bi, qi, ki: (bi, ki, _i0())),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, bq, H), lambda bi, qi, ki: (bi, qi, _i0())),
-            pl.BlockSpec((1, nh * 8, bq), lambda bi, qi, ki: (bi, _i0(), qi)),
-        ),
-        scratch_shapes=[pltpu.VMEM((bq, nh), jnp.float32),
-                        pltpu.VMEM((bq, nh), jnp.float32),
-                        pltpu.VMEM((bq, H), jnp.float32)],
-        interpret=interpret,
-        name=PACKED_FWD_NAME,
-    )(q, k, v)
-    return out, lse
-
-
-def _packed_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dq_ref, dq_sc, *, scale, causal, n_kb, nh, hd,
-                          kv_len=None):
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    bq = q_ref.shape[1]
-    bk = k_ref.shape[1]
-
-    @pl.when(ki == 0)
-    def _init():
-        dq_sc[...] = jnp.zeros_like(dq_sc)
-
-    needed = True if not causal else (ki * bk <= (qi + 1) * bq - 1)
-
-    @pl.when(needed)
-    def _step():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse_all = lse_ref[0].reshape(nh, 8, bq)
-        delta_all = delta_ref[0].reshape(nh, 8, bq)
-        for h in range(nh):
-            s = jnp.dot(_p_slice(q, h, hd), _p_slice(k, h, hd).T,
-                        preferred_element_type=jnp.float32) * scale
-            if causal:
-                s = _causal_mask(s, qi, ki, bq, bk)
-            if kv_len is not None:
-                s = _kv_mask(s, ki, bk, kv_len)
-            lse = lse_all[h, 0][:, None]
-            delta = delta_all[h, 0][:, None]
-            p = jnp.exp(s - lse)
-            dp = jnp.dot(_p_slice(do, h, hd), _p_slice(v, h, hd).T,
-                         preferred_element_type=jnp.float32)
-            ds = (p * (dp - delta)).astype(k.dtype)
-            dq_sc[:, h * hd:(h + 1) * hd] += jnp.dot(
-                ds, _p_slice(k, h, hd), preferred_element_type=jnp.float32)
-
-    @pl.when(ki == n_kb - 1)
-    def _finish():
-        dq_ref[0] = (dq_sc[...] * scale).astype(dq_ref.dtype)
-
-
-def _packed_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                           dk_ref, dv_ref, dk_sc, dv_sc, *, scale, causal,
-                           n_qb, nh, hd, kv_len=None):
-    ki, qi = pl.program_id(1), pl.program_id(2)
-    bk = k_ref.shape[1]
-    bq = q_ref.shape[1]
-
-    @pl.when(qi == 0)
-    def _init():
-        dk_sc[...] = jnp.zeros_like(dk_sc)
-        dv_sc[...] = jnp.zeros_like(dv_sc)
-
-    needed = True if not causal else ((qi + 1) * bq - 1 >= ki * bk)
-
-    @pl.when(needed)
-    def _step():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse_all = lse_ref[0].reshape(nh, 8, bq)
-        delta_all = delta_ref[0].reshape(nh, 8, bq)
-        for h in range(nh):
-            s = jnp.dot(_p_slice(q, h, hd), _p_slice(k, h, hd).T,
-                        preferred_element_type=jnp.float32) * scale
-            if causal:
-                s = _causal_mask(s, qi, ki, bq, bk)
-            if kv_len is not None:
-                s = _kv_mask(s, ki, bk, kv_len)
-            lse = lse_all[h, 0][:, None]
-            delta = delta_all[h, 0][:, None]
-            p = jnp.exp(s - lse)
-            pt = p.astype(do.dtype)
-            dv_sc[:, h * hd:(h + 1) * hd] += jnp.dot(
-                pt.T, _p_slice(do, h, hd),
-                preferred_element_type=jnp.float32)
-            dp = jnp.dot(_p_slice(do, h, hd), _p_slice(v, h, hd).T,
-                         preferred_element_type=jnp.float32)
-            ds = (p * (dp - delta)).astype(q.dtype)
-            dk_sc[:, h * hd:(h + 1) * hd] += jnp.dot(
-                ds.T, _p_slice(q, h, hd),
-                preferred_element_type=jnp.float32)
-
-    @pl.when(qi == n_qb - 1)
-    def _finish():
-        dk_ref[0] = (dk_sc[...] * scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
-
-
-PACKED_DQ_NAME = "pallas_packed_flash_dq"
-PACKED_DKV_NAME = "pallas_packed_flash_dkv"
-
-
-def _packed_flash_bwd(q, k, v, out, lse, g, *, scale, causal, bq, bk,
-                      interpret, nh, kv_len=None):
-    b, s_q, H = q.shape
-    s_k = k.shape[1]
-    hd = H // nh
-    # backward kernels hold 2x f32 accumulator panels (bk, H) — clamp their
-    # blocks to fit the 16M scoped-VMEM budget independently of the
-    # forward's (the fwd carries only ONE panel and can afford 512);
-    # re-establish divisibility after the clamp or the grid under-covers
-    # the sequence and uncovered gradient rows come back as garbage
-    bq = min(bq, 256)
-    bk = min(bk, 256)
-    while s_q % bq:
-        bq //= 2
-    while s_k % bk:
-        bk //= 2
-    n_kb = s_k // bk
-    n_qb = s_q // bq
-    # delta = rowsum(dO . O) per head: [B, S, nh] -> [B, nh*8, S]
-    delta = jnp.sum((g.astype(jnp.float32) * out.astype(jnp.float32))
-                    .reshape(b, s_q, nh, hd), axis=-1)       # [B, S, nh]
-    delta = jnp.broadcast_to(jnp.moveaxis(delta, 1, 2)[:, :, None, :],
-                             (b, nh, 8, s_q)).reshape(b, nh * 8, s_q)
-
-    dq = pl.pallas_call(
-        functools.partial(_packed_bwd_dq_kernel, scale=scale, causal=causal,
-                          n_kb=n_kb, nh=nh, hd=hd, kv_len=kv_len),
-        out_shape=jax.ShapeDtypeStruct((b, s_q, H), q.dtype),
-        grid=(b, n_qb, n_kb),
-        in_specs=[
-            pl.BlockSpec((1, bq, H), lambda bi, qi, ki: (bi, qi, _i0())),
-            pl.BlockSpec((1, bk, H), lambda bi, qi, ki: (bi, ki, _i0())),
-            pl.BlockSpec((1, bk, H), lambda bi, qi, ki: (bi, ki, _i0())),
-            pl.BlockSpec((1, bq, H), lambda bi, qi, ki: (bi, qi, _i0())),
-            pl.BlockSpec((1, nh * 8, bq), lambda bi, qi, ki: (bi, _i0(), qi)),
-            pl.BlockSpec((1, nh * 8, bq), lambda bi, qi, ki: (bi, _i0(), qi)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, H), lambda bi, qi, ki: (bi, qi, _i0())),
-        scratch_shapes=[pltpu.VMEM((bq, H), jnp.float32)],
-        interpret=interpret,
-        name=PACKED_DQ_NAME,
-    )(q, k, v, g, lse, delta)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_packed_bwd_dkv_kernel, scale=scale, causal=causal,
-                          n_qb=n_qb, nh=nh, hd=hd, kv_len=kv_len),
-        out_shape=(jax.ShapeDtypeStruct((b, s_k, H), k.dtype),
-                   jax.ShapeDtypeStruct((b, s_k, H), v.dtype)),
-        grid=(b, n_kb, n_qb),
-        in_specs=[
-            pl.BlockSpec((1, bq, H), lambda bi, ki, qi: (bi, qi, _i0())),
-            pl.BlockSpec((1, bk, H), lambda bi, ki, qi: (bi, ki, _i0())),
-            pl.BlockSpec((1, bk, H), lambda bi, ki, qi: (bi, ki, _i0())),
-            pl.BlockSpec((1, bq, H), lambda bi, ki, qi: (bi, qi, _i0())),
-            pl.BlockSpec((1, nh * 8, bq), lambda bi, ki, qi: (bi, _i0(), qi)),
-            pl.BlockSpec((1, nh * 8, bq), lambda bi, ki, qi: (bi, _i0(), qi)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, bk, H), lambda bi, ki, qi: (bi, ki, _i0())),
-            pl.BlockSpec((1, bk, H), lambda bi, ki, qi: (bi, ki, _i0())),
-        ),
-        scratch_shapes=[pltpu.VMEM((bk, H), jnp.float32),
-                        pltpu.VMEM((bk, H), jnp.float32)],
-        interpret=interpret,
-        name=PACKED_DKV_NAME,
-    )(q, k, v, g, lse, delta)
-    return dq, dk, dv
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _packed_flash(q, k, v, nh, scale, causal, bq, bk, interpret, kv_len=None):
-    out, _ = _packed_flash_fwd(q, k, v, scale=scale, causal=causal, bq=bq,
-                               bk=bk, interpret=interpret, nh=nh,
-                               kv_len=kv_len)
-    return out
-
-
-def _packed_vjp_fwd(q, k, v, nh, scale, causal, bq, bk, interpret,
-                    kv_len=None):
-    out, lse = _packed_flash_fwd(q, k, v, scale=scale, causal=causal, bq=bq,
-                                 bk=bk, interpret=interpret, nh=nh,
-                                 kv_len=kv_len)
-    return out, (q, k, v, out, lse)
-
-
-def _packed_vjp_bwd(nh, scale, causal, bq, bk, interpret, kv_len, res, g):
-    q, k, v, out, lse = res
-    dq, dk, dv = _packed_flash_bwd(q, k, v, out, lse, g, scale=scale,
-                                   causal=causal, bq=bq, bk=bk,
-                                   interpret=interpret, nh=nh, kv_len=kv_len)
-    return dq, dk, dv
-
-
-_packed_flash.defvjp(_packed_vjp_fwd, _packed_vjp_bwd)
-
-PACKED_BQ = 256
-PACKED_BK = 256
-
-
-def flash_attention_packed(q, k, v, num_heads: int, causal: bool = False,
-                           scale=None, block_q: int = None,
-                           block_k: int = None, interpret: bool = False,
-                           kv_len: int = None):
-    """Flash attention on PACKED [B, S, num_heads*128] arrays.
-
-    Zero layout transposes: inputs are the projection outputs as-is, and
-    dq/dk/dv come back in the same layout for the projection weight grads.
-    Requires head_dim == 128. Falls back to the [B,S,H,D] kernel via
-    reshape when the shape constraints don't hold.
-
-    Measured on v5e (GPT-1.3B B=3 S=2048): parity with the head-major
-    kernel at best (73.4% vs 73.3-73.7% MFU across block configs) — the
-    ~11 boundary layout passes the packed form eliminates turn out to
-    OVERLAP with MXU work in the XLA schedule, while the in-kernel head
-    loop (16 lane-sliced dots per block, 16M scoped-VMEM ceiling forcing
-    256-row blocks) gives the saving back. Kept as an opt-in
-    (PADDLE_TPU_FLASH_PACKED=1 routes GPT through it) for hardware where
-    the trade lands differently; the head-major kernel stays the default.
-    """
-    b, s_q, H = q.shape
-    hd = H // num_heads
     if scale is None:
-        scale = 1.0 / math.sqrt(hd)
-    s_k = k.shape[1]
-    if kv_len is not None and kv_len <= 0:
-        raise ValueError(f"flash_attention_packed: kv_len must be positive, "
-                         f"got {kv_len}")
-    if kv_len is not None and kv_len >= s_k:
-        kv_len = None
-    bq = block_q or min(PACKED_BQ, s_q)
-    bk = block_k or min(PACKED_BK, s_k)
-    bq = min(bq, s_q)
-    bk = min(bk, s_k)
-    while s_q % bq:
-        bq //= 2
-    while s_k % bk:
-        bk //= 2
-    if hd != 128 or bq < 8 or bk < 8:
-        q4 = q.reshape(b, s_q, num_heads, hd)
-        k4 = k.reshape(b, s_k, num_heads, hd)
-        v4 = v.reshape(b, s_k, num_heads, hd)
-        out = flash_attention(q4, k4, v4, causal=causal, scale=scale,
-                              block_q=block_q, block_k=block_k,
-                              interpret=interpret, kv_len=kv_len)
-        return out.reshape(b, s_q, H)
-    return _packed_flash(q, k, v, int(num_heads), float(scale), bool(causal),
-                         int(bq), int(bk), bool(interpret),
-                         None if kv_len is None else int(kv_len))
+        scale = 1.0 / math.sqrt(d)
+    bq, bk, kv_len = _blocks(q, (b, s_q, s_k, h, d), causal, block_q,
+                             block_k, interpret, kv_len)
+    if bq < 8 or bk < 8:
+        from ..attention import attention_reference
+        kmask = None if kv_len is None else \
+            (jnp.arange(s_k) < kv_len)[None, None, None, :]
+        return attention_reference(q, k, v, mask=kmask, is_causal=causal,
+                                   scale=scale)
+    pad = (-d) % LANES
+    if pad:     # heads padded to whole lanes go head-major (see `_flash`)
+        cfg = [(0, 0)] * 3 + [(0, pad)]
+        qkv = tuple(jnp.pad(x, cfg) for x in (q, k, v))
+    else:       # a view the caller's own reshape cancels against
+        qkv = tuple(x.reshape(*x.shape[:2], h * d) for x in (q, k, v))
+    out = _flash(qkv, h, float(scale), bool(causal), bq, bk, bool(interpret),
+                 kv_len)
+    return out[..., :d] if pad else out.reshape(b, s_q, h, d)
+
+
+def flash_attention_qkv(qkv, num_heads: int, causal: bool = False,
+                        scale=None, block_q: int = None, block_k: int = None,
+                        interpret: bool = False, kv_len: int = None):
+    """Flash self-attention on the PACKED projection [B, S, 3 * heads * 128]
+    as it leaves the matmul, q heads then k heads then v heads: the
+    kernels read q, k, v as three views of the one array and no slice of
+    it is made. Returns o [B, S, heads * 128]; the gradient is one
+    [B, S, 3 * heads * 128] array."""
+    b, s, width = qkv.shape
+    if width != 3 * num_heads * LANES:
+        raise ValueError(f"flash_attention_qkv: {num_heads} heads of {LANES} "
+                         f"packed three times are {3 * num_heads * LANES} "
+                         f"lanes, got {width}")
+    if scale is None:
+        scale = 1.0 / math.sqrt(LANES)
+    bq, bk, kv_len = _blocks(qkv, (b, s, s, num_heads, LANES), causal,
+                             block_q, block_k, interpret, kv_len)
+    if bq < 8 or bk < 8:
+        q, k, v = (x.reshape(b, s, num_heads, LANES)
+                   for x in jnp.split(qkv, 3, axis=-1))
+        return flash_attention(q, k, v, causal, scale, bq, bk, interpret,
+                               kv_len).reshape(b, s, num_heads * LANES)
+    return _flash((qkv,), int(num_heads), float(scale), bool(causal), bq, bk,
+                  bool(interpret), kv_len)

@@ -18,6 +18,7 @@ The topology is described inside a module-scoped fixture — never at
 import: only one process may hold libtpu, and every xdist worker imports
 every test file.
 """
+import math
 import os
 import re
 
@@ -138,6 +139,98 @@ def test_flash_attention_backward(one_chip, qkv):
     text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
                     qkv, qkv, qkv)
     _assert_kernel(text, fa.FWD_NAME, fa.DQ_NAME, fa.DKV_NAME)
+
+
+def _entry_instructions(text):
+    """(opcode, result elements, line) of the entry computation's
+    instructions: what the device runs one after the other (a fusion's
+    inner instructions are not passes of their own)."""
+    entry = text[text.index("\nENTRY "):]
+    out = []
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (.*?) ([a-z\-]+)\(", line)
+        if m:
+            dims = re.findall(r"[a-z]+[0-9]+\[([\d,]*)\]", m.group(1))
+            sizes = [math.prod(map(int, d.split(","))) if d else 1
+                     for d in dims]
+            out.append((m.group(2), max(sizes, default=0), line.strip()))
+    return out
+
+
+def _attention_layer_loss(nh, hd):
+    """GPT's training attention between its two projections' matmuls, as
+    the model has it: the packed projection in, the context reshaped for
+    the output projection, whose product hands back a token-major
+    cotangent."""
+    from paddle_tpu.models import gpt
+
+    def loss(qkv, w_out):
+        ctx = gpt._qkv_attention(qkv, nh, hd)
+        y = ctx.reshape(TRAIN_B, TRAIN_S, nh * hd) @ w_out
+        return y.astype(jnp.float32).sum()
+    return jax.value_and_grad(loss, argnums=(0, 1))
+
+
+def test_qkv_attention_reads_heads_from_the_packed_projection(
+        one_chip, monkeypatch):
+    """Cell 1's attention layer, bf16[3, 2048, 6144] at 16 heads of 128:
+    the three kernels take the projection itself three times, and no
+    instruction of the compiled program copies, transposes, reshapes or
+    slices an array of q's size or more. The packed gradient's assembly is
+    XLA's: here one pass (a loop fusion, allowed below by its opcode); in
+    the whole step it rides as an operand fusion of the projection's
+    weight-gradient and input-gradient products and is no pass at all."""
+    monkeypatch.setenv("PADDLE_TPU_FLASH", "1")      # the gate sees the CPU
+    text = _compile(_attention_layer_loss(NH, HD), one_chip,
+                    ((TRAIN_B, TRAIN_S, 3 * NH * HD), jnp.bfloat16),
+                    ((NH * HD, HIDDEN), jnp.bfloat16))
+    _assert_kernel(text, fa.FWD_NAME, fa.DQ_NAME, fa.DKV_NAME)
+    calls = [line for op, _, line in _entry_instructions(text)
+             if "tpu_custom_call" in line]
+    assert len(calls) == 3
+    for line in calls:
+        args = re.search(r"custom-call\((.*?)\), custom_call_target",
+                         line).group(1).split(", ")
+        assert args[0] == args[1] == args[2], line  # q, k, v: one operand
+    q_size = TRAIN_B * TRAIN_S * NH * HD
+    moved = [line for op, n, line in _entry_instructions(text)
+             if op in ("copy", "transpose", "reshape", "slice",
+                       "concatenate", "pad") and n >= q_size]
+    assert not moved, moved
+
+
+def test_qkv_attention_at_heads_of_80_keeps_the_three_kernels(
+        one_chip, monkeypatch):
+    """Cell 3's layer, 32 heads of 80: padded to the lanes and laid
+    head-major, bf16[3 * 32, 2048, 128], which to the same three kernels is
+    the token-major form of one head a row."""
+    monkeypatch.setenv("PADDLE_TPU_FLASH", "1")
+    text = _compile(_attention_layer_loss(32, 80), one_chip,
+                    ((TRAIN_B, TRAIN_S, 3 * 32 * 80), jnp.bfloat16),
+                    ((32 * 80, 32 * 80), jnp.bfloat16))
+    _assert_kernel(text, fa.FWD_NAME, fa.DQ_NAME, fa.DKV_NAME)
+    calls = [line for _, _, line in _entry_instructions(text)
+             if "tpu_custom_call" in line]
+    assert len(calls) == 3
+    assert all(f"bf16[{TRAIN_B * 32},{TRAIN_S},128]" in c for c in calls)
+
+
+@pytest.mark.parametrize("nh,hd,operand", [
+    (8, 256, f"bf16[{TRAIN_B},{TRAIN_S},{8 * 256}]"),       # token-major
+    (8, 192, f"bf16[{TRAIN_B * 8},{TRAIN_S},256]")])        # padded: head-major
+def test_qkv_attention_at_heads_over_the_lanes(one_chip, monkeypatch, nh, hd,
+                                               operand):
+    """Heads wider than the 128 lanes: the same three kernels on blocks as
+    wide as the head, whole lanes of it (192 is padded to 256)."""
+    monkeypatch.setenv("PADDLE_TPU_FLASH", "1")
+    text = _compile(_attention_layer_loss(nh, hd), one_chip,
+                    ((TRAIN_B, TRAIN_S, 3 * nh * hd), jnp.bfloat16),
+                    ((nh * hd, nh * hd), jnp.bfloat16))
+    _assert_kernel(text, fa.FWD_NAME, fa.DQ_NAME, fa.DKV_NAME)
+    calls = [line for _, _, line in _entry_instructions(text)
+             if "tpu_custom_call" in line]
+    assert len(calls) == 3
+    assert all(operand in c for c in calls)
 
 
 # ------------------------------------------------------------------ linear CE
@@ -272,6 +365,29 @@ def test_flash_under_dp_mp_mesh(mesh):
         "batch rows and heads are independent: no collective belongs here"
 
 
+def test_flash_qkv_under_dp_mesh(topo):
+    """The packed projection under a mesh of dp alone (the route GPT takes
+    it by: with an mp axis its heads go the [B, S, H, D] way above): each
+    chip runs the kernels on its own batch rows, the lanes (q, k and v
+    heads in turn) whole, and nothing is exchanged."""
+    m = dist.build_mesh({"dp": 4}, devices=topo.devices)
+    dist.set_mesh(m)
+    try:
+        sh = NamedSharding(m, P("dp", None, None))
+
+        def loss(qkv):
+            out = attn._flash_qkv(qkv, NH, causal=True, scale=None)
+            return out.astype(jnp.float32).sum()
+
+        text = _compile(jax.grad(loss), None,
+                        ((4, TRAIN_S, 3 * NH * HD), jnp.bfloat16, sh))
+    finally:
+        dist.set_mesh(None)
+    _assert_kernel(text, fa.FWD_NAME, fa.DQ_NAME, fa.DKV_NAME)
+    assert f"bf16[1,{TRAIN_S},{3 * NH * HD}]" in text    # a chip's rows
+    assert "all-gather" not in text and "all-reduce" not in text
+
+
 def test_linear_ce_under_dp_mp_mesh(mesh):
     """Tokens over dp, the vocab-parallel embedding's rows over mp: the
     kernel runs on each shard's [T/2, H] x [V/2, H], W is never gathered,
@@ -357,21 +473,6 @@ def test_layer_norm_backward(one_chip):
                     ((TRAIN_B * TRAIN_S, HIDDEN), jnp.bfloat16),
                     ((HIDDEN,), jnp.bfloat16), ((HIDDEN,), jnp.bfloat16))
     _assert_kernel(text, ln.FWD_NAME, ln.BWD_NAME)
-
-
-def test_flash_attention_packed_backward(one_chip):
-    """The [B, S, nh*hd] layout: its three kernels carry
-    names of their own, so a trace tells them from the unpacked ones."""
-    qkv = ((TRAIN_B, TRAIN_S, NH * HD), jnp.bfloat16)
-
-    def loss(q, k, v):
-        out = fa.flash_attention_packed(q, k, v, NH, causal=True)
-        return out.astype(jnp.float32).sum()
-
-    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
-                    qkv, qkv, qkv)
-    _assert_kernel(text, fa.PACKED_FWD_NAME, fa.PACKED_DQ_NAME,
-                   fa.PACKED_DKV_NAME)
 
 
 def test_latent_decode_at_the_serving_cells_geometry(one_chip):

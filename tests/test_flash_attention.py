@@ -1,5 +1,6 @@
 """Flash-attention kernel parity: fwd + blockwise bwd vs XLA reference
 (interpret mode on CPU; the driver exercises compiled mode on TPU)."""
+import functools
 import numpy as np
 import pytest
 import jax
@@ -227,136 +228,247 @@ def test_functional_attention_padded_flash_route(monkeypatch):
                                rtol=2e-4, atol=2e-4)
 
 
-class TestPackedFlash:
-    """flash_attention_packed: [B, S, nh*128] layout, in-kernel head loop."""
+# ----------------------------------------- token-major operands (PR 37)
+NH_P = 2        # heads of 128 in the packed projection of the tests below
 
-    def _qkv(self, B=2, S=256, NH=2, HD=128, seed=7):
-        rng = np.random.RandomState(seed)
-        H = NH * HD
-        mk = lambda: jnp.asarray(rng.randn(B, S, H).astype(np.float32) * 0.3)
-        return mk(), mk(), mk(), NH, HD
 
-    def _ref(self, q, k, v, nh, hd, causal, kv_len=None):
-        B, S, H = q.shape
-        q4 = q.reshape(B, S, nh, hd)
-        k4 = k.reshape(B, S, nh, hd)
-        v4 = v.reshape(B, S, nh, hd)
-        if kv_len is not None:
-            k4, v4 = k4[:, :kv_len], v4[:, :kv_len]
-        return attention_reference(q4, k4, v4, is_causal=causal,
-                                   scale=1.0 / np.sqrt(hd)).reshape(B, S, H)
+def _packed(b=2, s=256, nh=NH_P, seed=7):
+    """A packed [B, S, 3 nh 128] projection and a cotangent for o."""
+    rng = np.random.RandomState(seed)
+    return (jnp.asarray(rng.randn(b, s, 3 * nh * 128).astype(np.float32))
+            * 0.3,
+            jnp.asarray(rng.randn(b, s, nh * 128).astype(np.float32)))
 
-    @pytest.mark.parametrize("causal", [False, True])
-    def test_forward_and_grads(self, causal):
-        from paddle_tpu.ops.pallas.flash_attention import flash_attention_packed
-        q, k, v, NH, HD = self._qkv()
 
-        def lf(q, k, v):
-            return jnp.sum(flash_attention_packed(
-                q, k, v, NH, causal=causal, block_q=128, block_k=128,
-                interpret=True) ** 2)
+def _split(qkv, nh=NH_P):
+    """q, k, v [B, S, nh, 128] as the model's layout packs them: q heads,
+    then k heads, then v heads."""
+    b, s, _ = qkv.shape
+    return [x.reshape(b, s, nh, 128) for x in jnp.split(qkv, 3, axis=-1)]
 
-        def lr(q, k, v):
-            return jnp.sum(self._ref(q, k, v, NH, HD, causal) ** 2)
 
-        np.testing.assert_allclose(float(lf(q, k, v)), float(lr(q, k, v)),
-                                   rtol=2e-4)
-        gf = jax.grad(lf, argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(lr, argnums=(0, 1, 2))(q, k, v)
-        for a, c, nm in zip(gf, gr, "qkv"):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(c),
-                                       rtol=2e-3, atol=2e-3,
-                                       err_msg=f"d{nm} causal={causal}")
+def _qkv_value_and_grad(attend, qkv, cot):
+    """sum(o * cot) and its gradient in the packed array, of an `attend`
+    that maps q, k, v [B, S, nh, 128] (or the packed array) to o."""
+    def f(x):
+        return jnp.sum(attend(x).reshape(cot.shape) * cot)
+    return jax.value_and_grad(f)(qkv)
 
-    def test_kv_len(self):
-        from paddle_tpu.ops.pallas.flash_attention import flash_attention_packed
-        q, k, v, NH, HD = self._qkv()
-        out = flash_attention_packed(q, k, v, NH, block_q=128, block_k=128,
-                                     interpret=True, kv_len=200)
-        want = self._ref(q, k, v, NH, HD, False, kv_len=200)
-        np.testing.assert_allclose(np.asarray(out[:, :200]),
-                                   np.asarray(want[:, :200]),
-                                   rtol=2e-4, atol=2e-4)
 
-    def test_head_dim_fallback(self):
-        # hd != 128 falls back to the 4-D kernel path (reference fallback
-        # on CPU since tiles degrade) — shape contract holds
-        from paddle_tpu.ops.pallas.flash_attention import flash_attention_packed
-        rng = np.random.RandomState(1)
-        q = jnp.asarray(rng.randn(1, 64, 2 * 64).astype(np.float32))
-        out = flash_attention_packed(q, q, q, 2, interpret=True)
-        assert out.shape == q.shape
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_qkv_matches_reference(causal):
+    """The packed-operand entry: q, k, v are three views of ONE array and
+    the gradient comes back as one array, forward and backward as the
+    plain reference on the split heads."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_qkv
+    qkv, cot = _packed()
+    got, g_got = _qkv_value_and_grad(
+        lambda x: flash_attention_qkv(x, NH_P, causal=causal, block_q=128,
+                                      block_k=128, interpret=True), qkv, cot)
+    want, g_want = _qkv_value_and_grad(
+        lambda x: attention_reference(*_split(x), is_causal=causal),
+        qkv, cot)
+    np.testing.assert_allclose(float(got), float(want), rtol=2e-4)
+    assert g_got.shape == qkv.shape
+    np.testing.assert_allclose(np.asarray(g_got), np.asarray(g_want),
+                               rtol=2e-3, atol=2e-3)
 
-    def test_gpt_routes_through_packed(self, monkeypatch):
-        """PADDLE_TPU_FLASH_PACKED=1 routes GPT training attention through
-        the packed kernel (interpret-mode, tiny config)."""
-        monkeypatch.setenv("PADDLE_TPU_FLASH_PACKED", "1")
-        # the platform gate correctly refuses CPU — stub it for the
-        # interpret-mode routing check
-        import paddle_tpu.models.gpt as G
-        monkeypatch.setattr(G, "_use_packed_flash", lambda: True)
-        import paddle_tpu.ops.pallas.flash_attention as FA
-        calls = []
-        orig = FA.flash_attention_packed
 
-        def spy(*a, **kw):
-            calls.append(a[3] if len(a) > 3 else kw.get("num_heads"))
-            kw["interpret"] = True
-            return orig(*a, **kw)
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_qkv_bit_equal_to_three_arrays(causal):
+    """One walk for both forms of the operands: the packed array read
+    through lane-block offsets gives the bits three arrays give."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_qkv
+    qkv, cot = _packed(seed=8)
+    kw = dict(causal=causal, block_q=128, block_k=128, interpret=True)
+    one, g_one = _qkv_value_and_grad(
+        lambda x: flash_attention_qkv(x, NH_P, **kw), qkv, cot)
+    three, g_three = _qkv_value_and_grad(
+        lambda x: flash_attention(*_split(x), **kw), qkv, cot)
+    assert float(one) == float(three)
+    np.testing.assert_array_equal(np.asarray(g_one), np.asarray(g_three))
 
-        monkeypatch.setattr(FA, "flash_attention_packed", spy)
-        import numpy as np_
-        import paddle_tpu as paddle
-        import paddle_tpu.nn as nn
-        from paddle_tpu.models import GPTForCausalLM, gpt_config
-        paddle.seed(0)
-        cfg = gpt_config("gpt3-125m", hidden_size=256, num_layers=1,
-                         num_heads=2, vocab_size=128,
-                         max_position_embeddings=128)
-        assert cfg.head_dim == 128
-        m = GPTForCausalLM(cfg)
-        ids = paddle.to_tensor(np_.random.randint(0, 128, (1, 128)).astype("int32"))
-        lbl = paddle.to_tensor(np_.random.randint(0, 128, (1, 128)).astype("int64"))
-        loss = m.loss(ids, lbl)
-        loss.backward()
-        assert calls, "packed kernel was not routed to"
-        assert float(loss.numpy()) > 0 and np_.isfinite(float(loss.numpy()))
 
-def test_flash_save_transposed_grad_parity():
-    """PADDLE_TPU_FLASH_SAVE_T residual path (head-major residuals reused in
-    bwd) must produce the same gradients as the default recompute-transpose
-    path (advisor r3 finding: this opt-in had no coverage)."""
-    q, k, v = _rand(2, 256, 2, 64, seed=7)
+def test_flash_qkv_kv_len_inside_the_last_block():
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_qkv
+    qkv, cot = _packed(seed=9)
+    kv_len = 200
+    keep = (jnp.arange(256) < kv_len)[None, :, None]
+    got, g_got = _qkv_value_and_grad(
+        lambda x: flash_attention_qkv(x, NH_P, block_q=128, block_k=128,
+                                      interpret=True, kv_len=kv_len)
+        * keep, qkv, cot)
 
-    def loss(st):
-        def f(q, k, v):
-            out = flash_attention(q, k, v, causal=True, block_q=128,
-                                  block_k=128, interpret=True,
-                                  save_transposed=st)
-            return jnp.sum(out ** 2)
-        return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+    def ref(x):
+        q, k, v = (t[:, :kv_len] for t in _split(x))
+        out = attention_reference(q, k, v)
+        return jnp.pad(out, [(0, 0), (0, 256 - kv_len), (0, 0), (0, 0)])
 
-    g_def = loss(False)
-    g_st = loss(True)
-    for gd, gs, name in zip(g_def, g_st, "qkv"):
-        np.testing.assert_allclose(np.asarray(gd), np.asarray(gs),
-                                   rtol=1e-6, atol=1e-6,
-                                   err_msg=f"d{name} save_transposed mismatch")
+    want, g_want = _qkv_value_and_grad(ref, qkv, cot)
+    np.testing.assert_allclose(float(got), float(want), rtol=2e-4)
+    np.testing.assert_allclose(np.asarray(g_got), np.asarray(g_want),
+                               rtol=2e-3, atol=2e-3)
+    # masked keys and values get exactly nothing; their queries do
+    dq, dk, dv = jnp.split(g_got, 3, axis=-1)
+    assert float(jnp.abs(dk[:, kv_len:]).max()) == 0.0
+    assert float(jnp.abs(dv[:, kv_len:]).max()) == 0.0
+
+
+def test_flash_qkv_refuses_a_width_that_is_not_three_times_the_heads():
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_qkv
+    qkv, _ = _packed()
+    with pytest.raises(ValueError, match="heads"):
+        flash_attention_qkv(qkv, NH_P + 1, interpret=True)
+
+
+def test_flash_qkv_blocks_under_a_tile_take_the_reference():
+    """The tuner's override reaches the packed entry too; blocks too small
+    for a tile fall back to the plain form on the split heads."""
+    from paddle_tpu.ops.pallas import autotune as at
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_qkv
+    qkv, _ = _packed(b=1, s=64)
+    with at.override_blocks(4, 4):
+        out = flash_attention_qkv(qkv, NH_P, causal=True)
+    want = attention_reference(*_split(qkv), is_causal=True)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(want).reshape(out.shape),
+                               rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("d", [80, 64, 192, 256])
+def test_flash_padded_heads_batches_and_gradients(d):
+    """Heads under the lanes (cell 3's 80, BERT's 64) or over them (192,
+    padded to 256; 256) are padded to whole lanes and laid head-major, one
+    head a row of the kernels' batch and as wide as it comes: three heads
+    in two batch rows, gradients back in the caller's shape."""
+    q, k, v = _rand(2, 256, 3, d, seed=31)
+    cot = jnp.asarray(np.random.RandomState(32).randn(*q.shape)
+                      .astype(np.float32))
+
+    def loss(attend):
+        return lambda *a: jnp.sum(attend(*a) * cot)
+
+    flash = loss(lambda *a: flash_attention(*a, causal=True, block_q=128,
+                                            block_k=128, interpret=True))
+    ref = loss(lambda *a: attention_reference(*a, is_causal=True))
+    np.testing.assert_allclose(float(flash(q, k, v)), float(ref(q, k, v)),
+                               rtol=2e-4)
+    for gf, gr, name in zip(jax.grad(flash, (0, 1, 2))(q, k, v),
+                            jax.grad(ref, (0, 1, 2))(q, k, v), "qkv"):
+        assert gf.shape == (2, 256, 3, d)
+        np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
+                                   rtol=2e-3, atol=2e-3,
+                                   err_msg=f"d{name} mismatch")
+
+
+def test_flash_cross_attention_gradients():
+    """s_q != s_k, not causal: dq has the queries' length, dk and dv the
+    keys'."""
+    q, _, _ = _rand(2, 128, 3, 64, seed=33)
+    _, k, v = _rand(2, 384, 3, 64, seed=34)
+    cot = jnp.asarray(np.random.RandomState(35).randn(*q.shape)
+                      .astype(np.float32))
+    flash = lambda *a: jnp.sum(flash_attention(
+        *a, block_q=128, block_k=128, interpret=True) * cot)
+    ref = lambda *a: jnp.sum(attention_reference(*a) * cot)
+    for gf, gr, name in zip(jax.grad(flash, (0, 1, 2))(q, k, v),
+                            jax.grad(ref, (0, 1, 2))(q, k, v), "qkv"):
+        np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
+                                   rtol=2e-3, atol=2e-3,
+                                   err_msg=f"d{name} mismatch")
+
+
+@pytest.mark.parametrize("model", ["layered", "stacked"])
+def test_gpt_training_step_reads_heads_from_the_packed_projection(
+        monkeypatch, model):
+    """Heads of 128 on a TPU with no mp or sp axis: GPT's training
+    attention, layered or stacked, hands the projection to the kernels
+    whole, and no environment variable chooses it. (The CPU stands in for
+    the chip: the gate is told it sees one, the kernels run interpreted.)"""
+    import paddle_tpu as paddle
+    import paddle_tpu.ops.attention as A
+    import paddle_tpu.ops.pallas.flash_attention as FA
+    from paddle_tpu.models import GPTForCausalLM, gpt_config
+    from paddle_tpu.models.gpt_stacked import GPTStackedForCausalLM
+    for name in ("PADDLE_TPU_FLASH", "PADDLE_TPU_FLASH_BQ",
+                 "PADDLE_TPU_FLASH_BK"):
+        monkeypatch.delenv(name, raising=False)
+    monkeypatch.setattr(A, "on_tpu", lambda: True)
+    calls = []
+    orig = FA.flash_attention_qkv
+
+    def spy(qkv, num_heads, **kw):
+        calls.append((tuple(qkv.shape), num_heads))
+        return orig(qkv, num_heads, interpret=True, **kw)
+
+    monkeypatch.setattr(FA, "flash_attention_qkv", spy)
+    paddle.seed(0)
+    cfg = gpt_config("gpt3-125m", hidden_size=256, num_layers=1,
+                     num_heads=2, vocab_size=128,
+                     max_position_embeddings=128)
+    assert cfg.head_dim == 128
+    m = GPTForCausalLM(cfg)
+    if model == "stacked":
+        m = GPTStackedForCausalLM.from_layered(m)
+    rng = np.random.RandomState(0)
+    ids = paddle.to_tensor(rng.randint(0, 128, (2, 128)).astype("int32"))
+    lbl = paddle.to_tensor(rng.randint(0, 128, (2, 128)).astype("int64"))
+    loss = m.loss(ids, lbl)
+    loss.backward()
+    assert calls == [((2, 128, 3 * 256), 2)], calls
+    assert np.isfinite(float(loss.numpy())) and float(loss.numpy()) > 0
+    w = m.qkv_w if model == "stacked" else m.gpt.h[0].attn.qkv.weight
+    g = w.grad
+    assert g is not None and np.isfinite(np.asarray(g.numpy())).all()
+    assert float(np.abs(np.asarray(g.numpy())).max()) > 0
+
+
+def test_functional_qkv_attention_splits_where_the_kernel_does_not_run(
+        monkeypatch):
+    """The one gate of the packed route (ops/attention.py): heads off the
+    128 lanes, or a host with no TPU, split q, k, v through the caller's
+    constraint and take `functional_attention`; where it opens, the same
+    numbers come back from the kernels."""
+    import paddle_tpu.ops.attention as A
+    import paddle_tpu.ops.pallas.flash_attention as FA
+    monkeypatch.delenv("PADDLE_TPU_FLASH", raising=False)
+    qkv, _ = _packed()
+    seen = []
+
+    def constrain(x):
+        seen.append(tuple(x.shape))
+        return x
+
+    want = A.functional_qkv_attention(qkv, NH_P, 128, is_causal=True,
+                                      constrain=constrain)
+    assert seen == [(2, 256, NH_P, 128)] * 3 and want.shape == seen[0]
+    monkeypatch.setattr(A, "on_tpu", lambda: True)
+    monkeypatch.setattr(FA, "flash_attention_qkv", functools.partial(
+        FA.flash_attention_qkv, interpret=True))
+    got = A.functional_qkv_attention(qkv, NH_P, 128, is_causal=True,
+                                     constrain=constrain)
+    assert len(seen) == 3, "the packed route splits nothing"
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-4)
+    monkeypatch.setattr(FA, "flash_attention", functools.partial(
+        FA.flash_attention, interpret=True))
+    halves = A.functional_qkv_attention(qkv, 2 * NH_P, 64, is_causal=True,
+                                        constrain=constrain)
+    assert len(seen) == 6 and halves.shape == (2, 256, 2 * NH_P, 64)
 
 
 def test_flash_kv_len_nonpositive_rejected():
     """kv_len <= 0 would mask every key column and silently return a uniform
     average of V (advisor r3 finding) — must raise instead."""
-    from paddle_tpu.ops.pallas.flash_attention import flash_attention_packed
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_qkv
     q, k, v = _rand(1, 128, 2, 64)
     with pytest.raises(ValueError):
         flash_attention(q, k, v, kv_len=0, interpret=True)
     with pytest.raises(ValueError):
         flash_attention(q, k, v, kv_len=-3, interpret=True)
-    qp = jnp.reshape(q, (1, 128, 128))
     with pytest.raises(ValueError):
-        flash_attention_packed(qp, qp, qp, num_heads=1, kv_len=0,
-                               interpret=True)
+        flash_attention_qkv(_packed(b=1, s=128)[0], NH_P, kv_len=0,
+                            interpret=True)
 
 
 # ------------------------------------------------- causal tile plan (PR 32)

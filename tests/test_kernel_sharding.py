@@ -105,6 +105,39 @@ def test_flash_under_mesh_matches_reference(mesh, monkeypatch):
         np.testing.assert_allclose(np.asarray(g), w, atol=5e-5)
 
 
+def test_flash_qkv_under_mesh_matches_reference(mesh, monkeypatch):
+    """The packed projection's batch rows over dp (its lanes are q, k and
+    v heads in turn, no axis a mesh could split): each shard runs the
+    kernels on its own rows, and the one packed gradient comes back laid
+    out as the projection was."""
+    monkeypatch.setattr(fa, "flash_attention_qkv", functools.partial(
+        fa.flash_attention_qkv, interpret=True))
+    rng = np.random.RandomState(3)
+    nh = 2
+    sh = NamedSharding(mesh, P("dp", None, None))
+    qkv = jax.device_put(jnp.asarray(rng.randn(4, 128, 3 * nh * 128),
+                                     jnp.float32) * 0.3, sh)
+
+    def kernel_loss(x):
+        out = attn._flash_qkv(x, nh, causal=True, scale=None)
+        return jnp.sum(out * out), out
+
+    def ref_loss(x):
+        q, k, v = (t.reshape(4, 128, nh, 128) for t in jnp.split(x, 3, -1))
+        out = attn.attention_reference(q, k, v, is_causal=True)
+        return jnp.sum(out * out), out.reshape(4, 128, nh * 128)
+
+    (_, out), grad = jax.jit(jax.value_and_grad(kernel_loss, has_aux=True))(
+        qkv)
+    dist.set_mesh(None)
+    (_, want), want_grad = jax.value_and_grad(ref_loss, has_aux=True)(
+        jnp.asarray(np.asarray(qkv)))
+    assert out.sharding.is_equivalent_to(sh, out.ndim)
+    assert grad.sharding.is_equivalent_to(sh, grad.ndim)
+    np.testing.assert_allclose(np.asarray(out), want, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(grad), want_grad, atol=5e-5)
+
+
 @pytest.mark.parametrize("w_layout", ["vh", "hv"])
 def test_linear_ce_under_mesh_matches_unfused(mesh, w_layout):
     """Tokens over dp, vocab over mp: the per-shard (lse, gold) pairs are
