@@ -250,14 +250,14 @@ class BlockPool:
         saved (`STATE_SAVE`: slot -> snapshot) or restored (`STATE_LOAD`:
         snapshot -> slot). The operation and both rows are DATA of one
         small donated executable. Returns the replaced pools."""
-        import jax
         import jax.numpy as jnp
         n_paged = tuple(len(layer) for layer in self.layer_block_shapes)
         sig = ("state_move", self.state_rows, self.snapshot_rows,
                self.state_shapes)
         fn = _SPILL_SCATTER_CACHE.get(sig)
         if fn is None:
-            from ..jit.api import _note_cache_miss
+            from ..jit.api import (STATE_MOVE_PROGRAM, _note_cache_miss,
+                                   named_program)
             _note_cache_miss()
 
             def run(pools, op, slot, snap):
@@ -274,8 +274,8 @@ class BlockPool:
                             jnp.where(op == STATE_SAVE, row, kept))
                     out.append(tuple(planes))
                 return out
-            fn = _SPILL_SCATTER_CACHE[sig] = jax.jit(
-                run, donate_argnums=(0,))
+            fn = _SPILL_SCATTER_CACHE[sig] = named_program(
+                run, STATE_MOVE_PROGRAM, donate_argnums=(0,))
         return fn(pools, np.int32(op), np.int32(slot), np.int32(snap))
 
     @property
@@ -494,11 +494,11 @@ class BlockPool:
         bytes across shards. The executable cache key includes the mp
         axis size — engines at different shard counts never share a
         scatter program."""
-        import jax
         sig = self._spill_sig()
         fn = _SPILL_SCATTER_CACHE.get(sig)
         if fn is None:
-            from ..jit.api import _note_cache_miss
+            from ..jit.api import (SPILL_PROGRAM, _note_cache_miss,
+                                   named_program)
             _note_cache_miss()     # a new serving executable, counted
             # exactly like the models' compiled-runner builds
             if self.cache_dtype == "int8":
@@ -515,8 +515,8 @@ class BlockPool:
                     return [tuple(p.at[blk].set(planes[n * i + j])
                                   for j, p in enumerate(layer))
                             for i, layer in enumerate(pools)]
-            fn = _SPILL_SCATTER_CACHE[sig] = jax.jit(
-                run, donate_argnums=(0,))
+            fn = _SPILL_SCATTER_CACHE[sig] = named_program(
+                run, SPILL_PROGRAM, donate_argnums=(0,))
         return fn(pools, np.int32(block), *payload)
 
     def __repr__(self):

@@ -61,8 +61,33 @@ serving studies, PAPERS.md). This module provides:
                   this step's chunk); "serving/deliver" (tokens handed to
                   requests, finished rows freed and recorded) and
                   "serving/bookkeep" (batch gauges, compile accounting,
-                  the monitor's step) close the step. PERF.md section 3
-                  names the metric that reads each.
+                  the monitor's step) close the step. "serving/gc" is one
+                  collection of the host's garbage collector, wherever it
+                  fell (one `gc.callbacks` hook a process while an engine
+                  is open; `close()` of the last takes it out). PERF.md
+                  section 3 names the metric that reads each.
+
+                  The device's side of the same trace is its `XLA
+                  Modules` line: one event per program run, on the
+                  device's own clock, called `jit_<name>(<hash>)` after
+                  `jit.api`'s table of program names. `jit_serve_prefill`
+                  is one window, `jit_serve_decode` one chunk
+                  (`jit_serve_verify` a speculative window),
+                  `jit_serve_stage` the pending tokens picked,
+                  `jit_serve_put_first` a final window's first token
+                  kept, `jit_serve_page_copy` a copy-on-write,
+                  `jit_serve_state_move` a state row zeroed, saved or
+                  restored, `jit_serve_spill` a spilled page written
+                  back. Each "serving/prefill_launch" encloses exactly
+                  one `jit_serve_prefill` launch and each
+                  "serving/decode_launch" exactly one `jit_serve_decode`
+                  (or verify) launch, in order: the k-th span of a trace
+                  caused the k-th event of that program. Whatever else
+                  shows on that line (today `jit__threefry_seed` and two
+                  companions a call: the sampling key made eagerly) was
+                  sent under no name of the table.
+                  `benchmarks/tools/trace_dump.py` prints the line as
+                  `modules_ms_count` (milliseconds and runs by name).
 
   ServingMetrics  log-bucketed latency histograms (TTFT, per-output-token
                   time, end-to-end, queue wait — p50/p90/p99 derived from
@@ -70,7 +95,11 @@ serving studies, PAPERS.md). This module provides:
                   batch-fill ratio, KV occupancy) and counters
                   (requests/tokens in+out/rejections/timeouts/batches),
                   rendered to Prometheus exposition text by the SAME
-                  `profiler._metrics` formatter StepMonitor uses, plus one
+                  `profiler._metrics` formatter StepMonitor uses (among
+                  the counters: `programs_launched{program=...}`, one a
+                  launch the engine handed to the chip, by the table's
+                  name; `host_gc_pauses` and `host_gc_pause_ms`, the
+                  collections while the engine was open), plus one
                   JSONL record per finished request (the StepMonitor row
                   convention: a nested payload under "request" + "ts").
 
@@ -120,10 +149,12 @@ no longer monopolizes the engine for one monolithic prefill call.
 """
 from __future__ import annotations
 
+import gc
 import json
 import logging
 import time
 import uuid
+import weakref
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
@@ -132,11 +163,14 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..jit.api import (DECODE_PROGRAM, PAGE_COPY_PROGRAM, PREFILL_PROGRAM,
+                       PUT_FIRST_PROGRAM, SPILL_PROGRAM, STAGE_PROGRAM,
+                       STATE_MOVE_PROGRAM, VERIFY_PROGRAM, named_program)
 from ..profiler import StepMonitor
 from .kv_cache import STATE_LOAD, STATE_SAVE, STATE_ZERO
 from ..profiler.monitor import _jit_cache_misses
 from ..profiler._metrics import (LogHistogram, counter_lines, gauge_lines,
-                                 histogram_lines)
+                                 histogram_lines, labeled_counter_lines)
 
 _logger = logging.getLogger("paddle_tpu.inference.serving")
 
@@ -144,6 +178,50 @@ _logger = logging.getLogger("paddle_tpu.inference.serving")
 # them). A no-op while no profiler session is open; a span takes no
 # keyword argument, formats no string and reads no clock of its own.
 _span = jax.profiler.TraceAnnotation
+
+
+class _GcWatch:
+    """The process's one `gc.callbacks` hook: a `serving/gc` span from a
+    collection's start to its stop (a device trace then names the pause
+    as the innermost span over whatever gap it left) and, on the metrics
+    of every open engine, `host_gc_pauses` and `host_gc_pause_ms`. It
+    reads the clock twice a collection. The first engine installs it,
+    `ServingEngine.close()` of the last takes it out; an engine dropped
+    without `close()` leaves it in, counting for nobody."""
+
+    def __init__(self):
+        self._sinks = weakref.WeakSet()       # ServingMetrics of engines
+        self._open = None                     # (span, start) of a pause
+        # its own references: a collection at interpreter exit finds the
+        # module's globals gone
+        self._span, self._clock = _span, time.perf_counter
+
+    def __call__(self, phase, info):
+        if phase == "start":
+            span = self._span("serving/gc")
+            span.__enter__()
+            self._open = (span, self._clock())
+        elif self._open is not None:
+            span, t0 = self._open
+            self._open = None
+            ms = 1e3 * (self._clock() - t0)
+            span.__exit__(None, None, None)
+            for m in self._sinks:
+                m.counters["host_gc_pauses"] += 1
+                m.counters["host_gc_pause_ms"] += ms
+
+    def add(self, metrics):
+        if self not in gc.callbacks:
+            gc.callbacks.append(self)
+        self._sinks.add(metrics)
+
+    def discard(self, metrics):
+        self._sinks.discard(metrics)
+        if not self._sinks and self in gc.callbacks:
+            gc.callbacks.remove(self)
+
+
+_gc_watch = _GcWatch()
 
 
 # where a paged row's pending token and done flag are read from when a
@@ -401,7 +479,17 @@ class ServingMetrics:
                          "decode_chunks": 0,
                          "decode_chunks_overlapped": 0,
                          "eos_late_rows": 0,
-                         "decode_rows_idle": 0}
+                         "decode_rows_idle": 0,
+                         # collections of the host's garbage collector
+                         # while an engine was open, and what they took
+                         # (`_GcWatch`; each is a `serving/gc` span)
+                         "host_gc_pauses": 0,
+                         "host_gc_pause_ms": 0.0}
+        # launches by program (`jit.api`'s table): one a call the engine
+        # hands to the chip, so the k-th `serving/decode_launch` span of
+        # a trace caused the k-th `jit_serve_decode` event of its `XLA
+        # Modules` line, and so for prefill
+        self.programs_launched: Dict[str, int] = {}
         self.gauges = {"queue_depth": 0, "inflight": 0,
                        "batch_fill_ratio": None, "kv_occupancy": None,
                        "kv_slots_occupancy": None,
@@ -551,6 +639,7 @@ class ServingMetrics:
     # -- reporting ------------------------------------------------------
     def summary(self) -> dict:
         out = {**{f"{k}_total": v for k, v in self.counters.items()},
+               "programs_launched_total": dict(self.programs_launched),
                **{k: v for k, v in self.gauges.items()}}
         for name, _ in self.HISTS:
             h = self.hists[name]
@@ -619,6 +708,10 @@ class ServingMetrics:
                  "decode_rows_idle": "rows of launched decode chunks that "
                                      "rode neutral (max_batch less the "
                                      "rows decoding): they attend nothing",
+                 "host_gc_pauses": "collections of the host's garbage "
+                                   "collector while the engine was open",
+                 "host_gc_pause_ms": "milliseconds those collections took "
+                                     "(each a serving/gc span)",
                  # expert layers (models that hold a share of a sparse
                  # layer's experts; absent otherwise)
                  "expert_assignments_here": "(token, expert) assignments "
@@ -677,6 +770,11 @@ class ServingMetrics:
         for name, value in self.counters.items():
             lines.extend(counter_lines(prefix, f"{name}_total", value,
                                        helps[name]))
+        lines.extend(labeled_counter_lines(
+            prefix, "programs_launched_total", "program",
+            sorted(self.programs_launched.items()),
+            "launches the engine handed to the chip, by the program's "
+            "name on a device trace's XLA Modules line (less its jit_)"))
         ghelp = {"queue_depth": "requests waiting in the admission queue",
                  "inflight": "requests currently being served",
                  "batch_fill_ratio": "real rows / batch capacity of the "
@@ -1063,6 +1161,13 @@ class ServingEngine:
         # the trie (when present) drafts first, the hook fills misses
         self._draft_fn = config.spec_draft \
             if callable(config.spec_draft) else None
+        _gc_watch.add(self.metrics)
+
+    def close(self):
+        """Stop counting the host's collections for this engine; the
+        last open engine's `close()` takes the process's `gc.callbacks`
+        hook out. Serving after it works, uncounted."""
+        _gc_watch.discard(self.metrics)
 
     def _reset_device_carry(self):
         """Placeholders for what a launch reads from the launch before
@@ -1466,15 +1571,22 @@ class ServingEngine:
         return [i for i in self._live() if self._prefill_pos[i] < 0
                 and self._slots[i]._launched < self._slots[i].max_new_tokens]
 
-    def _device_helper(self, name: str, build):
+    def _count_launch(self, program: str):
+        launched = self.metrics.programs_launched
+        launched[program] = launched.get(program, 0) + 1
+
+    def _device_helper(self, name: str, program: str, build):
         """One of the engine's two small fixed-shape programs, kept in
         the model's compiled-runner cache like the model's own (a build
-        counts as a jit cache miss; the graph lint sees the call)."""
+        counts as a jit cache miss; the graph lint sees the call). The
+        fetch is the launch: it is counted under `program`."""
         from ..distributed import mesh as _dist_mesh
         cfg = self.config
         sig = (name, cfg.max_batch, cfg.decode_chunk, cfg.eos_token_id,
                _dist_mesh.mesh_axis_size("mp"))
-        return self.model._gen_cache_get(sig, lambda: jax.jit(build))
+        self._count_launch(program)
+        return self.model._gen_cache_get(
+            sig, lambda: named_program(build, program))
 
     def _stage_decode_inputs(self, live: List[int]):
         """(tables, lens, pending, done) of a decode or verify call over
@@ -1508,7 +1620,8 @@ class ServingEngine:
                              jnp.where(first, first_done, done_h))
             return pending, done
 
-        pending, done = self._device_helper("paged_stage", stage)(
+        pending, done = self._device_helper(
+            "paged_stage", STAGE_PROGRAM, stage)(
             self._toks_prev, self._done_prev, self._firsts, src,
             pending_h, done_h)
         return tables, lens, pending, done
@@ -1609,6 +1722,7 @@ class ServingEngine:
         host→device copy (the stacked payload ships as a single jit
         input) through the pool's donated scatter executable — the
         engine re-binds its pools because the call consumed them."""
+        self._count_launch(SPILL_PROGRAM)
         self._pools = self._pool.write_block(self._pools, blk, payload)
 
     def _cow_copy(self, src: int, dst: int):
@@ -1628,9 +1742,11 @@ class ServingEngine:
             def run(pools, s, d):
                 return _jax.tree_util.tree_map(
                     lambda p: p.at[d].set(p[s]), pools)
-            return _jax.jit(run, donate_argnums=(0,))
+            return named_program(run, PAGE_COPY_PROGRAM,
+                                 donate_argnums=(0,))
 
         fn = self.model._gen_cache_get(sig, build)
+        self._count_launch(PAGE_COPY_PROGRAM)
         self._pools = fn(self._pools, np.int32(src), np.int32(dst))
 
     def _admit_paged(self, ran: set) -> List[Request]:
@@ -1744,6 +1860,7 @@ class ServingEngine:
                     self._pools = self._pool.state_move(
                         self._pools, STATE_ZERO if snap is None
                         else STATE_LOAD, slot, snap or 0)
+                    self._count_launch(STATE_MOVE_PROGRAM)
                     ran.add("state_move")
                     if snap is not None:
                         self.metrics.counters["state_snapshots_restored"] += 1
@@ -1844,9 +1961,10 @@ class ServingEngine:
                         cache_dtype=cfg.cache_dtype, start=start, **kw)
                     if final:
                         self._firsts = self._device_helper(
-                            "paged_put_first",
+                            "paged_put_first", PUT_FIRST_PROGRAM,
                             lambda firsts, i, tok: firsts.at[i].set(tok[0])
                         )(self._firsts, np.int32(slot), first._data)
+            self._count_launch(PREFILL_PROGRAM)
             self._calls += 1
             ran.add("prefix_prefill" if name == "suffix_prefill" else name)
             if not final:
@@ -1879,6 +1997,7 @@ class ServingEngine:
         if row is not None:
             self._pools = self._pool.state_move(self._pools, STATE_SAVE,
                                                 slot, row)
+            self._count_launch(STATE_MOVE_PROGRAM)
             mt = self.metrics.counters
             mt["state_snapshots_taken"] += 1
             mt["state_snapshot_evictions"] += \
@@ -1908,6 +2027,7 @@ class ServingEngine:
                     eos_token_id=cfg.eos_token_id,
                     weight_dtype=cfg.weight_dtype,
                     cache_dtype=cfg.cache_dtype)
+        self._count_launch(DECODE_PROGRAM)
         self._calls += 1
         self._toks_prev = flight.toks._data
         for slot in live:
@@ -2106,6 +2226,7 @@ class ServingEngine:
             with _span("serving/decode_read"):
                 arr = np.asarray(toks.numpy())      # host sync per window  # lint: allow(tracer-asarray)
                 acc = np.asarray(n_acc)  # lint: allow(tracer-asarray)
+        self._count_launch(VERIFY_PROGRAM)
         self._calls += 1
         t = self.clock()
         with _span("serving/deliver"):

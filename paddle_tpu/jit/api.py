@@ -12,6 +12,7 @@ function as ONE tape op so eager `.backward()` differentiates through it
 from __future__ import annotations
 
 import contextlib
+import functools
 import threading
 from typing import Any, Callable, List, Optional, Sequence
 
@@ -47,6 +48,49 @@ def _maybe_wrap_lint_capture(fn, kind):
         _capture_record(sink, kind, fn, args, kwargs)
         return fn(*args, **kwargs)
     return wrapper
+
+
+# ---------------------------------------------------------------- programs
+# One name per program the package hands to the chip, as `ops/pallas/*`
+# `*_NAME` does for kernels: a device trace's `XLA Modules` line holds one
+# event per program run, named `jit_<name>(<hash>)`, and the hash changes
+# with every compile. A name says the program's ROLE, not the model, so
+# one reader serves every served family (PERF.md section 3 says which
+# metric reads which; tests/test_chip_compile.py holds the table against
+# the lowered modules).
+PREFILL_PROGRAM = "serve_prefill"        # prefill_paged: one window
+DECODE_PROGRAM = "serve_decode"          # decode_paged: one chunk
+VERIFY_PROGRAM = "serve_verify"          # verify_paged: one draft window
+STAGE_PROGRAM = "serve_stage"            # the engine picks pending / done
+PUT_FIRST_PROGRAM = "serve_put_first"    # a final window's first token
+PAGE_COPY_PROGRAM = "serve_page_copy"    # copy-on-write of one page
+STATE_MOVE_PROGRAM = "serve_state_move"  # zero / save / load a state row
+SPILL_PROGRAM = "serve_spill"            # a spilled page written back
+GENERATE_PROGRAM = "generate_static"     # generate_static(_ragged)
+EXPERT_CHOICES_PROGRAM = "expert_choices"    # diagnostics, off the
+SELECTED_BLOCKS_PROGRAM = "selected_blocks"  # serving path
+TRAIN_PROGRAM = "pure_step"              # TrainStep: one optimizer step
+TRAIN_SCAN_PROGRAM = "pure_steps"        # TrainStep.run_steps: n in one
+GRAD_PROBE_PROGRAM = "loss_and_grad_norm"
+
+PROGRAM_NAMES = tuple(v for k, v in sorted(globals().items())
+                      if k.endswith("_PROGRAM"))
+
+
+def named_program(fn, name: str, **jit_kwargs):
+    """`jax.jit(fn, **jit_kwargs)` whose module, and so its event on a
+    device trace's `XLA Modules` line, is called `jit_<name>`. The name is
+    set once, on a wrapper (a module-level `fn` keeps its own); a call
+    costs what a bare `jax.jit` call costs."""
+    if name not in PROGRAM_NAMES:
+        raise ValueError(f"{name!r} is no program of the table "
+                         f"{PROGRAM_NAMES}")
+
+    @functools.wraps(fn)
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program, **jit_kwargs)
 
 
 def compile_cache_misses() -> int:

@@ -36,8 +36,9 @@ from ..core.tensor import Tensor, Parameter
 from ..core import random as _random
 from ..core import autograd
 from ..profiler.timeline import current as _tl_current
-from .api import (_swap_params, _trace_guard, _tree_unwrap, _tree_wrap,
-                  _note_cache_miss)
+from .api import (GRAD_PROBE_PROGRAM, TRAIN_PROGRAM, TRAIN_SCAN_PROGRAM,
+                  _swap_params, _trace_guard, _tree_unwrap, _tree_wrap,
+                  _note_cache_miss, named_program)
 
 _logger = logging.getLogger("paddle_tpu.jit.train_step")
 
@@ -333,7 +334,8 @@ class TrainStep:
             )
             kwargs = dict(in_shardings=in_shardings, out_shardings=out_shardings)
         donate = (0, 1) if self.donate else ()
-        return jax.jit(pure_step, donate_argnums=donate, **kwargs)
+        return named_program(pure_step, TRAIN_PROGRAM,
+                             donate_argnums=donate, **kwargs)
 
     # ------------------------------------------------------------------
     def _build_scan(self, treedef, n_steps):
@@ -362,7 +364,8 @@ class TrainStep:
                 (keys, *flat_batches))
             return losses, pa, st, ss, auxs
 
-        return jax.jit(multi, donate_argnums=(0, 1))
+        return named_program(multi, TRAIN_SCAN_PROGRAM,
+                             donate_argnums=(0, 1))
 
     def _build_pure(self, treedef):
         """The single-step pure function (shared by __call__ and scan)."""
@@ -999,7 +1002,7 @@ class TrainStep:
                     if a.ndim > 0 else a for a in flat]
         if key is None:
             key = jax.random.PRNGKey(0)
-        compiled = jax.jit(f, **kwargs)
+        compiled = named_program(f, GRAD_PROBE_PROGRAM, **kwargs)
         self._compiled[key_sig] = compiled
         loss, gn = compiled(tuple(p._data for p in params), key, *flat)
         return float(loss), float(gn)

@@ -14,6 +14,7 @@ import numpy as np
 from jax import lax
 
 from ..core.tensor import Tensor
+from ..jit.api import DECODE_PROGRAM, PREFILL_PROGRAM, named_program
 from ..nn.layer import Layer
 from .gpt import GPTForCausalLM, sample_logits
 
@@ -175,8 +176,8 @@ class PagedStateDecoder(Layer):
         sig = (type(self).__name__ + ".prefill", b, p_cap, _shapes(pools),
                int(tables.shape[1]), float(temperature), int(top_k),
                float(top_p))
-        fn = self._gen_cache_get(
-            sig, lambda: jax.jit(run, donate_argnums=(1,)))
+        fn = self._gen_cache_get(sig, lambda: named_program(
+            run, PREFILL_PROGRAM, donate_argnums=(1,)))
         pools2, nxt, self._stats = fn(
             tuple(q._data for q in self.parameters()), pools, ids, lens,
             tables, st, slots, jax.random.PRNGKey(seed), self._stats)
@@ -227,8 +228,8 @@ class PagedStateDecoder(Layer):
                int(max_new_tokens), float(temperature), int(top_k),
                float(top_p),
                None if eos_token_id is None else int(eos_token_id))
-        fn = self._gen_cache_get(
-            sig, lambda: jax.jit(run, donate_argnums=(1,)))
+        fn = self._gen_cache_get(sig, lambda: named_program(
+            run, DECODE_PROGRAM, donate_argnums=(1,)))
         toks, pools2, lens2, done2, self._stats = fn(
             tuple(q._data for q in self.parameters()), pools, tables,
             lens_a, pend, done_a, jax.random.PRNGKey(seed), self._stats)

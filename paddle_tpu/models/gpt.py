@@ -38,6 +38,8 @@ from ..distributed.mpu import (ColumnParallelLinear, RowParallelLinear,
                                VocabParallelEmbedding, ParallelCrossEntropy)
 from ..distributed import mesh as _mesh
 from ..distributed.recompute import recompute
+from ..jit.api import (DECODE_PROGRAM, GENERATE_PROGRAM, PREFILL_PROGRAM,
+                       VERIFY_PROGRAM, named_program)
 from ..ops.attention import (functional_attention,
                              functional_qkv_attention)
 
@@ -956,7 +958,7 @@ class GPTForCausalLM(Layer):
                int(top_k), float(top_p),
                None if eos_token_id is None else int(eos_token_id), str(cdt),
                "q8" if q8 else "full", "c8" if c8 else "cfull")
-        fn = self._gen_cache_get(sig, lambda: jax.jit(run))
+        fn = self._gen_cache_get(sig, lambda: named_program(run, GENERATE_PROGRAM))
         payload = tuple(qmap[i] if i in qmap else p._data
                         for i, p in enumerate(params)) if q8 else \
             tuple(p._data for p in params)
@@ -1060,7 +1062,8 @@ class GPTForCausalLM(Layer):
                "q8" if q8 else "full", "c8" if c8 else "fp",
                "ofs" if ofs else "abs", _mesh.mesh_axis_size("mp"))
         fn = self._gen_cache_get(
-            sig, lambda: jax.jit(run, donate_argnums=(1,)))
+            sig, lambda: named_program(
+                run, PREFILL_PROGRAM, donate_argnums=(1,)))
         payload = tuple(qmap[i] if i in qmap else p._data
                         for i, p in enumerate(params)) if q8 else \
             tuple(p._data for p in params)
@@ -1169,7 +1172,8 @@ class GPTForCausalLM(Layer):
                str(cdt), "q8" if q8 else "full", "c8" if c8 else "fp",
                _mesh.mesh_axis_size("mp"))
         fn = self._gen_cache_get(
-            sig, lambda: jax.jit(run, donate_argnums=(1,)))
+            sig, lambda: named_program(
+                run, DECODE_PROGRAM, donate_argnums=(1,)))
         payload = tuple(qmap[i] if i in qmap else p._data
                         for i, p in enumerate(params)) if q8 else \
             tuple(p._data for p in params)
@@ -1295,7 +1299,8 @@ class GPTForCausalLM(Layer):
                str(cdt), "q8" if q8 else "full", "c8" if c8 else "fp",
                _mesh.mesh_axis_size("mp"))
         fn = self._gen_cache_get(
-            sig, lambda: jax.jit(run, donate_argnums=(1,)))
+            sig, lambda: named_program(
+                run, VERIFY_PROGRAM, donate_argnums=(1,)))
         payload = tuple(qmap[i] if i in qmap else p._data
                         for i, p in enumerate(params)) if q8 else \
             tuple(p._data for p in params)
@@ -1452,7 +1457,7 @@ class GPTForCausalLM(Layer):
                float(temperature), int(top_k), float(top_p),
                None if eos_token_id is None else int(eos_token_id), str(cdt),
                "q8" if q8 else "full", "c8" if c8 else "cfull")
-        fn = self._gen_cache_get(sig, lambda: jax.jit(run))
+        fn = self._gen_cache_get(sig, lambda: named_program(run, GENERATE_PROGRAM))
         payload = tuple(qmap[i] if i in qmap else p._data
                         for i, p in enumerate(params)) if q8 else \
             tuple(p._data for p in params)

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.tensor import apply_op
+from ..jit.api import SELECTED_BLOCKS_PROGRAM, named_program
 from ..nn import initializer as I
 from ..nn.layer import Layer
 from ..nn.layers.experts import gated_mlp
@@ -286,7 +287,7 @@ class MiniCPMSALAForCausalLM(PagedStateDecoder):
         list of (pages [B, S, Hkv, topk] ascending, whether the query
         selected [B, S]); the sequence padded to whole pages."""
         fn = self._plain(_arr(input_ids))
-        out = jax.jit(lambda *a: fn(*a)[1])(
+        out = named_program(lambda *a: fn(*a)[1], SELECTED_BLOCKS_PROGRAM)(
             *(q._data for q in self.parameters()))
         return [(np.asarray(a), np.asarray(b)) for a, b in out]  # lint: allow(tracer-asarray)
 

@@ -29,6 +29,8 @@ import numpy as np
 from jax import lax
 
 from ..core.tensor import Tensor, apply_op
+from ..jit.api import (DECODE_PROGRAM, EXPERT_CHOICES_PROGRAM,
+                       PREFILL_PROGRAM, named_program)
 from ..nn import initializer as I
 from ..nn.layer import Layer
 from ..nn.layers.experts import (LEAVES, STATS, HeldExperts, gated_mlp,
@@ -226,7 +228,7 @@ class PanguMoEForCausalLM(Layer):
         experts. For diagnosis: how a change of arithmetic moves tokens
         between experts."""
         fn = self._plain(_arr(input_ids))
-        out = jax.jit(lambda *a: fn(*a)[1])(
+        out = named_program(lambda *a: fn(*a)[1], EXPERT_CHOICES_PROGRAM)(
             *(q._data for q in self.parameters()))
         return [np.asarray(a) for a in out]  # lint: allow(tracer-asarray)
 
@@ -314,8 +316,8 @@ class PanguMoEForCausalLM(Layer):
         pool0 = pools[0][0]
         sig = ("pangu_prefill", b, p_cap, pool0.shape, int(tables.shape[1]),
                float(temperature), int(top_k), float(top_p), str(pool0.dtype))
-        fn = self._gen_cache_get(
-            sig, lambda: jax.jit(run, donate_argnums=(1,)))
+        fn = self._gen_cache_get(sig, lambda: named_program(
+            run, PREFILL_PROGRAM, donate_argnums=(1,)))
         pools2, nxt, self._stats = fn(
             tuple(q._data for q in self.parameters()), pools, ids, lens,
             tables, st, jax.random.PRNGKey(seed), self._stats)
@@ -378,8 +380,8 @@ class PanguMoEForCausalLM(Layer):
                float(top_p),
                None if eos_token_id is None else int(eos_token_id),
                str(pool0.dtype))
-        fn = self._gen_cache_get(
-            sig, lambda: jax.jit(run, donate_argnums=(1,)))
+        fn = self._gen_cache_get(sig, lambda: named_program(
+            run, DECODE_PROGRAM, donate_argnums=(1,)))
         toks, pools2, lens2, done2, self._stats = fn(
             tuple(q._data for q in self.parameters()), pools, tables,
             lens_a, pend, done_a, jax.random.PRNGKey(seed), self._stats)

@@ -63,11 +63,15 @@ def labeled_gauge_lines(prefix: str, name: str, label_key: str,
     renders exactly one): `samples` is an iterable of (label_value,
     value) pairs; pairs with a None value are skipped, and a family with
     no surviving samples renders nothing."""
+    return _labeled_lines(prefix, name, "gauge", label_key, samples, help_)
+
+
+def _labeled_lines(prefix, name, kind, label_key, samples, help_):
     kept = [(lv, v) for lv, v in samples if v is not None]
     if not kept:
         return []
     full = f"{prefix}_{name}" if prefix else name
-    return _header(prefix, name, "gauge", help_) + \
+    return _header(prefix, name, kind, help_) + \
         [f'{full}{{{label_key}="{lv}"}} {format_value(v)}'
          for lv, v in kept]
 
@@ -79,6 +83,14 @@ def counter_lines(prefix: str, name: str, value, help_: str) -> List[str]:
     full = f"{prefix}_{name}" if prefix else name
     return _header(prefix, name, "counter", help_) + \
         [f"{full} {format_value(value)}"]
+
+
+def labeled_counter_lines(prefix: str, name: str, label_key: str,
+                          samples, help_: str) -> List[str]:
+    """Render one counter family with one sample a label value
+    (`samples`: (label value, count) pairs); no pair, no family."""
+    return _labeled_lines(prefix, name, "counter", label_key, samples,
+                          help_)
 
 
 def histogram_lines(prefix: str, name: str, hist: "LogHistogram",

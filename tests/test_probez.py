@@ -123,11 +123,11 @@ def _user_slice(met):
     """The user-facing accounting the ISSUE pins: every request-scoped
     counter (goodput inputs, token volumes, cache/spec efficiency) and
     the rendered latency histograms. Excludes `batches`, the decode
-    chunk counts and the occupancy gauges — those describe MACHINE
+    chunk counts, the host's collections and the occupancy gauges — those describe MACHINE
     state, which probe rows genuinely occupy."""
     from paddle_tpu.profiler._metrics import histogram_lines
     machine = ("batches", "decode_chunks", "decode_chunks_overlapped",
-               "decode_rows_idle")
+               "decode_rows_idle", "host_gc_pauses", "host_gc_pause_ms")
     counters = {k: v for k, v in met.counters.items() if k not in machine}
     hists = "\n".join(
         "\n".join(histogram_lines("u", name, met.hists[name], help_))

@@ -69,7 +69,7 @@ class TestLogHistogram:
 # ------------------------------------------- Prometheus exposition format
 
 _SAMPLE = re.compile(
-    r'^([a-zA-Z_:][a-zA-Z0-9_:]*)(\{le="[^"]+"\})? '
+    r'^([a-zA-Z_:][a-zA-Z0-9_:]*)(\{(?:le|program)="[^"]+"\})? '
     r'(-?\d+(\.\d+)?([eE][-+]?\d+)?|\+Inf|NaN)$')
 
 
@@ -595,6 +595,66 @@ def test_engine_metrics_text_is_valid_exposition(served_model):
     from paddle_tpu.obs import lint_exposition
     fams = lint_exposition(eng.metrics_registry().render())
     assert set(types) <= set(fams)
+
+
+def test_programs_launched_is_one_labelled_counter(served_model):
+    """Every launch the engine hands to the chip is counted under its
+    program's name (`jit.api`'s table): one decode launch a chunk, one
+    stage a chunk, one prefill a window, one put-first a final window."""
+    from paddle_tpu.jit import api as programs
+    m, cfg = served_model
+    eng = _engine(m)
+    ids = _prompts(cfg, [CAP, 5, 7])
+    for r, ln in enumerate([CAP, 5, 7]):
+        eng.submit(ids[r, :ln])
+    eng.drain()
+    launched, mt = eng.metrics.programs_launched, eng.metrics.counters
+    assert launched == {programs.PREFILL_PROGRAM: 3,
+                        programs.PUT_FIRST_PROGRAM: 3,
+                        programs.DECODE_PROGRAM: mt["decode_chunks"],
+                        programs.STAGE_PROGRAM: mt["decode_chunks"]}
+    assert eng.summary()["programs_launched_total"] == launched
+    text = eng.metrics_text()
+    assert _check_exposition(text)[
+        "paddle_tpu_serving_programs_launched_total"] == "counter"
+    for name, n in launched.items():
+        assert f'paddle_tpu_serving_programs_launched_total' \
+               f'{{program="{name}"}} {n}\n' in text
+    # an engine that launched nothing renders no such family
+    assert "programs_launched" not in _engine(m).metrics_text()
+    eng.close()
+
+
+def test_the_gc_hook_is_one_a_process_and_close_takes_it_out(served_model):
+    import gc
+    from paddle_tpu.inference import serving
+    m, cfg = served_model
+    watch = serving._gc_watch
+    for metrics in list(watch._sinks):      # engines other tests left open
+        watch.discard(metrics)
+    assert watch not in gc.callbacks
+    a, b = _engine(m), _engine(m)
+    assert gc.callbacks.count(watch) == 1
+    gc.collect()
+    gc.collect()
+    for eng in (a, b):
+        mt = eng.metrics.counters
+        assert mt["host_gc_pauses"] >= 2 and mt["host_gc_pause_ms"] > 0
+    a.close()
+    assert gc.callbacks.count(watch) == 1
+    seen = a.metrics.counters["host_gc_pauses"]
+    gc.collect()
+    assert a.metrics.counters["host_gc_pauses"] == seen
+    assert b.metrics.counters["host_gc_pauses"] > seen
+    b.close()
+    b.close()                               # twice is once
+    assert watch not in gc.callbacks
+    types = _check_exposition(b.metrics_text())
+    assert types["paddle_tpu_serving_host_gc_pauses_total"] == "counter"
+    assert types["paddle_tpu_serving_host_gc_pause_ms_total"] == "counter"
+    # a closed engine still serves, uncounted
+    b.submit(_prompts(cfg, [5])[0, :5])
+    assert len(b.drain()) == 1
 
 
 def test_synthetic_traffic_shape():

@@ -24,6 +24,7 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.inference import BlockPool, ServingConfig, ServingEngine
+from paddle_tpu.inference import kv_cache
 from paddle_tpu.jit.api import compile_cache_misses
 from paddle_tpu.models import GPTConfig, GPTForCausalLM
 
@@ -424,6 +425,9 @@ def test_a_request_alone_in_a_wide_batch_serves_its_own_tokens(family,
     rng = np.random.RandomState(11)
     prompts = [rng.randint(1, vocab, (n,)).astype(np.int64)
                for n in (13, 5, 16, 9, 3, 12, 8, 15)]
+    # the pool's own programs are kept once a process and pool geometry:
+    # a test file this worker ran before may have built this one's
+    kv_cache._SPILL_SCATTER_CACHE.clear()
     miss0 = compile_cache_misses()
     alone = ServingEngine(m, ServingConfig(max_batch=8, prefix_cache=True,
                                            **kw))

@@ -18,6 +18,9 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.inference import ServingConfig, ServingEngine
+from paddle_tpu.jit.api import (DECODE_PROGRAM, PREFILL_PROGRAM,
+                                PUT_FIRST_PROGRAM, STAGE_PROGRAM,
+                                VERIFY_PROGRAM)
 from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
 
 CAP, NEW = 8, 6
@@ -38,6 +41,9 @@ PARENTS = {
     "serving/deliver": {"serving/step"},
     "serving/bookkeep": {"serving/step"},
 }
+# a collection of the host's garbage collector comes when it comes: under
+# any span of the step, or between two steps
+PARENTS["serving/gc"] = set(PARENTS) | {None}
 
 
 @pytest.fixture(scope="module")
@@ -96,7 +102,7 @@ def _check_tree(threads, must_have):
     tree = _tree(threads[0])
     names = {t[0] for t in tree}
     assert names <= set(PARENTS), names - set(PARENTS)
-    assert must_have <= names, must_have - names
+    assert must_have <= names | {"serving/gc"}, must_have - names
     children = {}
     for name, a, b, parent in tree:
         parent_name = tree[parent][0] if parent is not None else None
@@ -140,6 +146,7 @@ def test_paged_step_span_tree(served_model, tmp_path, kw):
     prompts = _prompts(cfg, [CAP, CAP, 5, CAP, 3])
     eng.submit(prompts[0])
     eng.drain()                     # compile outside the traced steps
+    launched0 = dict(eng.metrics.programs_launched)
 
     def run():
         for p in prompts:
@@ -166,6 +173,22 @@ def test_paged_step_span_tree(served_model, tmp_path, kw):
     assert 0 < count["serving/deliver"] <= \
         len(steps) * (2 if kw.get("spec_decode") else 1)
     assert count["serving/bookkeep"] == len(steps)
+    # the link from a host span to the device's program: every
+    # `*_launch` span enclosed exactly one launch of its program, so the
+    # k-th span of a trace caused the k-th `jit_serve_*` event of the
+    # device's `XLA Modules` line (a speculative window's is the verify
+    # program, or the plain chunk where no row had a draft)
+    launched = {k: v - launched0.get(k, 0)
+                for k, v in eng.metrics.programs_launched.items()}
+    assert launched[PREFILL_PROGRAM] == count["serving/prefill_launch"]
+    assert launched.get(DECODE_PROGRAM, 0) + launched.get(VERIFY_PROGRAM, 0) \
+        == count["serving/decode_launch"]
+    assert launched[STAGE_PROGRAM] == count["serving/decode_prep"]
+    # a final window's first token is put where the next chunk picks it
+    assert count["serving/prefill_read"] <= launched[PUT_FIRST_PROGRAM] \
+        <= launched[PREFILL_PROGRAM]
+    if not kw.get("spec_decode"):
+        assert VERIFY_PROGRAM not in launched
     # `serving/decode` encloses "launch this step's chunk, then read the
     # step before's": where it has both, the launch comes first, and the
     # plain engine has steps with both (the read waits under a busy chip)
@@ -181,6 +204,32 @@ def test_paged_step_span_tree(served_model, tmp_path, kw):
     assert both >= 2
     if kw.get("spec_decode"):
         assert both == len(decodes)
+
+
+def test_a_collection_is_a_span_while_an_engine_is_open(served_model,
+                                                        tmp_path):
+    """`serving/gc` from a collection's start to its stop: a gap the
+    pause left in the device's timeline gets a name of its own."""
+    import gc
+    m, cfg = served_model
+    eng = ServingEngine(m, ServingConfig(
+        max_batch=2, prompt_cap=CAP, max_new_tokens=NEW, decode_chunk=2,
+        kv_block=4))
+    eng.submit(_prompts(cfg, [5])[0])
+    eng.drain()
+
+    def run():
+        eng.submit(_prompts(cfg, [5])[0])
+        eng.step()
+        gc.collect()
+        eng.drain()
+
+    before = eng.metrics.counters["host_gc_pauses"]
+    names = [n for th in _traced_spans(tmp_path, run) for n, _, _ in th]
+    eng.close()
+    # (a collection while the session opens or closes is counted, unseen)
+    assert 1 <= names.count("serving/gc") \
+        <= eng.metrics.counters["host_gc_pauses"] - before
 
 
 def test_request_n_produced_counts_delivered_tokens(served_model):
