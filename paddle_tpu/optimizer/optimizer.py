@@ -244,20 +244,22 @@ class Momentum(Optimizer):
 # Each moment is coded through its root r (signed sqrt for the first, sqrt
 # for the second), linearly per block over the block's absmax. Two forms
 # store the same bits:
-#   * the Pallas codec (ops/pallas/q8_moments.py: `pallas_q8_decode`,
-#     `pallas_q8_encode`) reads and writes the moment in the parameter's
-#     own [R, C] tiling and cuts blocks in VMEM — taken on the chip for a
-#     2-D leaf whose shape `row_tile` accepts, outside a mesh that splits
-#     anything (`q8_codec`);
-#   * the flat XLA form below views the moment as [nb, _Q_BLOCK]: on the
-#     chip that view is a float32 relayout copy of the whole moment each
-#     way, kept for 1-D leaves, sizes that need padding, and sharded meshes.
+#   * the Pallas update (ops/pallas/q8_moments.py `pallas_q8_adamw`) reads
+#     the gradient, the parameter and the codes of a leaf in the
+#     parameter's own [R, C] tiling, cuts blocks in VMEM and writes the new
+#     parameter and codes, one pass — taken on the chip for a 2-D leaf
+#     whose shape `row_tile` accepts, outside a mesh that splits anything
+#     (`q8_codec`);
+#   * the flat XLA form below decodes both roots as [nb, _Q_BLOCK], updates
+#     them in XLA's fusion and encodes them again: on the chip that view
+#     is a float32 relayout copy of the whole moment each way, kept for
+#     1-D leaves, sizes that need padding, and sharded meshes.
 _Q_BLOCK = _q8k.BLOCK
 
 
 def q8_codec(shape) -> str:
-    """"kernel" or "flat": which form codes the 8-bit moments of a leaf of
-    this shape, under the mesh active now (TrainStep traces its update
+    """"kernel" or "flat": which form updates a leaf of this shape with
+    8-bit moments, under the mesh active now (TrainStep traces its update
     under its own)."""
     from ..distributed import mesh as _dmesh
     m = _dmesh.get_mesh()
@@ -291,18 +293,6 @@ def _q8_unblock(q, scale, shape, second):
     return r.reshape(-1)[:n].reshape(shape)
 
 
-def _q8_codes(r, second):
-    if q8_codec(r.shape) == "kernel":
-        return _q8k.encode(r, second=second)
-    return _q8_block(r, second)
-
-
-def _q8_root(q, scale, shape, second):
-    if q8_codec(shape) == "kernel":
-        return _q8k.decode(q, scale, tuple(shape), second=second)
-    return _q8_unblock(q, scale, shape, second)
-
-
 def _q8_encode(x32):
     """Blockwise SIGNED-SQRT int8 quantization (FIRST moment): code the
     sign-preserving sqrt, r = sign(x)*sqrt(|x|), linearly per block. Plain
@@ -310,11 +300,11 @@ def _q8_encode(x32):
     block absmax (rounds to 0 forever); sqrt compression moves that
     underflow floor to ~max/64516, the same treatment the second moment
     gets. Returns (int8 codes [nb, B], f32 scales [nb] in the r domain)."""
-    return _q8_codes(jnp.sign(x32) * jnp.sqrt(jnp.abs(x32)), False)
+    return _q8_block(jnp.sign(x32) * jnp.sqrt(jnp.abs(x32)), False)
 
 
 def _q8_decode(q, scale, shape):
-    r = _q8_root(q, scale, shape, False)
+    r = _q8_unblock(q, scale, shape, False)
     return jnp.sign(r) * jnp.square(r)
 
 
@@ -323,11 +313,11 @@ def _q8v_encode(v32):
     int8 on v itself underflows small entries to 0 → 1/(sqrt(0)+eps) blows
     the update up; sqrt halves the dynamic range and the +0.5-step decode
     bias below acts as a per-block adaptive epsilon."""
-    return _q8_codes(jnp.sqrt(jnp.maximum(v32, 0.0)), True)
+    return _q8_block(jnp.sqrt(jnp.maximum(v32, 0.0)), True)
 
 
 def _q8v_decode(q, scale, shape):
-    return jnp.square(_q8_root(q, scale, shape, True))
+    return jnp.square(_q8_unblock(q, scale, shape, True))
 
 
 class Adam(Optimizer):
@@ -425,7 +415,30 @@ class Adam(Optimizer):
     _fusable_elementwise = True
     _fused_state_keys = ("moment1", "moment2")
 
+    def _q8_kernel(self, param, grad, state, lr, step, wd, decoupled):
+        """The update of a leaf whose 8-bit moments take the kernel form
+        (`q8_codec`), or None: one Pallas pass from the raw gradient and
+        the codes to the new parameter and codes, the flat form's
+        arithmetic op for op."""
+        if "moment1_q" not in state or q8_codec(param.shape) != "kernel":
+            return None
+        b1, b2 = self._beta1, self._beta2
+        t = step.astype(jnp.float32)
+        hyper = jnp.stack([jnp.asarray(x, jnp.float32) for x in (
+            lr, (1 - lr * wd) if decoupled else wd,
+            1 - jnp.power(b1, t), 1 - jnp.power(b2, t))])
+        new_p, mq, ms, vq, vs = _q8k.adamw(
+            grad, param, state["moment1_q"], state["moment1_s"],
+            state["moment2_q"], state["moment2_s"], hyper, b1=b1, b2=b2,
+            eps=self._eps, decoupled=decoupled,
+            l2=not decoupled and bool(isinstance(wd, jnp.ndarray) or wd))
+        return new_p, {"moment1_q": mq, "moment1_s": ms,
+                       "moment2_q": vq, "moment2_s": vs}
+
     def update(self, param, grad, state, lr, step, wd=0.0):
+        out = self._q8_kernel(param, grad, state, lr, step, wd, False)
+        if out is not None:
+            return out
         b1, b2, eps = self._beta1, self._beta2, self._eps
         g = grad.astype(jnp.float32)
         p32 = param.astype(jnp.float32)
@@ -462,6 +475,9 @@ class AdamW(Adam):
         return float(self._wd_coeff)
 
     def update(self, param, grad, state, lr, step, wd=0.0):
+        out = self._q8_kernel(param, grad, state, lr, step, wd, True)
+        if out is not None:
+            return out
         b1, b2, eps = self._beta1, self._beta2, self._eps
         g = grad.astype(jnp.float32)
         p32 = param.astype(jnp.float32)

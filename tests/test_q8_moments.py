@@ -1,13 +1,13 @@
-"""The 8-bit moments' Pallas codec stores what XLA's flat form stores.
+"""The one-pass 8-bit AdamW update stores what XLA's flat form stores.
 
-`ops/pallas/q8_moments.py` codes a `[R, C]` moment in the parameter's own
-layout; `optimizer._q8_block` / `_q8_unblock` view it as `[nb, 2048]` and
-stay the reference. Both must give the same codes, scales and decoded
-float32 to the bit: the benchmark reads the stored state in the flat
-order (`benchmarks/reference.py` `Q8`). Kernels run in interpret mode
-here; `tests/test_chip_compile.py` compiles them for the chip.
+`ops/pallas/q8_moments.py` updates a `[R, C]` leaf and its coded moments
+in the parameter's own layout; `optimizer._q8_unblock` -> the update ->
+`optimizer._q8_block`, viewing each moment as `[nb, 2048]`, stays the
+reference. Both must give the same parameter, codes and scales to the bit:
+the benchmark reads the stored state in the flat order
+(`benchmarks/reference.py` `Q8`). The kernel runs in interpret mode here;
+`tests/test_chip_compile.py` compiles it for the chip.
 """
-import functools
 import json
 import os
 import subprocess
@@ -39,45 +39,108 @@ def _moment(shape, seed):
 
 @pytest.mark.parametrize("shape,tile", [
     ((2560, 10240), 32), ((10240, 2560), 128), ((2560, 7680), 128),
-    ((2560, 2560), 128), ((50304, 2560), 128), ((392, 2560), 392),
+    ((2560, 2560), 128), ((50304, 2560), 128), ((392, 2560), None),
     ((64, 10240), 32), ((2560,), None), ((100, 2560), None),
     ((64, 200), None), ((3, 2048), None)])
 def test_row_tile_takes_two_dimensional_whole_blocks(shape, tile):
     """Cell 3's leaves all take the kernel; 1-D leaves, lanes that are not
-    whole vregs and row counts that no tile of whole blocks divides keep
-    XLA's form."""
+    whole vregs and row counts that no tile of whole blocks and whole
+    bfloat16 sublanes (16 rows) divides keep XLA's form."""
     assert K.row_tile(shape) == tile
 
 
-@pytest.mark.parametrize("shape", [(64, 10240), (32, 2560), (128, 2560),
-                                   (128, 7680), (392, 2560)],
+# The one-pass update against the flat form's whole update, two steps from
+# coded moments over ~8 decades, in children without FMA instructions
+# (`--xla_cpu_max_isa=AVX`): XLA's CPU backend contracts multiply-adds
+# where its fusions allow, and the interpreted kernel fuses differently;
+# without them both compute each value by the same IEEE ops. Rows are cut
+# from the 2.7B model's leaves to two or three tiles.
+_ONE_PASS = [(96, 10240), (384, 2560), (256, 7680), (256, 2560),
+             (640, 2560), (512, 2560), (64, 10240), (32, 2560), (128, 2560)]
+_UPDATE_CHILD = r"""
+import functools, json, sys
+import numpy as np
+import jax, jax.numpy as jnp
+import paddle_tpu
+from paddle_tpu.optimizer import optimizer as O
+from paddle_tpu.ops.pallas import q8_moments as K
+
+K.adamw = functools.partial(K.adamw, interpret=True)
+
+def leaves(shape, cls, kernel):
+    O.on_tpu = lambda: kernel
+    opt = getattr(O, cls)(learning_rate=1e-3, beta2=0.95,
+                          moment_dtype="int8", parameters=[])
+    rng = np.random.default_rng(sum(shape))
+    x = rng.standard_normal(shape) * np.exp(3 * rng.standard_normal(shape))
+    v = np.abs(rng.standard_normal(shape)) * 1e-6
+    mq, ms = O._q8_encode(jnp.asarray((x * 1e-3).astype(np.float32)))
+    vq, vs = O._q8v_encode(jnp.asarray(v.astype(np.float32)))
+    st = {"moment1_q": mq, "moment1_s": ms, "moment2_q": vq, "moment2_s": vs}
+    p = jnp.asarray(rng.standard_normal(shape) * 0.02, jnp.bfloat16)
+    upd = jax.jit(lambda p, g, st, lr, t: opt.update(p, g, st, lr, t, 0.1))
+    for t in (3, 4):
+        g = jnp.asarray(rng.standard_normal(shape) * 1e-2, jnp.bfloat16)
+        p, st = upd(p, g, st, jnp.float32(1e-3), jnp.int32(t))
+    return {"param": p, **st}
+
+out = {}
+for shape, cls in json.loads(sys.argv[1]):
+    flat, kern = (leaves(tuple(shape), cls, k) for k in (False, True))
+    out["%dx%d/%s" % (shape[0], shape[1], cls)] = {
+        k: [str(kern[k].dtype), list(kern[k].shape),
+            int((np.asarray(flat[k]).view(np.uint8)
+                 != np.asarray(kern[k]).view(np.uint8)).sum())]
+        for k in flat}
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def one_pass_leaves():
+    """Every case of `_ONE_PASS` for Adam and AdamW, in three children at
+    once: {"RxC/cls": {leaf: [dtype, shape, bytes that differ]}}."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=ROOT,
+               XLA_FLAGS="--xla_cpu_max_isa=AVX")
+    cases = [(s, c) for s in _ONE_PASS for c in ("AdamW", "Adam")]
+    kids = [subprocess.Popen(
+        [sys.executable, "-c", _UPDATE_CHILD, json.dumps(cases[i::3])],
+        env=env, cwd=ROOT, stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+        text=True) for i in range(3)]
+    res = {}
+    try:
+        for kid in kids:
+            out, err = kid.communicate(timeout=600)
+            assert kid.returncode == 0, err[-4000:]
+            res.update(json.loads(out.strip().splitlines()[-1]))
+    finally:
+        for kid in kids:
+            kid.kill()
+    return res
+
+
+@pytest.mark.parametrize("shape", _ONE_PASS,
                          ids=lambda s: "x".join(map(str, s)))
-@pytest.mark.parametrize("second", [False, True], ids=["m1_int8", "m2_uint8"])
-def test_kernels_store_the_flat_forms_bits(shape, second):
-    """Codes, scales and the decoded root agree to the bit. For C = 2560
-    and 7680 a block straddles two rows (its pieces start at lanes 2048 /
-    1536 / 1024 / 512 of a row); for C = 10240 a block is a row segment."""
-    x = _moment(shape, seed=sum(shape) + second)
-    root = jnp.sqrt(jnp.abs(x)) if second else \
-        jnp.sign(x) * jnp.sqrt(jnp.abs(x))
-    fq, fs = O._q8_block(root, second)
-    kq, ks = K.encode(root, second=second, interpret=True)
-    assert kq.dtype == fq.dtype == (jnp.uint8 if second else jnp.int8)
-    assert kq.shape == fq.shape and ks.shape == fs.shape
-    assert (_bits(kq) == _bits(fq)).all()
-    assert (_bits(ks) == _bits(fs)).all()
-    fr = O._q8_unblock(fq, fs, shape, second)
-    kr = K.decode(fq, fs, shape, second=second, interpret=True)
-    assert kr.shape == shape and (_bits(kr) == _bits(fr)).all()
-    if shape[1] % K.BLOCK:
-        straddles = [b for b in range(fq.shape[0])
-                     if (b * K.BLOCK) // shape[1]
-                     != ((b + 1) * K.BLOCK - 1) // shape[1]]
-        assert straddles
+@pytest.mark.parametrize("cls", ["AdamW", "Adam"])
+def test_one_pass_update_stores_the_flat_forms_bits(one_pass_leaves, shape,
+                                                    cls):
+    """Two updates of a bfloat16 leaf through `pallas_q8_adamw` (interpret
+    mode) leave the parameter, both moments' codes and their scales as the
+    flat form's decode -> update -> encode does, bit for bit, in the stored
+    form (`[nb, 2048]` int8 / uint8, `[nb]` float32). For C = 2560 and 7680
+    a block straddles two rows; (32, 2560) is one tile of the whole leaf."""
+    got = one_pass_leaves["%dx%d/%s" % (shape + (cls,))]
+    nb = shape[0] * shape[1] // K.BLOCK
+    assert got == {"param": ["bfloat16", list(shape), 0],
+                   "moment1_q": ["int8", [nb, K.BLOCK], 0],
+                   "moment1_s": ["float32", [nb], 0],
+                   "moment2_q": ["uint8", [nb, K.BLOCK], 0],
+                   "moment2_s": ["float32", [nb], 0]}
+    assert K.row_tile(shape) is not None
 
 
 def test_codec_choice_follows_the_leaf(monkeypatch):
-    """On the chip a 2-D leaf that `row_tile` takes gets the kernels; a
+    """On the chip a 2-D leaf that `row_tile` takes gets the kernel; a
     1-D leaf falls back, and so does every leaf under a mesh that splits
     something. Off the chip everything is flat."""
     import paddle_tpu.distributed as dist
@@ -117,8 +180,7 @@ from paddle_tpu.profiler.monitor import StepMonitor
 def run(kernel):
     if kernel:
         O.on_tpu = lambda: True
-        K.encode = functools.partial(ENC, interpret=True)
-        K.decode = functools.partial(DEC, interpret=True)
+        K.adamw = functools.partial(ADAMW, interpret=True)
     paddle.seed(0)
     m = GPTForCausalLM(GPTConfig(vocab_size=256, hidden_size=128,
                                  num_layers=2, num_heads=4,
@@ -144,7 +206,7 @@ def run(kernel):
                    for k in sorted(st)]
     return losses, leaves, step.q8_codec_leaves, mon.report()
 
-ENC, DEC = K.encode, K.decode
+ADAMW = K.adamw
 flat = run(False)
 kern = run(True)
 print(json.dumps({
